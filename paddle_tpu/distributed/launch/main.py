@@ -100,9 +100,11 @@ def parse_args(argv=None):
     p.add_argument(
         "--compile_cache_dir", type=str,
         default=os.environ.get("PADDLE_COMPILE_CACHE_DIR", ""),
-        help="persistent compilation cache root exported to trainers as "
-        "PADDLE_COMPILE_CACHE_DIR; it outlives gang teardowns, so relaunched "
-        "ranks reload XLA binaries + AOT snapshots instead of recompiling",
+        help="compile cache root that outlives gang teardowns, so relaunched "
+        "ranks reload XLA binaries + AOT snapshots instead of recompiling: "
+        "exported to trainers as PADDLE_COMPILE_CACHE_DIR (snapshot tier) "
+        "and, unless the environment already places jax's cache, as "
+        "JAX_COMPILATION_CACHE_DIR",
     )
     p.add_argument(
         "--first_step_timeout", type=float, default=0.0,
@@ -350,6 +352,8 @@ class CollectiveController:
         # (and the snapshot fingerprint would reject mismatched entries).
         if args.compile_cache_dir:
             extra["PADDLE_COMPILE_CACHE_DIR"] = args.compile_cache_dir
+            if not os.environ.get("JAX_COMPILATION_CACHE_DIR"):
+                extra["JAX_COMPILATION_CACHE_DIR"] = args.compile_cache_dir
         for k, v in os.environ.items():
             if k.startswith("FLAGS_") or k == "PADDLE_COMPILE_CACHE_DIR":
                 extra.setdefault(k, v)

@@ -109,7 +109,7 @@ def get_default_dtype() -> str:
 
 
 class Place:
-    """Device placement, mirroring the reference's phi::Place taxonomy.
+    """Device placement, mirroring the reference's phi::Place classes.
 
     On this framework a place maps onto a JAX device: ``TPUPlace(i)`` is the
     i-th accelerator chip (PJRT device), ``CPUPlace()`` the host platform.
@@ -139,9 +139,12 @@ class Place:
     # -- JAX bridge ------------------------------------------------------
     def jax_device(self):
         devs = _devices_for(self.device_type)
-        if not devs:
-            raise RuntimeError(f"No {self.device_type} devices available")
-        return devs[self._device_id % len(devs)]
+        if not 0 <= self._device_id < len(devs):
+            raise RuntimeError(
+                f"{self!r} names no device: {len(devs)} {self.device_type} "
+                "device(s) present"
+            )
+        return devs[self._device_id]
 
 
 class CPUPlace(Place):
@@ -167,16 +170,13 @@ class CUDAPinnedPlace(CPUPlace):
 
 
 def _devices_for(kind: str):
-    try:
-        if kind == "cpu":
-            return jax.devices("cpu")
-        # any non-cpu accelerator backend counts as "tpu"/"gpu"
-        default = jax.devices()
-        if default and default[0].platform != "cpu":
-            return default
-        return []
-    except RuntimeError:
-        return []
+    if kind == "cpu":
+        return jax.devices("cpu")
+    # any non-cpu accelerator backend counts as "tpu"/"gpu"
+    default = jax.devices()
+    if default and default[0].platform != "cpu":
+        return default
+    return []
 
 
 _current_place = None
@@ -219,21 +219,18 @@ def set_device(device) -> Place:
     else:
         kind, idx = dev, 0
     if kind == "cpu":
-        _current_place = CPUPlace(idx)
-    elif kind in ("tpu", "xpu"):
-        _current_place = TPUPlace(idx)
-    elif kind in ("gpu", "cuda"):
+        place = CPUPlace(idx)
+    elif kind in ("tpu", "xpu", "gpu", "cuda"):
         # reference scripts say gpu; route to the accelerator
-        _current_place = TPUPlace(idx)
+        place = TPUPlace(idx)
     else:
         raise ValueError(f"Unknown device {device!r}")
     # steer jax's default placement (tensors stay uncommitted so they can
-    # combine with mesh-sharded operands)
-    try:
-        jax.config.update("jax_default_device", _current_place.jax_device())
-    except (RuntimeError, ValueError):
-        pass
-    return _current_place
+    # combine with mesh-sharded operands).  A device that is not there is
+    # an error: carrying on would run the program on the CPU unasked.
+    jax.config.update("jax_default_device", place.jax_device())
+    _current_place = place
+    return place
 
 
 def device_count(kind: str = "tpu") -> int:
@@ -390,8 +387,6 @@ def set_flags(flags: dict):
             raise KeyError(f"Unknown flag {k!r}")
         typ = _FLAG_DEFS[k][0]
         _flags[k] = _parse_flag(typ, v) if isinstance(v, str) and typ is not str else typ(v)
-    if "FLAGS_compile_cache_dir" in flags:
-        setup_compile_cache()
 
 
 def flag(name):
@@ -408,9 +403,11 @@ define_flag("FLAGS_log_level", 0, "VLOG level for python-side logging")
 define_flag(
     "FLAGS_compile_cache_dir",
     os.environ.get("PADDLE_COMPILE_CACHE_DIR", ""),
-    "persistent compilation cache root: XLA binaries (jax persistent cache) "
-    "and AOT executable snapshots survive the process, so restarts and "
-    "serving cold starts skip recompilation; empty disables",
+    "root of the AOT executable snapshot tier (jit/cache.py keeps "
+    "@to_static programs under <dir>/aot so a restart skips trace+lower); "
+    "empty disables the tier.  It does not place jax's persistent XLA "
+    "cache: that is always on, at JAX_COMPILATION_CACHE_DIR when the "
+    "environment sets it and at <checkout>/.jax_cache otherwise",
 )
 define_flag(
     "FLAGS_eager_cache_max_entries", 4096,
@@ -771,29 +768,30 @@ define_flag(
 
 
 # ---------------------------------------------------------------------------
-# Persistent compilation cache (tentpole of the compile-once cold start):
-# every XLA compile — eager op executables, @to_static train steps, the
-# inference Predictor — goes through jax's disk cache when a dir is set, so
-# a (program, topology, version) pays its compile bill once per machine,
-# not once per process.  The AOT snapshot tier (jit/cache.py) sits above
+# Persistent compilation cache: every XLA compile — eager op executables,
+# @to_static train steps, the serving engine's steps — goes through jax's
+# disk cache, so a (program, topology, version) pays its compile bill once
+# per machine, not once per process.  The directory is part of an entry's
+# identity to whoever looks for it next, so it never moves: where the
+# environment places it (JAX_COMPILATION_CACHE_DIR, which jax reads itself
+# and this module then leaves alone), else one fixed path in the checkout.
+# The AOT snapshot tier (jit/cache.py, FLAGS_compile_cache_dir) sits above
 # this and additionally skips trace+lower.
 # ---------------------------------------------------------------------------
 
+DEFAULT_COMPILE_CACHE_DIR = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__)))),
+    ".jax_cache",
+)
+
 _compile_cache_stats = {"disk_hits": 0, "requests": 0}
-_cc_listener_installed = False
 
 
 def _install_cc_listener():
     """Count jax's persistent-cache traffic: requests == compile calls that
     consulted the disk cache; disk_hits == loads that skipped XLA entirely.
     requests - disk_hits is therefore the fresh-XLA-compile count."""
-    global _cc_listener_installed
-    if _cc_listener_installed:
-        return
-    try:
-        from jax._src import monitoring as _mon
-    except ImportError:  # jax moved the module; stats stay zero
-        return
+    from jax._src import monitoring as _mon
 
     def _listener(event, **kw):
         if event == "/jax/compilation_cache/cache_hits":
@@ -802,13 +800,12 @@ def _install_cc_listener():
             _compile_cache_stats["requests"] += 1
 
     _mon.register_event_listener(_listener)
-    _cc_listener_installed = True
 
 
 def compile_cache_stats():
-    d = _flags["FLAGS_compile_cache_dir"]
+    d = jax.config.jax_compilation_cache_dir
     out = dict(_compile_cache_stats)
-    out["dir"] = d
+    out["dir"] = d or ""
     out["misses"] = out["requests"] - out["disk_hits"]
     entries = 0
     size = 0
@@ -828,28 +825,16 @@ def compile_cache_stats():
     return out
 
 
-def setup_compile_cache(path=None):
-    """Point jax's persistent compilation cache at FLAGS_compile_cache_dir
-    (or `path`, which also updates the flag).  Idempotent; re-invoked by
-    set_flags when the flag changes.  Empty dir disables the disk cache."""
-    if path is not None:
-        _flags["FLAGS_compile_cache_dir"] = str(path)
-    d = _flags["FLAGS_compile_cache_dir"]
-    if not d:
-        try:
-            jax.config.update("jax_compilation_cache_dir", None)
-        except (AttributeError, ValueError):
-            pass
-        return None
-    os.makedirs(d, exist_ok=True)
-    jax.config.update("jax_compilation_cache_dir", d)
-    # cache every executable: the default thresholds skip small/fast
-    # compiles, but cold-start latency is exactly the sum of those
+def _setup_compile_cache():
+    """Import-time, once: give jax's persistent cache its directory unless
+    the environment already did, and cache every executable — the default
+    thresholds skip small/fast compiles, but cold-start latency is exactly
+    the sum of those."""
+    if not os.environ.get("JAX_COMPILATION_CACHE_DIR"):
+        jax.config.update("jax_compilation_cache_dir", DEFAULT_COMPILE_CACHE_DIR)
     jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
     _install_cc_listener()
-    return d
 
 
-_install_cc_listener()
-setup_compile_cache()
+_setup_compile_cache()

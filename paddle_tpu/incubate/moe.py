@@ -256,8 +256,6 @@ class MoELayer(nn.Layer):
         by ep (the varlen tail-batch case) is zero-padded up and the pad
         rows sliced off after the exchange — they occupy gate slots on the
         last shard only, the same skew the reference's padded dispatch has."""
-        from jax.experimental.shard_map import shard_map
-
         mesh = _mesh.get_mesh()
         ep = mesh.shape["ep"]
         e = self.num_experts
@@ -280,7 +278,7 @@ class MoELayer(nn.Layer):
         act = jax.nn.gelu if self.experts.activation == "gelu" else jax.nn.relu
 
         @functools.partial(
-            shard_map,
+            jax.shard_map,
             mesh=mesh,
             in_specs=(
                 P("ep", None),            # tokens
@@ -292,7 +290,7 @@ class MoELayer(nn.Layer):
                 P("ep", None, None),
             ),
             out_specs=(P("ep", None), P(), P(), P(None)),
-            check_rep=False,
+            check_vma=False,
         )
         def local(fl, vl, wg, w1, b1, w2, b2):
             lg = fl.astype(jnp.float32) @ wg.astype(jnp.float32)  # [T_l, E]

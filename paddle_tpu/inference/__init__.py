@@ -701,6 +701,12 @@ def serve(path_or_predictor, port=8866, host="127.0.0.1", block=True,
         if state["draining"]:
             return state.get("drain_thread")
         state["draining"] = True
+        # signal.signal only works on the main thread, which is where a
+        # SIGTERM (and a block=False caller) runs drain(): restore here, not
+        # in the worker below, or the handler — and through its closure the
+        # server, the engine and every device buffer they own — outlives
+        # the drain for as long as the process does
+        _restore_handler()
         if grace is None:
             grace = float(
                 os.environ.get(
@@ -725,7 +731,6 @@ def serve(path_or_predictor, port=8866, host="127.0.0.1", block=True,
             if engine is not None:
                 engine.stop()
             server.shutdown()
-            _restore_handler()
 
         t = threading.Thread(target=_worker, name="serve-drain", daemon=True)
         state["drain_thread"] = t

@@ -31,7 +31,7 @@ the previous process's executables without tracing.
 
 Paged KV (ISSUE 7, default on via FLAGS_serve_paged_kv): instead of one
 dense `[slots, max_len, ...]` buffer per layer, K/V live in a block-paged
-ARENA `[num_pages, page_size, kv_heads, head_dim]` addressed through
+ARENA `[num_pages, kv_heads, page_size, head_dim]` addressed through
 per-slot page tables (`[slots, max_pages_per_seq]` int32) that ride the
 compiled steps as DATA — join/finish/recycle still cause zero recompiles.
 A request only occupies pages covering `prompt + max_new_tokens`, so the
@@ -1025,7 +1025,7 @@ class ContinuousBatchingEngine:
         """Disaggregated handoff import (ISSUE 19): land ONE page's worth of
         prompt K/V rows — shipped by a prefill worker — into arena page
         `dst` across every layer, in one compiled dispatch.  `k_tiles` /
-        `v_tiles` are `[n_layers, page_size, kv_heads, head_dim]` stacks
+        `v_tiles` are `[n_layers, kv_heads, page_size, head_dim]` stacks
         (partial last pages arrive zero-padded; padded rows sit past the
         slot's pos, masked like any other garbage row) and `dst` a scalar
         int32 — ALL data, so the decode worker imports any number of
@@ -1047,7 +1047,7 @@ class ContinuousBatchingEngine:
                              v_scale_tiles, dst):
         """`_import_page_body` for an int8 arena: the handoff ships the
         quantized rows AS STORED plus their float32 scale rows
-        (`[n_layers, page_size, kv_heads, 1]` stacks), so the import writes
+        (`[n_layers, kv_heads, 1, page_size]` stacks), so the import writes
         bit-identical arena state — no requantization, no drift, and the
         wire pays int8 prices (~2x cheaper than the cache dtype)."""
         from ..ops.dispatch import apply
@@ -1302,13 +1302,13 @@ class ContinuousBatchingEngine:
                     np.dtype(np.int8) if self.kv_quant == "int8"
                     else self._kv_dtype_np
                 )
-                tile = (nl, self.page_size, self._kv_heads, self._head_dim)
+                tile = (nl, self._kv_heads, self.page_size, self._head_dim)
                 args = [
                     to_tensor(np.zeros(tile, elem)),
                     to_tensor(np.zeros(tile, elem)),
                 ]
                 if self.kv_quant == "int8":
-                    srow = (nl, self.page_size, self._kv_heads, 1)
+                    srow = (nl, self._kv_heads, 1, self.page_size)
                     args += [
                         to_tensor(np.ones(srow, np.float32)),
                         to_tensor(np.ones(srow, np.float32)),
@@ -2474,22 +2474,24 @@ class ContinuousBatchingEngine:
             for i in range(n_prompt_pages):
                 lo, hi = i * ps, min(L, (i + 1) * ps)
                 rows = hi - lo
-                kt = np.zeros((nl, ps, kvh, hd), elem)
-                vt = np.zeros((nl, ps, kvh, hd), elem)
+                # wire rows are [L, kv_heads, head_dim]; arena pages are
+                # [kv_heads, page_size, head_dim] — transpose per page
+                kt = np.zeros((nl, kvh, ps, hd), elem)
+                vt = np.zeros((nl, kvh, ps, hd), elem)
                 for li, ly in enumerate(layers):
-                    kt[li, :rows] = ly["k"][lo:hi]
-                    vt[li, :rows] = ly["v"][lo:hi]
+                    kt[li, :, :rows] = ly["k"][lo:hi].swapaxes(0, 1)
+                    vt[li, :, :rows] = ly["v"][lo:hi].swapaxes(0, 1)
                 args = [to_tensor(kt), to_tensor(vt)]
                 if q8:
                     # padding rows carry scale 1.0, never 0: they sit past
                     # the slot's pos and are position-masked, but their
                     # dequantized values still flow through the masked
                     # attention sum and must stay finite
-                    kst = np.ones((nl, ps, kvh, 1), np.float32)
-                    vst = np.ones((nl, ps, kvh, 1), np.float32)
+                    kst = np.ones((nl, kvh, 1, ps), np.float32)
+                    vst = np.ones((nl, kvh, 1, ps), np.float32)
                     for li, ly in enumerate(layers):
-                        kst[li, :rows] = ly["k_scale"][lo:hi]
-                        vst[li, :rows] = ly["v_scale"][lo:hi]
+                        kst[li, :, 0, :rows] = ly["k_scale"][lo:hi, :, 0].T
+                        vst[li, :, 0, :rows] = ly["v_scale"][lo:hi, :, 0].T
                     args += [to_tensor(kst), to_tensor(vst)]
                 self._import_fn(*args, to_tensor(np.int32(pages[i])))
         with self._mu:
@@ -3002,24 +3004,32 @@ class ContinuousBatchingEngine:
         L = int(req.prompt.size)
         n_pages = -(-L // ps)
         idx = np.asarray(self._slot_pages[s][:n_pages], np.int64)
+
+        def rows(t):
+            # arena pages [n, kv_heads, page_size, head_dim] -> wire rows
+            # [L, kv_heads, head_dim] (the page-size-agnostic handoff format)
+            pages = np.asarray(t.numpy())[idx]
+            return pages.swapaxes(1, 2).reshape(
+                n_pages * ps, self._kv_heads, self._head_dim
+            )[:L]
+
+        def scale_rows(t):
+            # scale pages [n, kv_heads, 1, page_size] -> [L, kv_heads, 1]
+            pages = np.asarray(t.numpy())[idx]
+            return pages.transpose(0, 3, 1, 2).reshape(
+                n_pages * ps, self._kv_heads, 1
+            )[:L]
+
         layers = []
         with _san.allowed_sync("disagg page export"):
             for a in self._arenas:
                 ly = {
-                    "k": np.asarray(a.k.numpy())[idx].reshape(
-                        n_pages * ps, self._kv_heads, self._head_dim
-                    )[:L],
-                    "v": np.asarray(a.v.numpy())[idx].reshape(
-                        n_pages * ps, self._kv_heads, self._head_dim
-                    )[:L],
+                    "k": rows(a.k),
+                    "v": rows(a.v),
                 }
                 if a.k_scale is not None:
-                    ly["k_scale"] = np.asarray(a.k_scale.numpy())[idx].reshape(
-                        n_pages * ps, self._kv_heads, 1
-                    )[:L]
-                    ly["v_scale"] = np.asarray(a.v_scale.numpy())[idx].reshape(
-                        n_pages * ps, self._kv_heads, 1
-                    )[:L]
+                    ly["k_scale"] = scale_rows(a.k_scale)
+                    ly["v_scale"] = scale_rows(a.v_scale)
                 layers.append(ly)
         payload = serialize_kv_handoff(
             layers, L, self.kv_quant, self._kv_dtype_np.name

@@ -1,7 +1,7 @@
 """Host-side bookkeeping for the block-paged KV cache (ISSUE 7).
 
-The device side is dumb on purpose: per layer, one `[num_pages, page_size,
-kv_heads, head_dim]` K/V arena plus per-slot page tables carried as traced
+The device side is dumb on purpose: per layer, one `[num_pages, kv_heads,
+page_size, head_dim]` K/V arena plus per-slot page tables carried as traced
 DATA through the compiled decode/prefill steps (engine.py).  Everything that
 decides WHICH page holds WHICH tokens lives here, on the host, where it can
 be mutated without recompiles:
@@ -65,7 +65,7 @@ def spec_write_pages(pos, width, page_size, mapped_entries):
 
 # Quantized KV serving (ISSUE 18): 'int8' stores K/V pages as int8 with
 # per-token-row, per-kv-head float32 scales kept in a parallel scale arena
-# `[num_pages, page_size, kv_heads, 1]` (one per K and one per V per layer).
+# `[num_pages, kv_heads, 1, page_size]` (one per K and one per V per layer).
 # Scale rows are written by the SAME scatters that write the quantized page
 # rows and are addressed by the SAME page tables, so every piece of host
 # bookkeeping in this module — refcounts, COW, prefix chains — covers them
@@ -102,7 +102,7 @@ def validate_kv_quant(mode, paged=True):
 def kv_page_bytes(page_size, kv_heads, head_dim, dtype_bytes, quant="none"):
     """HBM bytes ONE layer's K+V storage spends per page.  Under 'int8'
     every K/V element costs 1 byte plus a 4-byte float32 scale per
-    (token row, kv head) — the scale arena's trailing unit dim.  This is
+    (token row, kv head) — one scale-arena element.  This is
     the byte math behind FLAGS_serve_kv_pool_pages auto-sizing: the int8
     pool gets `head_dim*dtype_bytes / (head_dim + 4)` times the pages the
     same budget buys at full precision (~1.94x at bf16 head_dim=128)."""
@@ -114,8 +114,8 @@ def kv_page_bytes(page_size, kv_heads, head_dim, dtype_bytes, quant="none"):
 def check_scale_arenas(arenas, num_pages, page_size):
     """Debug-invariants audit of the scale arenas (ISSUE 18): every int8
     layer arena must carry k_scale/v_scale buffers congruent with the K/V
-    arena — same leading page count (the tables index both), same
-    [page_size, kv_heads] row geometry, trailing unit dim, float32 — and a
+    arena — same leading page count (the tables index both), same kv
+    heads, one [1, page_size] row of scales per (page, head), float32 — and a
     'none' arena must carry none.  The pool's refcounts need no separate
     scale accounting precisely BECAUSE of this congruence: page p's scale
     rows live and die with page p.  Raises AssertionError on violation."""
@@ -129,8 +129,8 @@ def check_scale_arenas(arenas, num_pages, page_size):
                     "but carries scale buffers"
                 )
             continue
-        kvh = int(a.k.shape[2])
-        want = (int(num_pages), int(page_size), kvh, 1)
+        kvh = int(a.k.shape[1])
+        want = (int(num_pages), kvh, 1, int(page_size))
         for name, t in (("k_scale", ks), ("v_scale", vs)):
             if t is None:
                 raise AssertionError(
@@ -149,23 +149,20 @@ def check_scale_arenas(arenas, num_pages, page_size):
 
 
 # Canonical tensor-parallel layout of every KV cache buffer (ISSUE 14):
-# paged arenas are [num_pages, page_size, kv_heads, head_dim] and dense slot
-# pools are [slots, max_len, kv_heads, head_dim] — both split the KV HEADS
-# axis (dim 2) over the 'mp' mesh axis, so each device stores and streams
-# only its local heads' rows.  Page identity, table entries, and every piece
-# of host-side bookkeeping in this module stay device-count-agnostic: a page
+# paged arenas are [num_pages, kv_heads, page_size, head_dim] and dense slot
+# pools are [slots, max_len, kv_heads, head_dim] — both split their KV HEADS
+# axis over the 'mp' mesh axis, so each device stores and streams only its
+# local heads' rows.  Page identity, table entries, and every piece of
+# host-side bookkeeping in this module stay device-count-agnostic: a page
 # is the SAME page on every shard, just narrower.
-KV_TP_AXIS = 2
-
-
 def shard_kv_for_tp(cache):
     """Place a KV cache's k/v buffers on the installed serving mesh: kv
-    heads (dim 2) split over 'mp' (see KV_TP_AXIS) and — for paged arenas
-    under context parallelism (ISSUE 20) — the PAGE axis (dim 0) block-split
-    over 'cp', so shard s physically holds pages [s*per_shard,
-    (s+1)*per_shard) and the cp decode kernel streams only local pages.
-    No-op without a mesh, so the engine calls it unconditionally; returns
-    the cache for chaining."""
+    heads (dim 1 of a paged arena, dim 2 of a dense slot pool) split over
+    'mp' and — for paged arenas under context parallelism (ISSUE 20) — the
+    PAGE axis (dim 0) block-split over 'cp', so shard s physically holds
+    pages [s*per_shard, (s+1)*per_shard) and the cp decode kernel streams
+    only local pages.  No-op without a mesh, so the engine calls it
+    unconditionally; returns the cache for chaining."""
     from jax.sharding import PartitionSpec as P
 
     from ..distributed import mesh as _mesh
@@ -173,16 +170,16 @@ def shard_kv_for_tp(cache):
     cp = _mesh.axis_size("cp")
     if _mesh.get_mesh() is None or (_mesh.axis_size("mp") <= 1 and cp <= 1):
         return cache
-    # dim 0 is pages only for paged arenas (PagedKVCache carries page_size);
-    # a dense slot pool's dim 0 is SLOTS — never cp-sharded
-    page_axis = "cp" if (cp > 1 and hasattr(cache, "page_size")) else None
     mp_axis = "mp" if _mesh.axis_size("mp") > 1 else None
-    spec = P(page_axis, None, mp_axis, None)
+    if hasattr(cache, "page_size"):  # PagedKVCache
+        spec = P("cp" if cp > 1 else None, mp_axis, None, None)
+    else:  # dense slot pool: dim 0 is SLOTS — never cp-sharded
+        spec = P(None, None, mp_axis, None)
     _mesh.shard_tensor_(cache.k, spec)
     _mesh.shard_tensor_(cache.v, spec)
-    # int8 arenas (ISSUE 18): scale buffers share the [pages, page_size,
-    # kv_heads, 1] layout, so the same kv-heads sharding applies — each
-    # device holds exactly its local heads' scale rows
+    # int8 arenas (ISSUE 18): scale buffers are [pages, kv_heads, 1,
+    # page_size], so the same spec applies — each device holds exactly its
+    # local heads' scale rows
     for name in ("k_scale", "v_scale"):
         t = getattr(cache, name, None)
         if t is not None:

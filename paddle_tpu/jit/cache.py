@@ -4,14 +4,15 @@ Two persistence layers kill the per-process compile bill (ROADMAP north
 star: restarts are the COMMON case under the PR-2 gang-restart controller,
 and serving cold starts are user-visible latency):
 
-1. jax's persistent compilation cache (framework/core.setup_compile_cache,
-   FLAGS_compile_cache_dir / PADDLE_COMPILE_CACHE_DIR) — XLA binaries keyed
-   by (HLO, compile options) survive on disk, so a fresh process's compile
-   request becomes a disk read.  Covers EVERY compile: eager op
-   executables, @to_static steps, inference programs.
-2. the AOT snapshot tier here — a @to_static trace's lowered program
-   (jax.export StableHLO) plus its state-layout metadata is serialized
-   under <cache_dir>/aot/, keyed by (function source, arg signature, state
+1. jax's persistent compilation cache (framework/core.py: always on, at
+   JAX_COMPILATION_CACHE_DIR when set, else <checkout>/.jax_cache) — XLA
+   binaries keyed by (HLO, compile options) survive on disk, so a fresh
+   process's compile request becomes a disk read.  Covers EVERY compile:
+   eager op executables, @to_static steps, inference programs.
+2. the AOT snapshot tier here, on when FLAGS_compile_cache_dir /
+   PADDLE_COMPILE_CACHE_DIR names a root — a @to_static trace's lowered
+   program (jax.export StableHLO) plus its state-layout metadata is
+   serialized under <root>/aot/, keyed by (function source, arg signature, state
    avals, mesh/topology, platform) and guarded by a (jax + jaxlib +
    paddle_tpu version, relevant FLAGS, amp state) fingerprint.  A fresh
    process re-runs only the cheap discover pass (state slots are live
@@ -57,7 +58,7 @@ _NAME_RE = re.compile(r"[^A-Za-z0-9_.]+")
 
 
 def snapshot_dir():
-    """Snapshot root under the compile cache dir, or None when disabled."""
+    """<FLAGS_compile_cache_dir>/aot, or None when the tier is disabled."""
     from ..framework import core as _core
 
     d = _core.flag("FLAGS_compile_cache_dir")

@@ -204,18 +204,23 @@ def _slot_write(pool_t, new_t, slot_t):
 
 
 class PagedKVCache:
-    """One layer's paged K/V arena: `[num_pages, page_size, kv_heads,
+    """One layer's paged K/V arena: `[num_pages, kv_heads, page_size,
     head_dim]` buffers addressed through per-slot page tables (traced data).
-    Page 0 is scratch — inactive slots' all-zero table rows and every
-    masked scatter land there (see inference/paging.py).
+    (page_size, head_dim) are the minor dims because the fused page-walk
+    kernels stream one (page, kv head) tile per grid step and the TPU
+    lowering only takes a K/V block whose last two dims are whole (or
+    8x128-divisible) dims of the array.  Page 0 is scratch — inactive
+    slots' all-zero table rows and every masked scatter land there (see
+    inference/paging.py).
 
     quant="int8" (ISSUE 18) stores the K/V buffers as int8 and adds
-    `k_scale`/`v_scale` float32 buffers `[num_pages, page_size, kv_heads,
-    1]`: one symmetric scale per (token row, kv head), written by the same
-    scatters, addressed by the same tables, shared/copied by the same
-    refcount/COW machinery.  Per-ROW scales (not per-page) mean a decode
-    write never requantizes the rest of its page, and the trailing unit dim
-    keeps the scale tile 2-D for the fused kernel's BlockSpec."""
+    `k_scale`/`v_scale` float32 buffers `[num_pages, kv_heads, 1,
+    page_size]`: one symmetric scale per (token row, kv head), written by
+    the same scatters, addressed by the same tables, shared/copied by the
+    same refcount/COW machinery.  Per-ROW scales (not per-page) mean a
+    decode write never requantizes the rest of its page; the rows lie along
+    the minor (lane) dim so a page's scales are one dense [1, page_size]
+    tile — a trailing unit dim is lane-padded 128x on the TPU."""
 
     def __init__(self, num_pages, page_size, kv_heads, head_dim,
                  dtype="float32", quant="none"):
@@ -224,13 +229,13 @@ class PagedKVCache:
         self.page_size = int(page_size)
         self.quant = str(quant)
         if self.quant == "int8":
-            zeros = np.zeros((num_pages, page_size, kv_heads, head_dim), np.int8)
-            scales = np.zeros((num_pages, page_size, kv_heads, 1), np.float32)
+            zeros = np.zeros((num_pages, kv_heads, page_size, head_dim), np.int8)
+            scales = np.zeros((num_pages, kv_heads, 1, page_size), np.float32)
             self.k_scale = Tensor(scales)
             self.v_scale = Tensor(scales.copy())
         else:
             zeros = np.zeros(
-                (num_pages, page_size, kv_heads, head_dim),
+                (num_pages, kv_heads, page_size, head_dim),
                 _fcore.to_jax_dtype(dtype),
             )
             self.k_scale = None
@@ -292,7 +297,7 @@ def _page_scatter(arena_t, new_t, table_t, true_len_t, start_t=None):
 
     from ..ops.dispatch import apply
 
-    ps = arena_t.shape[1]
+    ps = arena_t.shape[2]
 
     def f(c, n, t, tl, *st):
         s = n.shape[1]
@@ -302,7 +307,9 @@ def _page_scatter(arena_t, new_t, table_t, true_len_t, start_t=None):
         P = t.shape[0]
         valid = (i < tl) & (entry < P)
         pg = jnp.where(valid, t[jnp.minimum(entry, P - 1)], 0)
-        return c.at[pg, idx % ps].set(n[0].astype(c.dtype))
+        # (page, :, row): the two index arrays broadcast to the leading dim
+        # of the update, so the [s, kv_heads, d] rows land as they are
+        return c.at[pg, :, idx % ps].set(n[0].astype(c.dtype))
 
     ins = [arena_t, new_t, table_t, true_len_t] + ([start_t] if start_t is not None else [])
     return apply(f, ins, name="kv_page_scatter")
@@ -322,7 +329,7 @@ def _rope_page_scatter(arena_k_t, arena_v_t, q, k, v, cos, sin, table_t,
 
     from ..ops.dispatch import apply
 
-    ps = arena_k_t.shape[1]
+    ps = arena_k_t.shape[2]
     s = q.shape[1]
 
     def f(ak, av, qa, ka, va, c, si, t, tl, *st):
@@ -348,8 +355,8 @@ def _rope_page_scatter(arena_k_t, arena_v_t, q, k, v, cos, sin, table_t,
         P = t.shape[0]
         valid = (i < tl) & (entry < P)
         pg = jnp.where(valid, t[jnp.minimum(entry, P - 1)], 0)
-        new_ak = ak.at[pg, gidx % ps].set(k_rot[0].astype(ak.dtype))
-        new_av = av.at[pg, gidx % ps].set(va[0].astype(av.dtype))
+        new_ak = ak.at[pg, :, gidx % ps].set(k_rot[0].astype(ak.dtype))
+        new_av = av.at[pg, :, gidx % ps].set(va[0].astype(av.dtype))
         return q_rot, k_rot, new_ak, new_av
 
     ins = [arena_k_t, arena_v_t, q, k, v, cos, sin, table_t, true_len_t]
@@ -389,7 +396,7 @@ def _rope_page_scatter_quant(arena_k_t, arena_v_t, ks_t, vs_t, q, k, v, cos,
 
     from ..ops.dispatch import apply
 
-    ps = arena_k_t.shape[1]
+    ps = arena_k_t.shape[2]
     s = q.shape[1]
 
     def f(ak, av, aks, avs, qa, ka, va, c, si, t, tl, *st):
@@ -415,10 +422,10 @@ def _rope_page_scatter_quant(arena_k_t, arena_v_t, ks_t, vs_t, q, k, v, cos,
         pg = jnp.where(valid, t[jnp.minimum(entry, P - 1)], 0)
         kq, ksc = _quantize_kv_rows(k_rot[0])
         vq, vsc = _quantize_kv_rows(va[0])
-        new_ak = ak.at[pg, gidx % ps].set(kq)
-        new_av = av.at[pg, gidx % ps].set(vq)
-        new_ks = aks.at[pg, gidx % ps].set(ksc)
-        new_vs = avs.at[pg, gidx % ps].set(vsc)
+        new_ak = ak.at[pg, :, gidx % ps].set(kq)
+        new_av = av.at[pg, :, gidx % ps].set(vq)
+        new_ks = aks.at[pg, :, 0, gidx % ps].set(ksc[..., 0])
+        new_vs = avs.at[pg, :, 0, gidx % ps].set(vsc[..., 0])
         return q_rot, k_rot, new_ak, new_av, new_ks, new_vs
 
     ins = [arena_k_t, arena_v_t, ks_t, vs_t, q, k, v, cos, sin, table_t,
@@ -448,13 +455,13 @@ def _page_decode_write(arena_t, new_t, tables_t, pos_t):
 
     from ..ops.dispatch import apply
 
-    ps = arena_t.shape[1]
+    ps = arena_t.shape[2]
 
     def f(c, n, t, p):
         if n.shape[1] == 1:
             entry = p // ps  # [slots]; pos < pages*ps by the admission math
             pg = jnp.take_along_axis(t, entry[:, None], axis=1)[:, 0]
-            return c.at[pg, p % ps].set(n[:, 0].astype(c.dtype))
+            return c.at[pg, :, p % ps].set(n[:, 0].astype(c.dtype))
         sq = n.shape[1]
         idx = p[:, None] + jnp.arange(sq, dtype=p.dtype)[None, :]  # [slots, sq]
         entry = idx // ps
@@ -464,7 +471,7 @@ def _page_decode_write(arena_t, new_t, tables_t, pos_t):
             jnp.take_along_axis(t, jnp.minimum(entry, P - 1), axis=1),
             0,
         )
-        return c.at[pg, idx % ps].set(n.astype(c.dtype))
+        return c.at[pg, :, idx % ps].set(n.astype(c.dtype))
 
     return apply(f, [arena_t, new_t, tables_t, pos_t], name="kv_page_decode_write")
 
@@ -481,16 +488,17 @@ def _page_decode_write_quant(arena_t, scale_t, new_t, tables_t, pos_t):
 
     from ..ops.dispatch import apply
 
-    ps = arena_t.shape[1]
+    ps = arena_t.shape[2]
 
     def f(c, sc, n, t, p):
         nq, ns = _quantize_kv_rows(n)
+        ns = ns[..., 0]  # [slots, s_q, kv_heads]
         if n.shape[1] == 1:
             entry = p // ps  # [slots]; pos < pages*ps by the admission math
             pg = jnp.take_along_axis(t, entry[:, None], axis=1)[:, 0]
             return (
-                c.at[pg, p % ps].set(nq[:, 0]),
-                sc.at[pg, p % ps].set(ns[:, 0]),
+                c.at[pg, :, p % ps].set(nq[:, 0]),
+                sc.at[pg, :, 0, p % ps].set(ns[:, 0]),
             )
         sq = n.shape[1]
         idx = p[:, None] + jnp.arange(sq, dtype=p.dtype)[None, :]  # [slots, sq]
@@ -501,7 +509,10 @@ def _page_decode_write_quant(arena_t, scale_t, new_t, tables_t, pos_t):
             jnp.take_along_axis(t, jnp.minimum(entry, P - 1), axis=1),
             0,
         )
-        return c.at[pg, idx % ps].set(nq), sc.at[pg, idx % ps].set(ns)
+        return (
+            c.at[pg, :, idx % ps].set(nq),
+            sc.at[pg, :, 0, idx % ps].set(ns),
+        )
 
     return apply(
         f, [arena_t, scale_t, new_t, tables_t, pos_t], multi=True,

@@ -2,14 +2,17 @@
 csrc/ — the framework's C++ runtime layer: flag registry, host staging
 arena, host tracer, TCPStore rendezvous, batch staging engine).
 
-The build is auto-attempted once (cmake+ninja, quiet) and every consumer
-degrades gracefully to a pure-Python path when the library is unavailable,
-so the framework works on machines without a toolchain.
+The library is built from csrc/ on first use (cmake+ninja) and rebuilt
+whenever a source is newer than it, so a checkout never runs a library its
+sources do not describe.  Every consumer degrades to a pure-Python path
+when no library can be had (no toolchain); `lib_status()` says which.
 """
 
 from __future__ import annotations
 
 import ctypes
+import glob
+import logging
 import os
 import subprocess
 import threading
@@ -23,12 +26,14 @@ _PKG_LIB = os.path.join(os.path.dirname(os.path.abspath(__file__)), _LIBNAME)
 
 _lib = None
 _tried = False
+_status = "not loaded"
 _lock = threading.Lock()
 
 
 def _try_build():
+    """Returns (path or None, status)."""
     if not os.path.isdir(_CSRC):
-        return None
+        return None, "unavailable: no csrc/ beside the package"
     try:
         subprocess.run(
             ["cmake", "-B", _BUILD, "-G", "Ninja", "-DCMAKE_BUILD_TYPE=Release"],
@@ -38,29 +43,59 @@ def _try_build():
             ["ninja", "-C", _BUILD, "paddle_tpu_core"],
             capture_output=True, timeout=300, check=True,
         )
-    except Exception:
-        return None
+    except (OSError, subprocess.SubprocessError) as e:
+        tail = (getattr(e, "stderr", None) or b"")[-300:].decode(errors="replace")
+        return None, f"unavailable: build failed ({e}) {tail}".strip()
     path = os.path.join(_BUILD, _LIBNAME)
-    return path if os.path.exists(path) else None
+    if not os.path.exists(path):
+        return None, "unavailable: build produced no library"
+    return path, f"built from csrc/ into {path}"
+
+
+def _older_than_sources(path):
+    built = os.path.getmtime(path)
+    sources = glob.glob(os.path.join(_CSRC, "*.cc")) + [
+        os.path.join(_CSRC, "CMakeLists.txt")
+    ]
+    return any(os.path.getmtime(s) > built for s in sources)
+
+
+def _locate():
+    """Returns (path or None, status): the wheel's bundled library, else the
+    csrc/build one — rebuilt first if it is missing or older than a source."""
+    if os.path.exists(_PKG_LIB):
+        return _PKG_LIB, f"bundled {_PKG_LIB}"
+    path = os.path.join(_BUILD, _LIBNAME)
+    if os.path.exists(path) and not _older_than_sources(path):
+        return path, f"prebuilt {path}"
+    return _try_build()
+
+
+def lib_status():
+    """One line on which native library this process got: bundled, prebuilt,
+    built on this call, or unavailable and why."""
+    get_lib()
+    return _status
 
 
 def get_lib():
     """Returns the loaded CDLL or None."""
-    global _lib, _tried
+    global _lib, _tried, _status
     if _lib is not None or _tried:
         return _lib
     with _lock:
         if _lib is not None or _tried:
             return _lib
         _tried = True
-        path = _PKG_LIB if os.path.exists(_PKG_LIB) else os.path.join(_BUILD, _LIBNAME)
-        if not os.path.exists(path):
-            path = _try_build()
-        if not path:
+        path, _status = _locate()
+        if path is None:
+            logging.getLogger("paddle_tpu").warning("native core %s", _status)
             return None
         try:
             lib = ctypes.CDLL(path)
-        except OSError:
+        except OSError as e:
+            _status = f"unavailable: {path} did not load ({e})"
+            logging.getLogger("paddle_tpu").warning("native core %s", _status)
             return None
         # signatures
         lib.pt_host_alloc.restype = ctypes.c_void_p

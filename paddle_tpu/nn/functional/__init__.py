@@ -1359,14 +1359,14 @@ def flash_decode(query, key, value, pos, scale=None):
 def paged_flash_decode(query, arena_k, arena_v, tables, pos, max_len, scale=None,
                        kernel="auto", k_scale=None, v_scale=None):
     """Cached attention over a block-paged KV pool: q [b, sq, h, d] against
-    per-layer arenas [num_pages, page_size, kv_h, d], addressed through
+    per-layer arenas [num_pages, kv_h, page_size, d], addressed through
     `tables` ([b, max_pages_per_seq] int32, traced data).  The page
     indirection happens inside the compiled step; validity comes from `pos`
     exactly as in flash_decode, so paged and dense decode are bit-identical.
     `kernel` selects the dispatch: "auto" (fused Pallas arena-reading kernel
     when eligible, else gather-then-dense), "fused", or "gather".  When the
     arenas are int8-quantized, pass their per-row scale arenas as
-    `k_scale`/`v_scale` ([num_pages, page_size, kv_h, 1] float32) — both
+    `k_scale`/`v_scale` ([num_pages, kv_h, 1, page_size] float32) — both
     dispatches then dequantize through the same page tables."""
     from ...ops.flash_attention import paged_flash_decode as _pfd
 

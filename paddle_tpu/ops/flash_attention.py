@@ -35,12 +35,18 @@ from .dispatch import apply, coerce
 
 _NEG_INF = -1e30
 
+# Scoped-VMEM budget declared to Mosaic for the dense flash kernels.  Their
+# 1024x1024 blocks keep ~16 MiB of f32 score/prob temporaries live; the
+# causal variants fit the compiler's default 16 MiB scope, the non-causal
+# key-bias forward overruns it by ~1 MiB (compile-time RESOURCE_EXHAUSTED
+# on v5e, libtpu 0.0.34).  A v5e core has 128 MiB of VMEM.
+_DENSE_VMEM_LIMIT = 32 * 1024 * 1024
+
 
 def _on_tpu():
-    try:
-        return jax.default_backend() == "tpu"
-    except RuntimeError:
-        return False
+    # a backend that fails to initialise raises here: answering False would
+    # send a TPU program down the XLA fallback without a word
+    return jax.default_backend() == "tpu"
 
 
 # ---------------------------------------------------------------------------
@@ -114,9 +120,9 @@ def _flash_fwd_kernel(
         ) * scale  # [bb, block_q, block_k]
         if kb_ref is not None:
             # additive key bias (lowered key-padding attn_mask): one value
-            # per key column, broadcast over the q rows exactly as the XLA
-            # fallback's `s + mask`
-            s = s + kb_ref[:, 0][None, None, :]
+            # per key column, a [1, block_k] row broadcast over the q rows
+            # exactly as the XLA fallback's `s + mask`
+            s = s + kb_ref[...][None]
         sq = sk = None
         if seg_refs:
             sq = seg_refs[0][:, 0]
@@ -195,8 +201,10 @@ def _pallas_flash_forward(q, k, v, causal, scale, segments=None, n_heads=1,
     global start position of each q block, for rectangular causal blocks
     whose rows are not contiguous in global positions (zig-zag context
     parallelism); build with q_block_starts().
-    kbias: optional [b, k_len, 1] f32 additive per-key bias (a lowered
-    key-padding attn_mask), shared across heads via the index map.
+    kbias: optional [b, 1, k_len] f32 additive per-key bias (a lowered
+    key-padding attn_mask), shared across heads via the index map; keys lie
+    along lanes — a [k_len, 1] column is lane-padded 128x in VMEM and tips
+    the 1024x1024 forward block over the 16 MiB scoped limit.
     Returns (out [bh, seq, d], lse [bh, seq, 1] f32)."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
@@ -226,7 +234,7 @@ def _pallas_flash_forward(q, k, v, causal, scale, segments=None, n_heads=1,
         args += [segments, segments]
     if kbias is not None:
         in_specs += [
-            pl.BlockSpec((None, block_k, 1), lambda b, i, j, *_: ((b * bb) // n_heads, j, 0)),
+            pl.BlockSpec((None, 1, block_k), lambda b, i, j, *_: ((b * bb) // n_heads, 0, j)),
         ]
         args += [kbias]
     if carry is not None:
@@ -276,6 +284,7 @@ def _pallas_flash_forward(q, k, v, causal, scale, segments=None, n_heads=1,
         pltpu.VMEM((bb, block_q, 1), jnp.float32),
         pltpu.VMEM((bb, block_q, d), jnp.float32),
     ]
+    params = pltpu.CompilerParams(vmem_limit_bytes=_DENSE_VMEM_LIMIT)
     if q_offset is not None:
         grid_spec = pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1, grid=grid, in_specs=in_specs,
@@ -283,6 +292,7 @@ def _pallas_flash_forward(q, k, v, causal, scale, segments=None, n_heads=1,
         )
         return pl.pallas_call(
             kernel, grid_spec=grid_spec, out_shape=out_shape, interpret=interpret,
+            compiler_params=params,
         )(jnp.asarray(q_offset, jnp.int32).reshape(-1), *args)
     return pl.pallas_call(
         kernel,
@@ -292,6 +302,7 @@ def _pallas_flash_forward(q, k, v, causal, scale, segments=None, n_heads=1,
         out_shape=out_shape,
         scratch_shapes=scratch,
         interpret=interpret,
+        compiler_params=params,
     )(*args)
 
 
@@ -334,7 +345,7 @@ def _flash_bwd_dkdv_kernel(
             q, k, (((2,), (2,)), ((0,), (0,))), preferred_element_type=jnp.float32
         ) * scale  # [bb, bq, bk]
         if kb_ref is not None:
-            s = s + kb_ref[:, 0][None, None, :]
+            s = s + kb_ref[...][None]
         sq = sk = None
         if seg_refs:
             sq = seg_refs[0][:, 0]
@@ -392,7 +403,7 @@ def _flash_bwd_dq_kernel(
             q, k, (((2,), (2,)), ((0,), (0,))), preferred_element_type=jnp.float32
         ) * scale
         if kb_ref is not None:
-            s = s + kb_ref[:, 0][None, None, :]
+            s = s + kb_ref[...][None]
         sq = sk = None
         if seg_refs:
             sq = seg_refs[0][:, 0]
@@ -420,7 +431,7 @@ def _pallas_flash_backward(q, k, v, g, out, lse, causal, scale, segments=None,
     start positions; without q_offset, causal assumes sq == sk).
     delta: optional precomputed rowsum(g*out) [bh, sq, 1] — the ring path
     computes it ONCE for all hops instead of once per hop.
-    kbias: optional [b, sk, 1] f32 additive per-key bias (same operand as
+    kbias: optional [b, 1, sk] f32 additive per-key bias (same operand as
     the forward pass — s must be recomputed identically for p to match).
     Returns (dq, dk, dv)."""
     from jax.experimental import pallas as pl
@@ -438,6 +449,7 @@ def _pallas_flash_backward(q, k, v, g, out, lse, causal, scale, segments=None,
         )  # [bh, s, 1]
 
     common = dict(causal=causal, scale=scale, block_q=block_q, block_k=block_k)
+    params = pltpu.CompilerParams(vmem_limit_bytes=_DENSE_VMEM_LIMIT)
 
     # -- dk/dv: grid over k blocks, stream q --------------------------------
     in_specs = [
@@ -457,7 +469,7 @@ def _pallas_flash_backward(q, k, v, g, out, lse, causal, scale, segments=None,
         args += [segments, segments]
     if kbias is not None:
         in_specs += [
-            pl.BlockSpec((None, block_k, 1), lambda b, i, j, *_: ((b * bb) // n_heads, i, 0)),
+            pl.BlockSpec((None, 1, block_k), lambda b, i, j, *_: ((b * bb) // n_heads, 0, i)),
         ]
         args += [kbias]
 
@@ -502,6 +514,7 @@ def _pallas_flash_backward(q, k, v, g, out, lse, causal, scale, segments=None,
             ),
             out_shape=dkdv_out_shape,
             interpret=interpret,
+            compiler_params=params,
         )(off_arr, *args)
     else:
         dk, dv = pl.pallas_call(
@@ -512,6 +525,7 @@ def _pallas_flash_backward(q, k, v, g, out, lse, causal, scale, segments=None,
             out_shape=dkdv_out_shape,
             scratch_shapes=dkdv_scratch,
             interpret=interpret,
+            compiler_params=params,
         )(*args)
 
     # -- dq: grid over q blocks, stream k -----------------------------------
@@ -532,7 +546,7 @@ def _pallas_flash_backward(q, k, v, g, out, lse, causal, scale, segments=None,
         args += [segments, segments]
     if kbias is not None:
         in_specs += [
-            pl.BlockSpec((None, block_k, 1), lambda b, i, j, *_: ((b * bb) // n_heads, j, 0)),
+            pl.BlockSpec((None, 1, block_k), lambda b, i, j, *_: ((b * bb) // n_heads, 0, j)),
         ]
         args += [kbias]
 
@@ -566,6 +580,7 @@ def _pallas_flash_backward(q, k, v, g, out, lse, causal, scale, segments=None,
             ),
             out_shape=dq_out_shape,
             interpret=interpret,
+            compiler_params=params,
         )(off_arr, *args)
     else:
         dq = pl.pallas_call(
@@ -576,6 +591,7 @@ def _pallas_flash_backward(q, k, v, g, out, lse, causal, scale, segments=None,
             out_shape=dq_out_shape,
             scratch_shapes=dq_scratch,
             interpret=interpret,
+            compiler_params=params,
         )(*args)
     return dq, dk, dv
 
@@ -735,13 +751,23 @@ def decode_attention_array(q, k, v, pos, scale=None):
     # flash-decode for q=1 (measured: Pallas per-layer launches cost ~30%
     # of decode tok/s).  The Pallas kernel wins for prefill-with-cache,
     # where it avoids materializing the [sq, L] score block.
-    if (
+    use_pallas = (
         (_on_tpu() or interpret)
         and not per_row_pos
         and d <= 256
         and L % 128 == 0
         and sq >= 64
-    ):
+    )
+    if use_pallas:
+        from ..distributed import mesh as _mesh
+
+        if _mesh.axis_size("mp") > 1:
+            # the kernel flattens (batch, kv heads) into one grid dim, which
+            # a heads-sharded mesh cannot map per device, and GSPMD cannot
+            # partition a Mosaic call; it can partition the dense path below
+            _log_pallas_fallback("decode kernel under an mp mesh", shape=q.shape)
+            use_pallas = False
+    if use_pallas:
         # pad q rows up to the TPU sublane tile; padded rows attend slot 0+
         # legitimately (their q_ids exceed the real rows') and are sliced off.
         # The common serving shapes are already 8/128-aligned — hoist the
@@ -798,15 +824,27 @@ def flash_decode(query, key, value, pos, scale=None):
 
 
 def paged_gather_kv(arena, tables, max_len):
-    """Gather a paged arena [num_pages, page_size, kv_h, d] back into dense
+    """Gather a paged arena [num_pages, kv_h, page_size, d] back into dense
     per-sequence buffers [b, max_len, kv_h, d] through the page tables
     ([b, P] int32).  The reshape-then-slice keeps the attended geometry
     identical to the dense slot pool (P * page_size >= max_len; the slack
     rows come from the sequence's own trailing page and are masked by pos
     downstream anyway)."""
     b = tables.shape[0]
-    g = arena[tables]  # [b, P, page_size, kv_h, d]
-    g = g.reshape(b, -1, arena.shape[2], arena.shape[3])
+    g = arena[tables]  # [b, P, kv_h, page_size, d]
+    g = jnp.swapaxes(g, 2, 3)  # [b, P, page_size, kv_h, d]
+    g = g.reshape(b, -1, arena.shape[1], arena.shape[3])
+    return g[:, :max_len]
+
+
+def paged_gather_scale(scale, tables, max_len):
+    """`paged_gather_kv` for a scale arena [num_pages, kv_h, 1, page_size]:
+    returns [b, max_len, kv_h, 1], the per-(row, kv head) factors aligned
+    with the gathered K/V rows."""
+    b = tables.shape[0]
+    g = scale[tables]  # [b, P, kv_h, 1, page_size]
+    g = jnp.transpose(g, (0, 1, 4, 2, 3))  # [b, P, page_size, kv_h, 1]
+    g = g.reshape(b, -1, scale.shape[1], 1)
     return g[:, :max_len]
 
 
@@ -818,7 +856,10 @@ def _fused_paged_decode_forward(q, arena_k, arena_v, tables, pos, max_len,
     step — the single biggest HBM tax on the serving hot path; ROADMAP 4).
 
     q: [b, sq, h, d] (sq == 1 plain decode, sq == k+1 speculative verify);
-    arena_k/v: [num_pages, page_size, kv_h, d]; tables: [b, P] int32 page
+    arena_k/v: [num_pages, kv_h, page_size, d] — (page_size, d) minor, so
+    one (page, kv head) tile is a whole trailing [page_size, d] block of
+    the array, the only K/V block shape the Mosaic lowering accepts for
+    kv_h > 1; tables: [b, P] int32 page
     ids (traced DATA — they index the arena inside the BlockSpec index
     maps, fed as scalar-prefetch so the DMA engine knows each page before
     its grid step); pos: int32 scalar or [b] per-slot positions.
@@ -840,8 +881,8 @@ def _fused_paged_decode_forward(q, arena_k, arena_v, tables, pos, max_len,
     from jax.experimental.pallas import tpu as pltpu
 
     b, sq, h, d = q.shape
-    ps = arena_k.shape[1]
-    hk = arena_k.shape[2]
+    hk = arena_k.shape[1]
+    ps = arena_k.shape[2]
     rep = h // hk
     P = tables.shape[1]
     R = rep * sq
@@ -903,10 +944,10 @@ def _fused_paged_decode_forward(q, arena_k, arena_v, tables, pos, max_len,
         in_specs=[
             pl.BlockSpec((None, None, qr, d), lambda s, g, j, t, p: (s, g, 0, 0)),
             pl.BlockSpec(
-                (None, ps, None, d), lambda s, g, j, t, p: (t[s * P + j], 0, g, 0)
+                (None, None, ps, d), lambda s, g, j, t, p: (t[s * P + j], g, 0, 0)
             ),
             pl.BlockSpec(
-                (None, ps, None, d), lambda s, g, j, t, p: (t[s * P + j], 0, g, 0)
+                (None, None, ps, d), lambda s, g, j, t, p: (t[s * P + j], g, 0, 0)
             ),
         ],
         out_specs=pl.BlockSpec(
@@ -962,24 +1003,26 @@ def _fused_paged_decode_quant_forward(q, arena_k, arena_v, k_scale, v_scale,
                                       tables, pos, max_len, scale,
                                       interpret=False):
     """`_fused_paged_decode_forward` over an int8 arena (ISSUE 18): the K/V
-    page tiles arrive as int8 and their per-row scales ([page_size, 1]
-    float32 tiles from the parallel scale arenas, addressed by the SAME
-    `t[s*P+j]` table lookup in their BlockSpec index maps) ride into VMEM
-    with them; dequantization — `tile.astype(f32) * scale_row` — happens
-    per page tile inside the online-softmax loop, so the arena's HBM
-    footprint is what streams: 1 byte per element plus 4 bytes per (row,
-    head) instead of 2.  q is cast to f32 in-kernel so the dot runs at the
-    dequantized precision the gather oracle uses — fused-vs-gather parity
-    holds under quantization too.  Masks and the softmax recurrence are
-    byte-identical to the unquantized kernel: scratch-page garbage scales
-    are finite by construction and fenced by `jid <= pos + w` before they
-    could reach a softmax."""
+    page tiles arrive as int8 and their per-row scales ([1, page_size]
+    float32 tiles from the parallel scale arenas `[num_pages, kv_h, 1,
+    page_size]`, addressed by the SAME `t[s*P+j]` table lookup in their
+    BlockSpec index maps) ride into VMEM with them.  The scale rows lie
+    along LANES: a trailing unit dim would make XLA relayout the whole
+    scale arena into a 128x lane-padded copy in front of every call.
+    Dequantization happens per page tile inside the online-softmax loop,
+    so the arena's HBM footprint is what streams: 1 byte per element plus
+    4 bytes per (row, head) instead of 2.  q is cast to f32 in-kernel so
+    the dots run at the dequantized precision the gather oracle uses —
+    fused and gather agree to float reassociation under quantization too.
+    Masks and the softmax recurrence are those of the unquantized kernel:
+    scratch-page garbage scales are finite by construction and fenced by
+    `jid <= pos + w` before they could reach a softmax."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
     b, sq, h, d = q.shape
-    ps = arena_k.shape[1]
-    hk = arena_k.shape[2]
+    hk = arena_k.shape[1]
+    ps = arena_k.shape[2]
     rep = h // hk
     P = tables.shape[1]
     R = rep * sq
@@ -1008,13 +1051,16 @@ def _fused_paged_decode_quant_forward(q, arena_k, arena_v, k_scale, v_scale,
         @pl.when(needed)
         def _compute():
             qb = q_ref[...].astype(jnp.float32)  # [qr, d]
-            # in-VMEM dequant: int8 page tile * its [ps, 1] scale column
-            kb = k_ref[...].astype(jnp.float32) * ks_ref[...]
-            vb = v_ref[...].astype(jnp.float32) * vs_ref[...]
+            kb = k_ref[...].astype(jnp.float32)  # [ps, d] raw int8 values
+            vb = v_ref[...].astype(jnp.float32)
+            # in-VMEM dequant, factored out of the contractions: a row's
+            # scale is constant over head_dim, so q.(k*ks) == (q.k)*ks and
+            # p.(v*vs) == (p*vs).v — the [1, ps] scale rows multiply score
+            # COLUMNS instead of [ps, d] tiles
             s = jax.lax.dot_general(
                 qb, kb, (((1,), (1,)), ((), ())),
                 preferred_element_type=jnp.float32,
-            ) * scale  # [qr, ps]
+            ) * ks_ref[...] * scale  # [qr, ps]
             w = jax.lax.broadcasted_iota(jnp.int32, (qr, ps), 0) % sq
             jid = j * ps + jax.lax.broadcasted_iota(jnp.int32, (qr, ps), 1)
             s = jnp.where((jid <= p0 + w) & (jid < max_len), s, _NEG_INF)
@@ -1026,7 +1072,7 @@ def _fused_paged_decode_quant_forward(q, arena_k, arena_v, k_scale, v_scale,
             m_scr[...] = m_new[..., None]
             l_scr[...] = (alpha * l + p.sum(-1))[..., None]
             acc_scr[...] = acc_scr[...] * alpha[..., None] + jax.lax.dot_general(
-                p, vb, (((1,), (0,)), ((), ())),
+                p * vs_ref[...], vb, (((1,), (0,)), ((), ())),
                 preferred_element_type=jnp.float32,
             )
 
@@ -1036,10 +1082,10 @@ def _fused_paged_decode_quant_forward(q, arena_k, arena_v, k_scale, v_scale,
             o_ref[...] = (acc_scr[...] / l_safe[..., None]).astype(o_ref.dtype)
 
     page_tile = pl.BlockSpec(
-        (None, ps, None, d), lambda s, g, j, t, p: (t[s * P + j], 0, g, 0)
+        (None, None, ps, d), lambda s, g, j, t, p: (t[s * P + j], g, 0, 0)
     )
     scale_tile = pl.BlockSpec(
-        (None, ps, None, 1), lambda s, g, j, t, p: (t[s * P + j], 0, g, 0)
+        (None, None, 1, ps), lambda s, g, j, t, p: (t[s * P + j], g, 0, 0)
     )
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
@@ -1114,9 +1160,10 @@ def _fused_paged_viable(q, page_size):
 
 
 def _fused_paged_decode_tp(q, arena_k, arena_v, tables, pos, max_len, scale,
-                           interpret, mp):
-    """Tensor-parallel dispatch of the fused kernel: `shard_map` over the
-    'mp' mesh axis, q/arena/output split on their HEADS dim (axis 2) and
+                           interpret, k_scale=None, v_scale=None):
+    """Tensor-parallel dispatch of the fused kernels: `shard_map` over the
+    'mp' mesh axis, q/output split on their HEADS dim (axis 2), the arenas
+    (and, quantized, their scale arenas) on their kv-heads dim (axis 1),
     tables/pos replicated, so each device's `pallas_call` streams only its
     local kv heads' pages.  GSPMD cannot partition a custom call — without
     the shard_map it would all-gather the whole arena onto every device.
@@ -1125,48 +1172,34 @@ def _fused_paged_decode_tp(q, arena_k, arena_v, tables, pos, max_len, scale,
     to kv head `hk`, and contiguous 'mp' sharding of both head axes gives
     device d q heads [d*h/mp, (d+1)*h/mp) == the rep-block of its kv heads
     [d*hk/mp, (d+1)*hk/mp) — each local kernel is byte-identical to a
-    single-device kernel over a model with h/mp heads.  check_rep=False:
+    single-device kernel over a model with h/mp heads.  check_vma=False:
     tables/pos stay replicated but the output is genuinely sharded."""
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     from ..distributed import mesh as _mesh
 
-    heads = P(None, None, "mp", None)
-    fn = shard_map(
-        lambda qq, ak, av, t, p: _fused_paged_decode(
-            qq, ak, av, t, p, max_len, scale, interpret
-        ),
+    q_heads = P(None, None, "mp", None)
+    kv_heads = P(None, "mp", None, None)
+    if k_scale is None:
+        def body(qq, ak, av, t, p):
+            return _fused_paged_decode(
+                qq, ak, av, t, p, max_len, scale, interpret
+            )
+        ins, specs = (), ()
+    else:
+        def body(qq, ak, av, ks, vs, t, p):
+            return _fused_paged_decode_quant(
+                qq, ak, av, ks, vs, t, p, max_len, scale, interpret
+            )
+        ins, specs = (k_scale, v_scale), (kv_heads, kv_heads)
+    fn = jax.shard_map(
+        body,
         mesh=_mesh.get_mesh(),
-        in_specs=(heads, heads, heads, P(None, None), P(None)),
-        out_specs=heads,
-        check_rep=False,
+        in_specs=(q_heads, kv_heads, kv_heads) + specs + (P(None, None), P(None)),
+        out_specs=q_heads,
+        check_vma=False,
     )
-    return fn(q, arena_k, arena_v, tables, pos)
-
-
-def _fused_paged_decode_quant_tp(q, arena_k, arena_v, k_scale, v_scale,
-                                 tables, pos, max_len, scale, interpret, mp):
-    """Tensor-parallel dispatch of the QUANTIZED fused kernel: identical
-    shard_map contract to `_fused_paged_decode_tp`, with the scale arenas
-    riding the same kv-heads 'mp' sharding (their axis 2 is kv_heads too) —
-    each device dequantizes only its local heads' pages in VMEM."""
-    from jax.experimental.shard_map import shard_map
-    from jax.sharding import PartitionSpec as P
-
-    from ..distributed import mesh as _mesh
-
-    heads = P(None, None, "mp", None)
-    fn = shard_map(
-        lambda qq, ak, av, ks, vs, t, p: _fused_paged_decode_quant(
-            qq, ak, av, ks, vs, t, p, max_len, scale, interpret
-        ),
-        mesh=_mesh.get_mesh(),
-        in_specs=(heads, heads, heads, heads, heads, P(None, None), P(None)),
-        out_specs=heads,
-        check_rep=False,
-    )
-    return fn(q, arena_k, arena_v, k_scale, v_scale, tables, pos)
+    return fn(q, arena_k, arena_v, *ins, tables, pos)
 
 
 def _fused_paged_decode_partials_forward(q, arena_k, arena_v, tables,
@@ -1206,8 +1239,8 @@ def _fused_paged_decode_partials_forward(q, arena_k, arena_v, tables,
 
     quant = k_scale is not None
     b, sq, h, d = q.shape
-    ps = arena_k.shape[1]
-    hk = arena_k.shape[2]
+    hk = arena_k.shape[1]
+    ps = arena_k.shape[2]
     rep = h // hk
     P = tables.shape[1]
     R = rep * sq
@@ -1244,8 +1277,8 @@ def _fused_paged_decode_partials_forward(q, arena_k, arena_v, tables,
         def _compute():
             if quant:
                 qb = q_ref[...].astype(jnp.float32)
-                kb = k_ref[...].astype(jnp.float32) * ks_ref[...]
-                vb = v_ref[...].astype(jnp.float32) * vs_ref[...]
+                kb = k_ref[...].astype(jnp.float32)
+                vb = v_ref[...].astype(jnp.float32)
             else:
                 qb = q_ref[...]  # [qr, d]
                 kb = k_ref[...]  # [ps, d] — the page this table entry names
@@ -1253,7 +1286,10 @@ def _fused_paged_decode_partials_forward(q, arena_k, arena_v, tables,
             s = jax.lax.dot_general(
                 qb, kb, (((1,), (1,)), ((), ())),
                 preferred_element_type=jnp.float32,
-            ) * scale  # [qr, ps]
+            )  # [qr, ps]
+            if quant:
+                s = s * ks_ref[...]  # [1, ps] row scales on score columns
+            s = s * scale
             w = jax.lax.broadcasted_iota(jnp.int32, (qr, ps), 0) % sq
             jid = j0 + jax.lax.broadcasted_iota(jnp.int32, (qr, ps), 1)
             s = jnp.where((jid <= p0 + w) & (jid < max_len), s, _NEG_INF)
@@ -1264,7 +1300,7 @@ def _fused_paged_decode_partials_forward(q, arena_k, arena_v, tables,
             alpha = jnp.exp(m - m_new)
             m_scr[...] = m_new[..., None]
             l_scr[...] = (alpha * l + p.sum(-1))[..., None]
-            pv = p if quant else p.astype(vb.dtype)
+            pv = p * vs_ref[...] if quant else p.astype(vb.dtype)
             acc_scr[...] = acc_scr[...] * alpha[..., None] + jax.lax.dot_general(
                 pv, vb, (((1,), (0,)), ((), ())),
                 preferred_element_type=jnp.float32,
@@ -1280,10 +1316,10 @@ def _fused_paged_decode_partials_forward(q, arena_k, arena_v, tables,
             ol_ref[...] = l_scr[...]
 
     page_tile = pl.BlockSpec(
-        (None, ps, None, d), lambda s, g, j, t, bb, p: (t[s * P + j], 0, g, 0)
+        (None, None, ps, d), lambda s, g, j, t, bb, p: (t[s * P + j], g, 0, 0)
     )
     scale_tile = pl.BlockSpec(
-        (None, ps, None, 1), lambda s, g, j, t, bb, p: (t[s * P + j], 0, g, 0)
+        (None, None, 1, ps), lambda s, g, j, t, bb, p: (t[s * P + j], g, 0, 0)
     )
     q_tile = pl.BlockSpec(
         (None, None, qr, d), lambda s, g, j, t, bb, p: (s, g, 0, 0)
@@ -1429,7 +1465,6 @@ def _fused_paged_decode_cp_impl(q, arena_k, arena_v, tables, pos, max_len,
     kernel masks.  The per-shard partials then merge with ONE
     pmax + two psums over 'cp' (`cp_softmax_combine` math) — the only
     cross-device traffic the whole decode step adds."""
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     from ..distributed import mesh as _mesh
@@ -1437,15 +1472,15 @@ def _fused_paged_decode_cp_impl(q, arena_k, arena_v, tables, pos, max_len,
     quant = k_scale is not None
     num_pages = arena_k.shape[0]
     per_shard = num_pages // cp
-    ps = arena_k.shape[1]
+    hk = arena_k.shape[1]
+    ps = arena_k.shape[2]
     b, sq, h, d = q.shape
-    hk = arena_k.shape[2]
     rep = h // hk
     R = rep * sq
 
     mp_ax = "mp" if mp > 1 else None
     heads = P(None, None, mp_ax, None)
-    pages = P("cp", None, mp_ax, None)
+    pages = P("cp", mp_ax, None, None)
 
     def body(qq, ak, av, ks, vs, t, p):
         s = jax.lax.axis_index("cp")
@@ -1480,13 +1515,13 @@ def _fused_paged_decode_cp_impl(q, arena_k, arena_v, tables, pos, max_len,
         scale_spec = P()
     else:
         scale_spec = pages
-    fn = shard_map(
+    fn = jax.shard_map(
         body,
         mesh=_mesh.get_mesh(),
         in_specs=(heads, pages, pages, scale_spec, scale_spec,
                   P(None, None), P(None)),
         out_specs=heads,
-        check_rep=False,
+        check_vma=False,
     )
     return fn(q, arena_k, arena_v, k_scale, v_scale, tables, pos)
 
@@ -1590,10 +1625,10 @@ def paged_decode_attention_array(q, arena_k, arena_v, tables, pos, max_len,
     if kernel != "gather":
         from ..distributed import mesh as _mesh
 
-        ok, reason = _fused_paged_viable(q, arena_k.shape[1])
+        ok, reason = _fused_paged_viable(q, arena_k.shape[2])
         mp = _mesh.axis_size("mp")
         cp = _mesh.axis_size("cp")
-        if ok and mp > 1 and (q.shape[2] % mp or arena_k.shape[2] % mp):
+        if ok and mp > 1 and (q.shape[2] % mp or arena_k.shape[1] % mp):
             # engine construction validates this for serving; direct callers
             # (or a q-head count that packs unevenly) fall back to the
             # GSPMD-sharded gather path instead of a shard_map shape error
@@ -1618,20 +1653,15 @@ def paged_decode_attention_array(q, arena_k, arena_v, tables, pos, max_len,
                 )
             _log_pallas_call("paged_decode_fused_q8" if quant else
                              "paged_decode_fused")
-            if quant:
-                if mp > 1:
-                    return _fused_paged_decode_quant_tp(
-                        q, arena_k, arena_v, k_scale, v_scale, tables, pos,
-                        max_len, scale, interpret, mp,
-                    )
-                return _fused_paged_decode_quant(
-                    q, arena_k, arena_v, k_scale, v_scale, tables, pos,
-                    max_len, scale, interpret,
-                )
             if mp > 1:
                 return _fused_paged_decode_tp(
                     q, arena_k, arena_v, tables, pos, max_len, scale,
-                    interpret, mp,
+                    interpret, k_scale, v_scale,
+                )
+            if quant:
+                return _fused_paged_decode_quant(
+                    q, arena_k, arena_v, k_scale, v_scale, tables, pos,
+                    max_len, scale, interpret,
                 )
             return _fused_paged_decode(
                 q, arena_k, arena_v, tables, pos, max_len, scale, interpret
@@ -1649,8 +1679,8 @@ def paged_decode_attention_array(q, arena_k, arena_v, tables, pos, max_len,
         # the oracle's dequant is the same math the kernel runs in VMEM:
         # int8 rows * their gathered scale rows, q upcast to f32 so both
         # paths reduce at the same precision
-        k = k.astype(jnp.float32) * paged_gather_kv(k_scale, tables, max_len)
-        v = v.astype(jnp.float32) * paged_gather_kv(v_scale, tables, max_len)
+        k = k.astype(jnp.float32) * paged_gather_scale(k_scale, tables, max_len)
+        v = v.astype(jnp.float32) * paged_gather_scale(v_scale, tables, max_len)
         out = decode_attention_array(q.astype(jnp.float32), k, v, pos, scale)
         return out.astype(q.dtype)
     return decode_attention_array(q, k, v, pos, scale)
@@ -1832,6 +1862,8 @@ _FALLBACK_REASONS = (
     "attn_mask not key-padding",
     "q/k shapes differ",
     "head_dim > 256",
+    "heads not divisible by mp",
+    "decode kernel under an mp mesh",
     "paged head_dim > 256",
     "paged page_size not 8-aligned",
     "paged heads not divisible by mp",
@@ -1891,6 +1923,52 @@ def _log_pallas_fallback(reason, shape=None):
 _FORCE_INTERPRET = False
 
 
+def _per_device(local, bh_arrays, b_arrays, out_ndims):
+    """Run `local(*bh_arrays, *b_arrays)` once per device shard.
+
+    GSPMD cannot partition a Mosaic custom call ("Mosaic kernels cannot be
+    automatically partitioned"), so under a mesh the dense kernels are
+    `shard_map`ped over every mesh axis that is still automatic: batch
+    (dim 0) split over 'dp', heads (dim 1 of `bh_arrays`) over 'mp' —
+    attention is independent per (batch, head) — and everything else
+    replicated.  `b_arrays` are per-batch-row operands (segment ids, key
+    bias; None entries allowed).  `local` returns a tuple of arrays laid out
+    [batch, heads, ...] of ranks `out_ndims`.  Without a mesh, or inside a
+    `shard_map` that has already made every axis manual, the call is direct.
+    `_pallas_viable` has checked that the heads divide 'mp'."""
+    from jax.sharding import PartitionSpec as P
+
+    from ..distributed import mesh as _mesh
+
+    mesh = _mesh.get_mesh()
+    if mesh is None or mesh.size == 1:
+        return local(*bh_arrays, *b_arrays)
+    ctx = jax.sharding.get_abstract_mesh()
+    manual = set() if ctx.empty else set(ctx.manual_axes)
+    auto = set(mesh.axis_names) - manual
+    if not auto:
+        return local(*bh_arrays, *b_arrays)
+    b, h = bh_arrays[0].shape[:2]
+
+    def axis(name, n):
+        ok = name in auto and mesh.shape[name] > 1 and n % mesh.shape[name] == 0
+        return name if ok else None
+
+    dp, mp = axis("dp", b), axis("mp", h)
+    bh_spec = lambda ndim: P(dp, mp, *([None] * (ndim - 2)))
+    in_specs = tuple(bh_spec(x.ndim) for x in bh_arrays) + tuple(
+        None if x is None else P(dp, *([None] * (x.ndim - 1))) for x in b_arrays
+    )
+    out_specs = tuple(bh_spec(n) for n in out_ndims)
+    # inside a partial-manual region the context mesh must be used as is
+    where = {} if manual else {"mesh": mesh}
+    fn = jax.shard_map(
+        local, in_specs=in_specs, out_specs=out_specs, axis_names=auto,
+        check_vma=False, **where,
+    )
+    return fn(*bh_arrays, *b_arrays)
+
+
 def _key_padding_bias(mask, b, sk):
     """If `mask` is a plain key-padding mask — additive, broadcast over the
     q rows and heads, i.e. shape [mb, 1, 1, sk] with mb in {1, b} — lower it
@@ -1948,6 +2026,12 @@ def _pallas_viable(q, k, mask, kbias):
         return False, "q/k shapes differ"
     if d > 256:
         return False, "head_dim > 256"
+    from ..distributed import mesh as _mesh
+
+    if q.shape[1] % _mesh.axis_size("mp"):
+        # under an 'mp' mesh the kernels are mapped per device over heads
+        # (_per_device); heads that do not divide cannot be
+        return False, "heads not divisible by mp"
     return True, None
 
 
@@ -1982,18 +2066,22 @@ def _flash_fwd_impl(q, k, v, mask, segments, causal, scale):
                 q, k, v, segments, kbias
             )
             _log_pallas_call("flash_fwd")
-            qf = qp.reshape(b * h, s_pad, d)
-            kf = kp.reshape(b * h, s_pad, d)
-            vf = vp.reshape(b * h, s_pad, d)
-            segf = _seg_flat(segp, h) if segp is not None else None
-            kbf = kbp[:, :, None] if kbp is not None else None
-            out, lse = _pallas_flash_forward(
-                qf, kf, vf, causal, scale, segments=segf, n_heads=h,
-                interpret=interpret, kbias=kbf,
-            )
-            out = out.reshape(b, h, s_pad, d)[:, :, :s]
-            lse = lse.reshape(b, h, s_pad)[:, :, :s]
-            return out, lse, True
+
+            def local(qx, kx, vx, segx, kbx):
+                bl, hl = qx.shape[:2]  # this device's batch rows and heads
+                out, lse = _pallas_flash_forward(
+                    qx.reshape(bl * hl, s_pad, d),
+                    kx.reshape(bl * hl, s_pad, d),
+                    vx.reshape(bl * hl, s_pad, d),
+                    causal, scale,
+                    segments=None if segx is None else _seg_flat(segx, hl),
+                    n_heads=hl, interpret=interpret,
+                    kbias=None if kbx is None else kbx[:, None, :],
+                )
+                return out.reshape(bl, hl, s_pad, d), lse.reshape(bl, hl, s_pad)
+
+            out, lse = _per_device(local, (qp, kp, vp), (segp, kbp), (4, 3))
+            return out[:, :, :s], lse[:, :, :s], True
         _log_pallas_fallback(reason, shape=q.shape)
     if segments is not None:
         seg_mask = _segments_mask(segments, b, h)
@@ -2023,29 +2111,25 @@ def _flash_bwd_rule(causal, scale, res, g):
             gp = jnp.pad(g, ((0, 0), (0, 0), (0, pad), (0, 0)))
             outp = jnp.pad(out, ((0, 0), (0, 0), (0, pad), (0, 0)))
             lsep = jnp.pad(lse, ((0, 0), (0, 0), (0, pad)))
-        segf = _seg_flat(segp, h) if segp is not None else None
-        kbf = kbp[:, :, None] if kbp is not None else None
         _log_pallas_call("flash_bwd")
-        dq, dk, dv = _pallas_flash_backward(
-            qp.reshape(b * h, s_pad, d),
-            kp.reshape(b * h, s_pad, d),
-            vp.reshape(b * h, s_pad, d),
-            gp.reshape(b * h, s_pad, d),
-            outp.reshape(b * h, s_pad, d),
-            lsep.reshape(b * h, s_pad, 1),
-            causal,
-            scale,
-            segments=segf,
-            n_heads=h,
-            interpret=_FORCE_INTERPRET,
-            kbias=kbf,
+
+        def local(qx, kx, vx, gx, ox, lx, segx, kbx):
+            bl, hl = qx.shape[:2]  # this device's batch rows and heads
+            flat = lambda x: x.reshape(bl * hl, s_pad, d)
+            dq, dk, dv = _pallas_flash_backward(
+                flat(qx), flat(kx), flat(vx), flat(gx), flat(ox),
+                lx.reshape(bl * hl, s_pad, 1), causal, scale,
+                segments=None if segx is None else _seg_flat(segx, hl),
+                n_heads=hl, interpret=_FORCE_INTERPRET,
+                kbias=None if kbx is None else kbx[:, None, :],
+            )
+            return tuple(x.reshape(bl, hl, s_pad, d) for x in (dq, dk, dv))
+
+        dq, dk, dv = _per_device(
+            local, (qp, kp, vp, gp, outp, lsep), (segp, kbp), (4, 4, 4)
         )
         return (
-            dq.reshape(b, h, s_pad, d)[:, :, :s],
-            dk.reshape(b, h, s_pad, d)[:, :, :s],
-            dv.reshape(b, h, s_pad, d)[:, :, :s],
-            None,
-            None,
+            dq[:, :, :s], dk[:, :, :s], dv[:, :, :s], None, None,
         )
     if segments is not None:
         seg_mask = _segments_mask(segments, q.shape[0], q.shape[1])

@@ -191,7 +191,11 @@ class Autoscaler:
     (default: boot a `ReplicaProcess` subprocess worker and wrap it);
     `stop_fn(replica)` tears one down after its drain (default: SIGTERM
     the managed process).  Injecting both keeps the control law testable
-    with in-process replicas — the loop itself never cares which."""
+    with in-process replicas — the loop itself never cares which.
+
+    `devices_total` is the number of chips this fleet may claim, given as
+    data: a chip belongs to one process, so the controller's process —
+    which spawns the workers that need the chips — must never ask jax."""
 
     def __init__(self, router, spawn_fn=None, stop_fn=None, *,
                  min_replicas=None, max_replicas=None, interval=None,
@@ -243,11 +247,11 @@ class Autoscaler:
         }
         self.tp_max = _pick(tp_max, "FLAGS_autoscale_tp_max", int)
         if devices_total is None:
-            try:
-                import jax
-                devices_total = jax.device_count()
-            except Exception:
-                devices_total = 1
+            raise ValueError(
+                "devices_total is required: counting devices here would "
+                "initialise jax in the controller's process and take the "
+                "chip away from the replicas it spawns"
+            )
         self.devices_total = int(devices_total)
         self.cfg["chips"] = max(1, self.devices_total)
         self.kv_heads = kv_heads

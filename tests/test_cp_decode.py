@@ -146,8 +146,8 @@ def _cp_arena(cp=2, num_pages=16, ps=8, hk=2, d=16, b=3, P=4, seed=0,
     [s*per_shard, (s+1)*per_shard), page s*per_shard being scratch)."""
     r = np.random.RandomState(seed)
     per = num_pages // cp
-    k = r.randn(num_pages, ps, hk, d).astype(np.float32)
-    v = r.randn(num_pages, ps, hk, d).astype(np.float32)
+    k = r.randn(num_pages, hk, ps, d).astype(np.float32)
+    v = r.randn(num_pages, hk, ps, d).astype(np.float32)
     for s in range(cp):  # scratch pages stay zero, like a live pool
         k[s * per] = 0.0
         v[s * per] = 0.0
@@ -162,11 +162,10 @@ def _cp_arena(cp=2, num_pages=16, ps=8, hk=2, d=16, b=3, P=4, seed=0,
     ka, va = jnp.asarray(k), jnp.asarray(v)
     if not quant:
         return ka, va, jnp.asarray(tables), None, None
-    kq, ks = _quantize_kv_rows(ka.reshape(num_pages * ps, hk, d))
-    vq, vs = _quantize_kv_rows(va.reshape(num_pages * ps, hk, d))
-    return (kq.reshape(num_pages, ps, hk, d), vq.reshape(num_pages, ps, hk, d),
-            jnp.asarray(tables), ks.reshape(num_pages, ps, hk, 1),
-            vs.reshape(num_pages, ps, hk, 1))
+    kq, ks = _quantize_kv_rows(ka)  # scales [num_pages, hk, ps, 1]
+    vq, vs = _quantize_kv_rows(va)
+    return (kq, vq, jnp.asarray(tables), jnp.swapaxes(ks, 2, 3),
+            jnp.swapaxes(vs, 2, 3))
 
 
 @pytest.mark.parametrize("sq", [1, 3])  # plain decode and a verify window
@@ -206,8 +205,8 @@ def test_cp_indivisible_shapes_fall_back_to_gather():
     the GSPMD gather path with the typed fallback reason — never a
     shard_map shape error."""
     r = np.random.RandomState(8)
-    ka = jnp.asarray(r.randn(7, 8, 2, 16).astype(np.float32))  # 7 % 2 != 0
-    va = jnp.asarray(r.randn(7, 8, 2, 16).astype(np.float32))
+    ka = jnp.asarray(r.randn(7, 2, 8, 16).astype(np.float32))  # 7 % 2 != 0
+    va = jnp.asarray(r.randn(7, 2, 8, 16).astype(np.float32))
     tables = jnp.asarray([[1, 2, 3]], jnp.int32)
     q = jnp.asarray(r.randn(1, 1, 4, 16).astype(np.float32))
     pos = jnp.asarray([10], jnp.int32)

@@ -252,14 +252,13 @@ class TestCollectives:
     def test_allreduce_inside_shard_map(self):
         pmesh.build_mesh(dp=8)
         import jax.numpy as jnp
-        from jax.experimental.shard_map import shard_map
 
         mesh = pmesh.get_mesh()
 
         def f(x):
             return jax.lax.psum(x, "dp")
 
-        fn = shard_map(f, mesh=mesh, in_specs=P("dp"), out_specs=P())
+        fn = jax.shard_map(f, mesh=mesh, in_specs=P("dp"), out_specs=P())
         x = jnp.arange(8.0)
         out = fn(x)
         assert float(out[0]) == 28.0

@@ -65,7 +65,7 @@ def _paged(model, **kw):
 
 def _arena(num_pages=9, ps=8, hk=2, d=16, seed=0):
     r = np.random.RandomState(seed)
-    mk = lambda: jnp.asarray(r.rand(num_pages, ps, hk, d).astype(np.float32) - 0.5)
+    mk = lambda: jnp.asarray(r.rand(num_pages, hk, ps, d).astype(np.float32) - 0.5)
     return mk(), mk()
 
 
@@ -141,7 +141,7 @@ class TestFusedVsGather:
         # 'fused' must refuse, not silently degrade, when ineligible
         with pytest.raises(ValueError, match="fused"):
             fa.paged_decode_attention_array(
-                q, ak[:, :4], av[:, :4], t, jnp.int32(0), 32, kernel="fused"
+                q, ak[:, :, :4], av[:, :, :4], t, jnp.int32(0), 32, kernel="fused"
             )  # page_size 4: not sublane-aligned
 
     def test_auto_dispatch_counts_pallas_call(self):
@@ -160,7 +160,7 @@ class TestFusedVsGather:
         assert profiler.flash_fallback_summary() == {}
         with _interpret():  # ineligible page size -> counted fallback
             fa.paged_decode_attention_array(
-                q, ak[:, :4], av[:, :4], t, jnp.int32(5), 16
+                q, ak[:, :, :4], av[:, :, :4], t, jnp.int32(5), 16
             )
         assert (
             profiler.flash_fallback_summary()["paged page_size not 8-aligned"]

@@ -115,8 +115,8 @@ def test_quantize_roundtrip_error_bound():
 def test_paged_cache_int8_layout():
     c = PagedKVCache(4, 8, 2, 16, "float32", quant="int8")
     assert c.quant == "int8"
-    assert tuple(c.k.shape) == (4, 8, 2, 16) and str(c.k.dtype) == "int8"
-    assert tuple(c.k_scale.shape) == (4, 8, 2, 1)
+    assert tuple(c.k.shape) == (4, 2, 8, 16) and str(c.k.dtype) == "int8"
+    assert tuple(c.k_scale.shape) == (4, 2, 1, 8)
     assert str(c.k_scale.dtype) == "float32"
     full = PagedKVCache(4, 8, 2, 16, "float32")
     assert full.quant == "none" and full.k_scale is None
@@ -131,10 +131,10 @@ def _quant_arena(num_pages=9, ps=8, hk=2, d=16, seed=0):
     """int8 arenas + realistic per-row scale arenas (scratch page 0 kept
     all-zero with scale 1, like the engine's freshly-allocated pool)."""
     r = np.random.RandomState(seed)
-    qk = r.randint(-127, 128, size=(num_pages, ps, hk, d)).astype(np.int8)
-    qv = r.randint(-127, 128, size=(num_pages, ps, hk, d)).astype(np.int8)
-    sk = (r.rand(num_pages, ps, hk, 1).astype(np.float32) * 0.02) + 1e-4
-    sv = (r.rand(num_pages, ps, hk, 1).astype(np.float32) * 0.02) + 1e-4
+    qk = r.randint(-127, 128, size=(num_pages, hk, ps, d)).astype(np.int8)
+    qv = r.randint(-127, 128, size=(num_pages, hk, ps, d)).astype(np.int8)
+    sk = (r.rand(num_pages, hk, 1, ps).astype(np.float32) * 0.02) + 1e-4
+    sv = (r.rand(num_pages, hk, 1, ps).astype(np.float32) * 0.02) + 1e-4
     qk[0] = 0
     qv[0] = 0
     sk[0] = 1.0

@@ -1,0 +1,196 @@
+"""Every Pallas entry point must lower for the TPU from the CPU host.
+
+`jax.export.export(..., platforms=["tpu"])` runs the Pallas -> Mosaic
+lowering without a chip, so a block shape the TPU lowering refuses (the
+squeezed second-minor kv_heads dim of the old `[pages, page_size, kv_heads,
+head_dim]` arena was refused for every kv_heads > 1) fails here, in seconds,
+instead of in `engine.warmup()` on the chip.
+
+The lowering does not run libtpu's Mosaic compiler, which has limits of its
+own (scoped VMEM, tile shapes).  The slow-marked twin below does: it
+compiles the same cases ahead of time for a v5e topology that libtpu
+describes without a chip.  Run it before spending chip time on a kernel
+change:
+
+    pytest tests/test_pallas_tpu_lowering.py -m slow
+"""
+
+import functools
+import math
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax import export
+from jax.sharding import NamedSharding, PartitionSpec as P, SingleDeviceSharding
+
+from paddle_tpu.distributed import mesh as pmesh
+from paddle_tpu.ops import flash_attention as fa
+
+BF16 = jnp.bfloat16
+MAX_LEN = 1024
+
+
+@pytest.fixture(autouse=True)
+def _dispatch_as_on_tpu(monkeypatch):
+    """The dispatchers pick the Pallas path from the backend; take it here
+    so the public wrappers (custom_vjp, padding, shard_map) lower too."""
+    monkeypatch.setattr(fa, "_on_tpu", lambda: True)
+    prev = pmesh.get_mesh()
+    yield
+    pmesh.set_mesh(prev)
+
+
+# ---------------------------------------------------------------------------
+# cases: (id, fn, [(shape, dtype, partition spec or None), ...], mesh degrees)
+# ---------------------------------------------------------------------------
+
+
+def _sq_loss(out):
+    return (out.astype(jnp.float32) ** 2).mean()
+
+
+def _flash_grad(causal, segments=False, kbias=False):
+    def loss(q, k, v, *extra):
+        seg = extra[0] if segments else None
+        mask = extra[0] if kbias else None
+        return _sq_loss(fa.sdpa_array(q, k, v, mask, causal, None, segment_ids=seg))
+
+    return jax.grad(loss, argnums=(0, 1, 2))
+
+
+def _flash_cases():
+    b, s, h, d = 8, 2048, 16, 128  # the bench_llama attention block
+    qkv = [((b, s, h, d), BF16, None)] * 3
+    yield "flash-causal", _flash_grad(True), qkv, None
+    yield "flash-noncausal", _flash_grad(False), qkv, None
+    yield ("flash-segments", _flash_grad(True, segments=True),
+           qkv + [((b, s), jnp.int32, None)], None)
+    yield ("flash-keybias", _flash_grad(False, kbias=True),
+           qkv + [((b, 1, 1, s), jnp.float32, None)], None)
+    # ragged: a non-128-multiple sequence takes the pad-and-fence path
+    yield "flash-ragged", _flash_grad(True), [((4, 200, h, d), BF16, None)] * 3, None
+    # under a mesh GSPMD cannot partition a Mosaic call: the dispatcher
+    # must shard_map it (hybrid training; tensor-parallel prefill, b=1)
+    sharded = [((b, s, 20, d), BF16, P("dp", None, "mp", None))] * 3
+    yield "flash-dp2-mp2", _flash_grad(True), sharded, {"dp": 2, "mp": 2}
+    yield ("flash-prefill-mp4",
+           lambda q, k, v: fa.sdpa_array(q, k, v, None, True, None),
+           [((1, 512, h, d), BF16, P(None, None, "mp", None))] * 3, {"mp": 4})
+    for sq in (64, 512):
+        yield (
+            f"decode-q{sq}",
+            lambda q, k, v, p: fa.decode_attention_array(q, k, v, p),
+            [((1, sq, h, d), BF16, None), ((1, MAX_LEN, h, d), BF16, None),
+             ((1, MAX_LEN, h, d), BF16, None), ((), jnp.int32, None)],
+            None,
+        )
+
+
+def _paged_fn(quant):
+    if quant:
+        return lambda q, ak, av, t, p, ks, vs: fa.paged_decode_attention_array(
+            q, ak, av, t, p, MAX_LEN, kernel="fused", k_scale=ks, v_scale=vs)
+    return lambda q, ak, av, t, p: fa.paged_decode_attention_array(
+        q, ak, av, t, p, MAX_LEN, kernel="fused")
+
+
+def _paged_args(b, sq, h, hk, ps, quant, cp=1, mp=1):
+    d = 128
+    n_tab = MAX_LEN // ps
+    pages = (b * n_tab // cp + 1) * cp
+    mp_ax = "mp" if mp > 1 else None
+    arena = P("cp" if cp > 1 else None, mp_ax, None, None)
+    dt = jnp.int8 if quant else BF16
+    args = [
+        ((b, sq, h, d), BF16, P(None, None, mp_ax, None)),
+        ((pages, hk, ps, d), dt, arena),
+        ((pages, hk, ps, d), dt, arena),
+        ((b, n_tab), jnp.int32, P()),
+        ((b,), jnp.int32, P()),
+    ]
+    if quant:
+        args += [((pages, hk, 1, ps), jnp.float32, arena)] * 2
+    return args
+
+
+def _partials_fn(quant):
+    def f(q, ak, av, t, p, *scales):
+        base = jnp.arange(t.shape[1], dtype=jnp.int32) * ak.shape[2]
+        ks, vs = scales if quant else (None, None)
+        return fa._fused_paged_decode_partials_forward(
+            q, ak, av, t, base, p, MAX_LEN, 0.1, k_scale=ks, v_scale=vs)
+
+    return f
+
+
+def _paged_cases():
+    for h, hk in ((16, 16), (32, 8)):
+        for ps in (8, 128):
+            for quant in (False, True):
+                tag = f"h{h}-kv{hk}-ps{ps}-{'int8' if quant else 'bf16'}"
+                # slot-batched decode, and a chunk prefill (b=1, q rows =
+                # the bucket) — the two shapes the engine sends
+                yield (f"paged-decode-{tag}", _paged_fn(quant),
+                       _paged_args(8, 1, h, hk, ps, quant), None)
+                yield (f"paged-chunk-{tag}", _paged_fn(quant),
+                       _paged_args(1, 256, h, hk, ps, quant), None)
+                yield (f"paged-partials-{tag}", _partials_fn(quant),
+                       _paged_args(8, 1, h, hk, ps, quant), None)
+    for cp, mp in ((1, 4), (2, 2)):  # the shard_map wrappers
+        for quant in (False, True):
+            yield (f"paged-cp{cp}-mp{mp}-{'int8' if quant else 'bf16'}",
+                   _paged_fn(quant),
+                   _paged_args(8, 1, 16, 16, 128, quant, cp=cp, mp=mp),
+                   {"cp": cp, "mp": mp})
+
+
+CASES = list(_flash_cases()) + list(_paged_cases())
+IDS = [c[0] for c in CASES]
+
+
+def _avals(args, degrees, devices):
+    """ShapeDtypeStructs for a case.  With mesh `degrees` the mesh is
+    installed over the first of `devices` and every operand carries its
+    NamedSharding — which makes the lowering multi-device, where a Mosaic
+    call outside a shard_map is refused."""
+    if degrees:
+        n = math.prod(degrees.values())
+        mesh = pmesh.build_mesh(devices=list(devices)[:n], **degrees)
+        sharding_for = lambda spec: NamedSharding(mesh, spec or P())
+    else:
+        sharding_for = lambda spec: SingleDeviceSharding(devices[0])
+    return [
+        jax.ShapeDtypeStruct(shape, dt, sharding=sharding_for(spec))
+        for shape, dt, spec in args
+    ]
+
+
+@pytest.mark.parametrize("case", CASES, ids=IDS)
+def test_lowers_for_tpu(case):
+    _, fn, args, degrees = case
+    exported = export.export(jax.jit(fn), platforms=["tpu"])(
+        *_avals(args, degrees, jax.devices())
+    )
+    assert "tpu_custom_call" in exported.mlir_module()
+
+
+@functools.lru_cache(maxsize=1)
+def _v5e_devices():
+    from jax.experimental import topologies
+
+    try:
+        topo = topologies.get_topology_desc(
+            platform="tpu", topology_name="v5e:2x2"
+        )
+    except Exception as e:  # no libtpu in this installation
+        pytest.skip(f"no TPU topology description available: {e}")
+    return tuple(topo.devices)
+
+
+@pytest.mark.slow
+@pytest.mark.parametrize("case", CASES, ids=IDS)
+def test_compiles_for_v5e(case):
+    """Full XLA + Mosaic compile for `TPU v5 lite` without a chip."""
+    _, fn, args, degrees = case
+    jax.jit(fn).lower(*_avals(args, degrees, _v5e_devices())).compile()

@@ -269,8 +269,8 @@ def test_fused_shard_map_matches_gather_oracle(engines):
     assert _mesh.axis_size("mp") == 4
     rng = np.random.RandomState(0)
     pages, ps, hk, d, slots = 9, 8, 4, 16, 3
-    ak = rng.randn(pages, ps, hk, d).astype(np.float32)
-    av = rng.randn(pages, ps, hk, d).astype(np.float32)
+    ak = rng.randn(pages, hk, ps, d).astype(np.float32)
+    av = rng.randn(pages, hk, ps, d).astype(np.float32)
     q = rng.randn(slots, 1, hk, d).astype(np.float32)
     tables = np.array([[1, 2, 0], [3, 4, 0], [5, 6, 0]], np.int32)
     pos = np.array([13, 9, 17], np.int32)

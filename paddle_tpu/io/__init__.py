@@ -752,6 +752,12 @@ class DataLoader:
                     p.terminate()
             for p in procs:
                 p.join(timeout=5)
+                if p.is_alive():
+                    # forked with the parent's SIGTERM handler and wedged on
+                    # an inherited lock, a worker never runs it: SIGTERM is
+                    # swallowed, and the orphan keeps the parent's stderr open
+                    p.kill()
+                    p.join(timeout=5)
 
 
 class _MPUnavailable(RuntimeError):

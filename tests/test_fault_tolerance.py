@@ -381,7 +381,7 @@ class TestTrainingIntegration:
         opt2.set_state_dict(sd2["opt"])
         np.testing.assert_allclose(net2.weight.numpy(), w_step2, rtol=1e-6)
 
-    def test_sigterm_mid_step_graceful_checkpoint_exit75(self, tmp_path):
+    def test_sigterm_mid_step_graceful_checkpoint_exit75(self, tmp_path, run_child):
         """SIGTERM a live supervised trainer: it must commit a best-effort
         checkpoint and exit with the restart-requested code (75)."""
         root = tmp_path / "ckpt"
@@ -403,21 +403,17 @@ class TestTrainingIntegration:
             "    time.sleep(0.02)\n"
             "    sup.after_step(0.5)\n"
         )
-        proc = subprocess.Popen([sys.executable, str(script)], env=_env(),
-                                stdout=subprocess.PIPE,
-                                stderr=subprocess.STDOUT, text=True)
+        trainer = run_child([sys.executable, str(script)], env=_env())
         deadline = time.time() + 120
         while not (tmp_path / "ready").exists():
             assert time.time() < deadline, "trainer never came up"
-            assert proc.poll() is None, proc.stdout.read()
+            assert trainer.proc.poll() is None, "trainer died before its first step"
             time.sleep(0.1)
         time.sleep(0.3)  # let it take a few steps
-        proc.send_signal(signal.SIGTERM)
-        rc = proc.wait(timeout=60)
-        out = proc.stdout.read()
-        assert rc == fault.RESTART_EXIT_CODE, (rc, out)
+        trainer.send_signal(signal.SIGTERM)
+        assert trainer.wait(60) == fault.RESTART_EXIT_CODE
         latest = ckpt.find_latest_valid(str(root))
-        assert latest is not None, f"no checkpoint committed: {out}"
+        assert latest is not None, "no checkpoint committed"
         dst = {"w": paddle.to_tensor(np.zeros(4, np.float32))}
         assert ckpt.load_latest(dst, str(root)) == latest[0]
         np.testing.assert_allclose(dst["w"].numpy(), np.ones(4))

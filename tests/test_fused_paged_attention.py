@@ -413,17 +413,19 @@ class TestWidenedGate:
 
 def test_decode_zero_copy_when_aligned():
     """The hoisted padding check: an already-8-aligned q chunk must reach
-    the Pallas decode kernel with NO pad/slice in the traced program; a
-    ragged one pads (and slices) as before."""
+    the Pallas decode kernel with NO pad in the traced program and the
+    kernel sees its rows as they are; a ragged one pads to the next sublane
+    tile (65 -> 72 rows, no further) and is sliced back."""
     k = jnp.zeros((1, 128, 2, 32), jnp.float32)
     v = jnp.zeros((1, 128, 2, 32), jnp.float32)
 
     def prims(jaxpr, acc):
-        """Primitive names, recursing through pjit wrappers (jnp.pad hides
-        inside one) but NOT into the pallas kernel body."""
+        """(primitive, output shape), recursing through call wrappers
+        (jnp.pad hides inside one: `pjit`, named `jit` since jax 0.9) but
+        NOT into the pallas kernel body."""
         for e in jaxpr.eqns:
-            acc.add(e.primitive.name)
-            if e.primitive.name == "pjit":
+            acc.append((e.primitive.name, tuple(e.outvars[0].aval.shape)))
+            if e.primitive.name in ("pjit", "jit"):
                 prims(e.params["jaxpr"].jaxpr, acc)
         return acc
 
@@ -433,10 +435,15 @@ def test_decode_zero_copy_when_aligned():
             jx = jax.make_jaxpr(
                 lambda q, k, v: fa.decode_attention_array(q, k, v, jnp.int32(0))
             )(q, k, v)
-        return prims(jx.jaxpr, set())
+        return prims(jx.jaxpr, [])
 
-    assert "pad" not in run(64)
-    assert "pad" in run(65)  # pads up to 72 rows
+    aligned = run(64)
+    assert "pad" not in [name for name, _ in aligned]
+    assert ("pallas_call", (2, 64, 32)) in aligned
+    ragged = run(65)
+    assert ("pad", (2, 72, 32)) in ragged
+    assert ("pallas_call", (2, 72, 32)) in ragged
+    assert ("slice", (2, 65, 32)) in ragged  # the padded rows never leave
 
 
 def test_check_table_bounds():

@@ -19,7 +19,9 @@ class _DS(Dataset):
 
 
 def test_multiprocess_workers_order_and_values():
-    loader = DataLoader(_DS(), batch_size=4, num_workers=2, shuffle=False)
+    # timeout: a forked worker wedged on a lock it inherited (rare, after
+    # jax's threads exist) is this test's failure in a minute, not a hang
+    loader = DataLoader(_DS(), batch_size=4, num_workers=2, shuffle=False, timeout=60)
     seen = []
     for xb, yb in loader:
         assert xb.shape == [4, 3]
@@ -36,7 +38,7 @@ def test_multiprocess_worker_error_surfaces():
 
     import pytest
 
-    loader = DataLoader(Bad(), batch_size=4, num_workers=2)
+    loader = DataLoader(Bad(), batch_size=4, num_workers=2, timeout=60)
     with pytest.raises(RuntimeError, match="worker failed"):
         list(loader)
 

@@ -368,7 +368,14 @@ def test_span_completions_noted_in_flight_ring(model):
             url, {"input_ids": _prompt(6).tolist(), "max_new_tokens": 2}
         )
         assert status == 200
-        spans = [e for e in flight.events() if e["kind"] == "span"]
+        # the handler closes serve.handle AFTER it has written the response:
+        # the client can be back here first
+        deadline = time.time() + 5
+        while True:
+            spans = [e for e in flight.events() if e["kind"] == "span"]
+            if any(e["detail"] == "serve.handle" for e in spans) or time.time() > deadline:
+                break
+            time.sleep(0.01)
         # serve.handle is a flight-noted kind; engine.* stage spans are not
         # (they would flood the ring)
         assert any(e["detail"] == "serve.handle" for e in spans)

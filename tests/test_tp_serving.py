@@ -216,8 +216,9 @@ def test_flight_dump_header_carries_mesh(engines, tmp_path):
     path = flight.dump("tp-test", path=str(tmp_path / "f.jsonl"))
     with open(path) as f:
         header = json.loads(f.readline())
+    # cp rides along since the context-parallel engine: 1 = decode not cp-sharded
     assert header["mesh"] == {
-        "devices": 8, "tp": 4, "allreduce_per_step": 5,
+        "devices": 8, "tp": 4, "cp": 1, "allreduce_per_step": 5,
     }
 
 

@@ -165,6 +165,29 @@ def control_gap(cfg, seed, sample):
     return worst
 
 
+def read_spans(ctx):
+    """The program's spans that intersect the traced window, in the order
+    they were recorded: a span outside it overlaps no idle gap.  Read when
+    the timed window closes and not when the traced one does: `engine.decode`
+    spans are recorded when a decode epoch closes, and the ring behind them
+    drops its oldest first, which the drain's spans would push out."""
+    from paddle_tpu.obs import trace as obs
+
+    stats = obs.stats()
+    # spans carry wall-clock starts; the harness's clock is perf_counter
+    wall_to_perf = time.perf_counter() - time.time()
+    lo, hi = ctx.trace_window
+    for s in obs.spans():
+        a = s["ts"] + wall_to_perf
+        if a < hi and a + s["dur_s"] > lo:
+            ctx.spans.append((s["name"], a, a + s["dur_s"]))
+    ctx.log(f"{len(ctx.spans)} spans meet the traced window, of {stats['spans_recorded']} "
+            f"recorded and {stats['spans_dropped']} dropped")
+    if stats["spans_dropped"]:
+        ctx.log("SPANS WERE DROPPED: the ring lost its oldest, the traced window's first; "
+                "the idle gaps' labels are INCOMPLETE")
+
+
 def run(ctx):
     import paddle_tpu as paddle
     from paddle_tpu import profiler
@@ -200,17 +223,13 @@ def run(ctx):
     t1 = time.perf_counter()
     serving = profiler.serving_summary()
     log_memory(ctx, "window closed")
+    if ctx.tracing:
+        read_spans(ctx)
+    t_drain = time.perf_counter()
     drained = clients.drain()
     counts = engine.compile_counts()
-    ctx.log(f"window {t1 - t0:.3f}s closed, drained={drained}, {len(clients.records)} requests")
-    if ctx.tracing:
-        from paddle_tpu.obs import trace as obs
-
-        # spans carry wall-clock starts; the harness's clock is perf_counter
-        wall_to_perf = time.perf_counter() - time.time()
-        for s in obs.spans():
-            a = s["ts"] + wall_to_perf
-            ctx.spans.append((s["name"], a, a + s["dur_s"]))
+    ctx.log(f"window {t1 - t0:.3f}s closed, drained={drained} in "
+            f"{time.perf_counter() - t_drain:.1f}s, {len(clients.records)} requests")
     engine.stop()
 
     records = [r for r in clients.records if r.submit_t is not None]

@@ -123,8 +123,12 @@ def reduce_trace(ctx):
     from benchmarks import trace_reduce
 
     try:
+        t = time.perf_counter()
         profile = trace_reduce.load(trace_reduce.find_xplane(ctx.trace_dir))
-        ctx.trace = trace_reduce.reduce(profile, ctx.spans, ctx.trace_window)
+        ctx.log(f"trace loaded in {time.perf_counter() - t:.1f}s")
+        t = time.perf_counter()
+        ctx.trace = trace_reduce.reduce(profile, ctx.spans, ctx.trace_window, log=ctx.log)
+        ctx.log(f"trace reduced in {time.perf_counter() - t:.1f}s")
     finally:  # a trace is tens of MB; a check makes hundreds of runs
         shutil.rmtree(ctx.trace_dir, ignore_errors=True)
 
@@ -148,7 +152,10 @@ def run_cell(ctx, metrics, e2e_names, root=HERE):
     if ctx.tracing:
         reduce_trace(ctx)
         device.update(busy_s=ctx.trace["busy_s"], window_s=ctx.trace["window_s"])
+        t = time.perf_counter()
         values = read_metrics(ctx, metrics, root)
+        ctx.log(f"{len(values)} of {len(metrics)} per-layer metrics read in "
+                f"{time.perf_counter() - t:.1f}s")
     else:
         missing = [n for n in e2e_names if n not in outcome["end_to_end"]]
         if missing:

@@ -4,7 +4,9 @@ the result line).  Tiny sizes, one process, no child, no TPU topology."""
 
 import io
 import json
+import random
 import shutil
+import time
 from contextlib import redirect_stdout
 
 import numpy as np
@@ -127,13 +129,105 @@ def test_union_gaps_and_labels():
     assert tr.label_gap((8, 10), spans) == "host (no span)"
 
 
+def seeded(seeds, grid=None, reverse=False):
+    """Gaps and spans from each seed: nested spans, twins that overlap a gap
+    equally, spans of no length, spans wholly outside the window, gaps that no
+    span touches; on a `grid` most overlaps tie and the list's order decides."""
+    for seed in seeds:
+        r = random.Random(seed)
+        point = (lambda: float(r.randrange(grid))) if grid else (lambda: r.uniform(0, 1000))
+        cuts = sorted({point() for _ in range(2 * r.randrange(1, 60))})
+        gaps = [(cuts[i], cuts[i + 1]) for i in range(0, len(cuts) - 1, 2)]
+        spans = []
+        for i in range(r.randrange(1, 80)):
+            a = 0.8 * point() + r.choice([-1200.0, 1200.0]) * (r.random() < 0.1)
+            kind = r.random()
+            d = r.uniform(0, 3) if kind < 0.6 else r.uniform(0, 300) if kind < 0.95 else 0.0
+            d = float(int(d)) if grid else d
+            spans.append((f"s{i % 7}", a, a + d))
+            if r.random() < 0.3:
+                spans.append((f"n{i % 5}", a, a + d) if r.random() < 0.5
+                             else (f"n{i % 5}", a + d / 4, a + d / 2))
+        yield gaps, spans[::-1] if reverse else spans
+
+
+def recorded_train_trace():
+    """The gaps and spans that `reduce` makes of the recorded v5e train trace."""
+    meta = R.load_json(R.HERE / "tests/recorded_trace.json")
+    profile = tr.load(str(R.HERE / "tests/recorded_trace.xplane.pb"))
+    (lo, hi), = tr.host_events(profile, tr.WINDOW_EVENT)
+    shift = lo - meta["window_perf"][0] * 1e9
+    spans = [(n, a * 1e9 + shift, b * 1e9 + shift) for n, a, b in meta["spans"]]
+    (ev,) = tr.device_ops(profile).values()
+    busy = tr.union([(max(a, lo), min(b, hi)) for _, a, b in ev if b > lo and a < hi])
+    yield tr.gaps(busy, lo, hi), spans
+
+
+def recorded_serve_cut():
+    """A cut of a traced window of `mistral7b_serve.chat32` on a v5e (PR 27):
+    the device's intervals and the engine's spans that meet them, in the
+    order the engine recorded them, in ns from the cut's start."""
+    cut = R.load_json(R.HERE / "tests/recorded_serve_cut.json")
+    busy = tr.union([tuple(iv) for iv in cut["device_intervals"]])
+    yield tr.gaps(busy, cut["lo"], cut["hi"]), [tuple(s) for s in cut["spans"]]
+
+
+def ring_full():
+    """200,000 gaps against 400,000 spans, the ring's capacity."""
+    r = random.Random(27)
+    t, gaps = 0.0, []
+    for _ in range(200_000):
+        g0 = t + r.uniform(1e5, 5e5)
+        t = g0 + r.uniform(1e3, 4e4)
+        gaps.append((g0, t))
+    spans = []
+    for i in range(400_000):
+        a = r.uniform(-0.1 * t, 1.1 * t)
+        d = r.choice([r.uniform(1e4, 1e6), r.uniform(1e7, 3e8), r.uniform(1e9, 5e9)])
+        spans.append((f"engine.{i % 4}", a, a + d))
+    yield gaps, spans
+
+
+LABEL_CASES = {
+    "seeded": lambda: seeded(range(60)),
+    "seeded_list_reversed": lambda: seeded(range(60), reverse=True),
+    "ties_on_a_grid": lambda: seeded(range(60), grid=50),
+    "ties_on_a_grid_list_reversed": lambda: seeded(range(60), grid=50, reverse=True),
+    "spans_outside_only": lambda: [([(10.0, 20.0), (30.0, 31.0)], [
+        ("a", 0.0, 10.0), ("b", 31.0, 40.0), ("c", 20.0, 30.0), ("d", -5.0, -1.0)])],
+    "zero_spans": lambda: [([(0.0, 1.0), (2.0, 3.0)], [])],
+    "recorded_train_trace": recorded_train_trace,
+    "recorded_serve_cut": recorded_serve_cut,
+    "ring_full": ring_full,
+}
+
+
+@pytest.mark.parametrize("case", list(LABEL_CASES))
+def test_label_gaps_is_label_gap_of_every_gap(case):
+    n = 0
+    for gaps, spans in LABEL_CASES[case]():
+        t = time.perf_counter()
+        got = tr.label_gaps(gaps, spans)
+        assert time.perf_counter() - t < 60
+        assert len(got) == len(gaps)
+        check = range(len(gaps))
+        if len(gaps) * len(spans) > 1e8:  # `label_gap` on all of them would take hours
+            check = random.Random(27).sample(check, 20)
+        for i in check:
+            assert got[i] == tr.label_gap(gaps[i], spans), (case, i, gaps[i])
+        n += len(gaps)
+    assert n
+
+
 def test_reduce_a_recorded_trace():
     """A few train steps recorded on a v5e (PR 24): the numbers the file
     holds, counted by hand once."""
     path = R.HERE / "tests/recorded_trace.xplane.pb"
     meta = R.load_json(R.HERE / "tests/recorded_trace.json")
+    lines = []
     out = tr.reduce(tr.load(str(path)), [tuple(s) for s in meta["spans"]],
-                    tuple(meta["window_perf"]))
+                    tuple(meta["window_perf"]), log=lines.append)
+    assert lines == ["1100 device operations, 905 idle gaps labelled by 4 spans of 4 handed over"]
     assert out["planes"] == 1
     assert out["window_s"] == pytest.approx(meta["window_s"], rel=1e-6)
     assert out["busy_s"] == pytest.approx(meta["busy_s"], rel=1e-6)
@@ -142,6 +236,32 @@ def test_reduce_a_recorded_trace():
     assert sum(t for _, t in out["idle_gaps"]) == pytest.approx(
         out["window_s"] - out["busy_s"], rel=1e-3)
     assert {n for n, _ in out["idle_gaps"]} <= {s[0] for s in meta["spans"]} | {"host (no span)"}
+
+
+def test_read_spans_keeps_the_traced_windows_spans_and_says_when_the_ring_dropped(capsys):
+    import paddle_tpu as paddle
+    from benchmarks.kinds import serve_closed
+    from paddle_tpu.obs import trace as obs
+
+    paddle.set_flags({"FLAGS_trace": True, "FLAGS_obs_buffer_events": 16})  # the least it takes
+    obs.reset()
+    try:
+        now = time.perf_counter()  # the traced window is (2, 4) from now
+        for name, a, b in ([("dropped", 2, 3)] + [("before", 0, 1.5)] * 13
+                           + [("inside", 2.5, 3), ("across", 1, 5), ("after", 4.5, 6)]):
+            obs.record(name, "t", t0=now + a, t1=now + b)
+        ctx = tiny.serve_ctx()
+        ctx.trace_window = (now + 2, now + 4)
+        serve_closed.read_spans(ctx)
+    finally:
+        paddle.set_flags({"FLAGS_trace": False, "FLAGS_obs_buffer_events": 4096})
+        obs.reset()
+    assert [n for n, _, _ in ctx.spans] == ["inside", "across"]
+    (_, a, b), _ = ctx.spans
+    assert a == pytest.approx(now + 2.5, abs=1e-3) and b == pytest.approx(now + 3, abs=1e-3)
+    err = capsys.readouterr().err
+    assert "2 spans meet the traced window, of 17 recorded and 1 dropped" in err
+    assert "INCOMPLETE" in err
 
 
 def test_kernel_roofline_is_silent_when_another_kernel_joins_the_match():

@@ -12,7 +12,9 @@ part of the run; it also ties the trace's clock to `time.perf_counter`.
 
 from __future__ import annotations
 
+import bisect
 import glob
+import heapq
 import os
 import re
 
@@ -110,12 +112,41 @@ def label_gap(gap, spans):
     return best
 
 
-def reduce(profile, spans_perf=(), window_perf=None, top=10):
+def label_gaps(gaps, spans):
+    """`label_gap(g, spans)` for every g of `gaps` (sorted, disjoint), in one
+    sweep: near-linear where a call per gap walks every span for every gap.
+
+    A span that covers a gap whole overlaps it by the gap's length, which no
+    overlap exceeds, so of those only the first in the list can win: they sit
+    in a heap by list index, entered once the sweep has passed their start,
+    left from the top once they end before a gap does.  Every other span
+    that overlaps a gap starts or ends inside it, and a point lies inside one
+    gap at most.  `label_gap` then chooses among those, in the list's order."""
+    by_start = sorted(range(len(spans)), key=lambda i: spans[i][1])
+    by_end = sorted(range(len(spans)), key=lambda i: spans[i][2])
+    starts = [spans[i][1] for i in by_start]
+    ends = [spans[i][2] for i in by_end]
+    out, covering, k = [], [], 0
+    for g0, g1 in gaps:
+        first_inside = bisect.bisect_right(starts, g0)
+        for i in by_start[k:first_inside]:
+            heapq.heappush(covering, i)
+        k = first_inside
+        while covering and spans[covering[0]][2] < g1:
+            heapq.heappop(covering)
+        inside = (by_start[k:bisect.bisect_left(starts, g1)]
+                  + by_end[bisect.bisect_right(ends, g0):bisect.bisect_left(ends, g1)])
+        out.append(label_gap((g0, g1), [spans[i] for i in sorted(covering[:1] + inside)]))
+    return out
+
+
+def reduce(profile, spans_perf=(), window_perf=None, top=10, log=None):
     """The whole reduction.
 
     spans_perf: (name, t0, t1) host spans in `time.perf_counter` seconds.
     window_perf: (t0, t1) of WINDOW_EVENT on the same clock, to tie clocks;
     without it the gaps are not labelled.
+    log: takes one line, the counts of what was reduced.
 
     Returns busy_s and window_s (averaged over device planes), ops
     {name: seconds} and op_counts {name: events} summed over planes and
@@ -133,7 +164,9 @@ def reduce(profile, spans_perf=(), window_perf=None, top=10):
     if marks and window_perf is not None:
         shift = lo - window_perf[0] * 1e9
         spans_ns = [(n, a * 1e9 + shift, b * 1e9 + shift) for n, a, b in spans_perf]
-    busy_total, ops, counts, gap_by_label = 0.0, {}, {}, {}
+        # a span outside the window overlaps no gap; the list's order stays
+        spans_ns = [s for s in spans_ns if s[2] > lo and s[1] < hi]
+    busy_total, ops, counts, gap_by_label, n_gaps = 0.0, {}, {}, {}, 0
     for ev in planes.values():
         inside = [(n, max(a, lo), min(b, hi)) for n, a, b in ev if b > lo and a < hi]
         busy = union([(a, b) for _, a, b in inside])
@@ -141,9 +174,13 @@ def reduce(profile, spans_perf=(), window_perf=None, top=10):
         for n, a, b in inside:
             ops[n] = ops.get(n, 0.0) + (b - a)
             counts[n] = counts.get(n, 0) + 1
-        for g in gaps(busy, lo, hi):
-            lab = label_gap(g, spans_ns)
+        idle = gaps(busy, lo, hi)
+        n_gaps += len(idle)
+        for g, lab in zip(idle, label_gaps(idle, spans_ns)):
             gap_by_label[lab] = gap_by_label.get(lab, 0.0) + (g[1] - g[0])
+    if log:
+        log(f"{sum(len(ev) for ev in planes.values())} device operations, {n_gaps} idle gaps "
+            f"labelled by {len(spans_ns)} spans of {len(spans_perf)} handed over")
     k = len(planes)
     ops = {n: t / k / 1e9 for n, t in ops.items()}
     rank = lambda d: sorted(d.items(), key=lambda kv: -kv[1])[:top]

@@ -255,7 +255,8 @@ class PagedPrefillView:
     (`start` an int32[1] Tensor): a prefix-cache hit prefills only the
     unshared suffix at rope offset `start`, attending the shared pages
     through a table gather.  Rows past `true_len` (bucket padding) and rows
-    whose page index overruns the table are redirected to scratch page 0."""
+    whose page index overruns the table never touch a mapped page
+    (`_kv_store`)."""
 
     def __init__(self, arena, table, true_len, max_len, start=None,
                  kernel="auto"):
@@ -287,29 +288,91 @@ class PagedDecodeView:
         self.kernel = kernel  # paged attention dispatch: auto|fused|gather
 
 
-def _page_scatter(arena_t, new_t, table_t, true_len_t, start_t=None):
-    """Scatter a [1, s, kv_heads, d] prefill chunk into pages: row i lands
-    at global index start+i -> (table[idx // page_size], idx % page_size).
-    Rows with i >= true_len (bucket padding) or a page index beyond the
-    table are redirected to scratch page 0 — padding garbage never touches
-    a page a reader could share."""
-    import jax.numpy as jnp
+def _kv_store(arena, rows, tables, start=None, true_len=None):
+    """The one way K/V rows enter a paged arena (traced: the five writers
+    below call it inside their ops).  `arena` is a value arena `[pages,
+    kv_heads, page_size, head_dim]` taking `rows` `[b, s, kv_heads,
+    head_dim]`, or an int8 arena's scale buffer `[pages, kv_heads, 1,
+    page_size]` taking `rows` `[b, s, kv_heads]`.  Row (n, i) has global
+    index `start[n] + i` (`start` None: i) and lands on page `tables[n,
+    idx // page_size]`, row `idx % page_size`.  Rows with `i >= true_len`
+    and rows whose page entry overruns the table never touch a mapped page:
+    they fall on scratch page 0 (content unspecified) or are dropped.
 
+    Arena and rows go through `stop_gradient`: under `dispatch.apply`'s
+    `jax.vjp` a scatter whose updates carry a tangent selects over a u32
+    twin of the whole arena, and no gradient is ever taken through a cache.
+
+    The static row count per sequence picks the form, because XLA:TPU
+    copies the whole arena around a scatter that does not run in the
+    arena's own layout (chip readings: PERF.md, PR 28):
+
+    - `s < page_size` (decode, the verify window): (page, head, row) are all
+      indexed, so the only window dim is the minor one;
+    - else (prefill buckets), by whole pages: gather the pages the chunk
+      touches, merge the new rows into them on the row axis (rows before
+      `start` and rows at or past `true_len` keep what they held), and put
+      the pages back with a scatter whose only indexed dim is `pages`.
+    """
+    import jax
+    import jax.numpy as jnp
+    from jax import lax
+
+    arena = lax.stop_gradient(arena)
+    rows = lax.stop_gradient(rows).astype(arena.dtype)
+    scales = rows.ndim == 3
+    ps = arena.shape[3] if scales else arena.shape[2]
+    b, s, kvh = rows.shape[:3]
+    P = tables.shape[1]
+    # a chunk with a traced start may begin inside a page: one page more
+    n_pg = -(-s // ps) + (0 if start is None else 1)
+    if start is None:
+        start = jnp.zeros((b,), jnp.int32)
+    n_valid = s if true_len is None else jnp.reshape(true_len, ())
+
+    def pages_of(entry, live):
+        # [b, n] table entries -> page ids; dead and overrunning -> scratch
+        pg = jnp.take_along_axis(tables, jnp.minimum(entry, P - 1), axis=1)
+        return jnp.where(live & (entry < P), pg, 0)
+
+    if s < ps:
+        i = jnp.arange(s, dtype=jnp.int32)[None, :]
+        idx = start[:, None] + i
+        pg = pages_of(idx // ps, i < n_valid)[:, :, None]
+        row = (idx % ps)[:, :, None]
+        head = jnp.arange(kvh, dtype=jnp.int32)[None, None, :]
+        return arena.at[(pg, head, 0, row) if scales else (pg, head, row)].set(rows)
+
+    # the chunk on the grid of its pages: grid row j is index first * ps + j
+    first, off = start // ps, start % ps
+    grid = jax.vmap(
+        lambda r, o: lax.dynamic_update_slice_in_dim(
+            jnp.zeros((n_pg * ps,) + r.shape[1:], r.dtype), r, o, 0
+        )
+    )(rows, off)
+    j = jnp.arange(n_pg * ps, dtype=jnp.int32)[None, :]
+    new = (j >= off[:, None]) & (j < off[:, None] + jnp.minimum(n_valid, s))
+    new = new.reshape(b, n_pg, ps)
+    entry = first[:, None] + jnp.arange(n_pg, dtype=jnp.int32)[None, :]
+    pgs = pages_of(entry, new.any(axis=2))
+    # [b, n_pg * ps, kv_heads, ...] -> pages shaped as the arena holds them
+    grid = jnp.moveaxis(grid.reshape((b, n_pg, ps) + grid.shape[2:]), 2, 3)
+    if scales:
+        grid, new = grid[:, :, :, None, :], new[:, :, None, None, :]
+    else:
+        new = new[:, :, None, :, None]
+    return arena.at[pgs].set(jnp.where(new, grid, arena[pgs]))
+
+
+def _page_scatter(arena_t, new_t, table_t, true_len_t, start_t=None):
+    """Store a [1, s, kv_heads, d] prefill chunk into the pages of `table_t`
+    ([max_pages_per_seq] int32) from global index `start_t` (int32[1];
+    None: 0).  Rows i >= true_len (bucket padding) never touch a page a
+    reader could share: see `_kv_store`."""
     from ..ops.dispatch import apply
 
-    ps = arena_t.shape[2]
-
     def f(c, n, t, tl, *st):
-        s = n.shape[1]
-        i = jnp.arange(s, dtype=jnp.int32)
-        idx = (st[0][0] + i) if st else i
-        entry = idx // ps
-        P = t.shape[0]
-        valid = (i < tl) & (entry < P)
-        pg = jnp.where(valid, t[jnp.minimum(entry, P - 1)], 0)
-        # (page, :, row): the two index arrays broadcast to the leading dim
-        # of the update, so the [s, kv_heads, d] rows land as they are
-        return c.at[pg, :, idx % ps].set(n[0].astype(c.dtype))
+        return _kv_store(c, n, t[None], *st, true_len=tl)
 
     ins = [arena_t, new_t, table_t, true_len_t] + ([start_t] if start_t is not None else [])
     return apply(f, ins, name="kv_page_scatter")
@@ -317,19 +380,18 @@ def _page_scatter(arena_t, new_t, table_t, true_len_t, start_t=None):
 
 def _rope_page_scatter(arena_k_t, arena_v_t, q, k, v, cos, sin, table_t,
                        true_len_t, start_t=None):
-    """Fused prefill cache-write: RoPE on q/k AND the k/v page scatters in
+    """Fused prefill cache-write: RoPE on q/k AND the k/v page stores in
     ONE traced op — the unfused form round-trips the rotated k (and raw v)
-    through HBM between the rope op and each scatter op; fusing them keeps
-    the activations in registers/VMEM within one XLA computation.  The math
-    is operation-for-operation identical to `apply_rotary_pos_emb` (static
-    offset 0 without `start_t`, the per-row cos/sin gather with it) followed
-    by two `_page_scatter`s, so outputs stay bit-identical to the unfused
-    executables.  Returns (q_rot, k_rot, new_arena_k, new_arena_v)."""
+    through HBM between the rope op and each store op; fusing them keeps
+    the activations in registers/VMEM within one XLA computation.  The rope
+    math is operation-for-operation identical to `apply_rotary_pos_emb`
+    (static offset 0 without `start_t`, the per-row cos/sin gather with it),
+    so q and k are bit-identical to the unfused op's.  Returns (q_rot,
+    k_rot, new_arena_k, new_arena_v)."""
     import jax.numpy as jnp
 
     from ..ops.dispatch import apply
 
-    ps = arena_k_t.shape[2]
     s = q.shape[1]
 
     def f(ak, av, qa, ka, va, c, si, t, tl, *st):
@@ -348,16 +410,11 @@ def _rope_page_scatter(arena_k_t, arena_v_t, q, k, v, cos, sin, table_t,
             rh = jnp.concatenate([-x[..., half:], x[..., :half]], axis=-1)
             return x * cc + rh * si_
 
+        def store(arena, rows):
+            return _kv_store(arena, rows, t[None], *st, true_len=tl)
+
         q_rot, k_rot = rot(qa), rot(ka)
-        i = jnp.arange(s, dtype=jnp.int32)
-        gidx = (st[0][0] + i) if st else i
-        entry = gidx // ps
-        P = t.shape[0]
-        valid = (i < tl) & (entry < P)
-        pg = jnp.where(valid, t[jnp.minimum(entry, P - 1)], 0)
-        new_ak = ak.at[pg, :, gidx % ps].set(k_rot[0].astype(ak.dtype))
-        new_av = av.at[pg, :, gidx % ps].set(va[0].astype(av.dtype))
-        return q_rot, k_rot, new_ak, new_av
+        return q_rot, k_rot, store(ak, k_rot), store(av, va)
 
     ins = [arena_k_t, arena_v_t, q, k, v, cos, sin, table_t, true_len_t]
     if start_t is not None:
@@ -369,7 +426,7 @@ def _quantize_kv_rows(x):
     """Symmetric per-row int8 quantization of KV rows `[..., head_dim]`:
     scale = max|x| / 127 over the head dim (float32), zero rows pinned to
     scale 1 so their dequant is exactly zero.  Returns (int8 values,
-    float32 scales [..., 1]).  Traced inline inside the scatter ops, so
+    float32 scales [..., 1]).  Traced inline inside the store ops, so
     the rotated K (and raw V) quantize in-register — no full-precision
     round trip through HBM on the way into the arena."""
     import jax.numpy as jnp
@@ -383,20 +440,16 @@ def _quantize_kv_rows(x):
 
 def _rope_page_scatter_quant(arena_k_t, arena_v_t, ks_t, vs_t, q, k, v, cos,
                              sin, table_t, true_len_t, start_t=None):
-    """`_rope_page_scatter` for an int8 arena (ISSUE 18): identical RoPE +
-    page-address math, but the K/V rows quantize per (row, kv head) before
-    landing and the scales scatter into the parallel scale arenas through
-    the SAME page/row indices — one traced op still, so rope, quantize and
-    all four scatters fuse.  Redirected rows (padding, table overrun) drop
-    their garbage values AND scales on scratch page 0, where the position
-    fence masks them before any softmax.  Returns (q_rot, k_rot, new_ak,
-    new_av, new_ks, new_vs) — q_rot/k_rot stay full precision for the
-    prefill's own causal attention."""
+    """`_rope_page_scatter` for an int8 arena (ISSUE 18): identical RoPE,
+    but the K/V rows quantize per (row, kv head) before landing and the
+    scales go into the parallel scale buffers through the SAME addresses —
+    one traced op still, so rope, quantize and all four stores fuse.
+    Returns (q_rot, k_rot, new_ak, new_av, new_ks, new_vs) — q_rot/k_rot
+    stay full precision for the prefill's own causal attention."""
     import jax.numpy as jnp
 
     from ..ops.dispatch import apply
 
-    ps = arena_k_t.shape[2]
     s = q.shape[1]
 
     def f(ak, av, aks, avs, qa, ka, va, c, si, t, tl, *st):
@@ -413,20 +466,14 @@ def _rope_page_scatter_quant(arena_k_t, arena_v_t, ks_t, vs_t, q, k, v, cos,
             rh = jnp.concatenate([-x[..., half:], x[..., :half]], axis=-1)
             return x * cc + rh * si_
 
+        def store(arena, rows):
+            return _kv_store(arena, rows, t[None], *st, true_len=tl)
+
         q_rot, k_rot = rot(qa), rot(ka)
-        i = jnp.arange(s, dtype=jnp.int32)
-        gidx = (st[0][0] + i) if st else i
-        entry = gidx // ps
-        P = t.shape[0]
-        valid = (i < tl) & (entry < P)
-        pg = jnp.where(valid, t[jnp.minimum(entry, P - 1)], 0)
-        kq, ksc = _quantize_kv_rows(k_rot[0])
-        vq, vsc = _quantize_kv_rows(va[0])
-        new_ak = ak.at[pg, :, gidx % ps].set(kq)
-        new_av = av.at[pg, :, gidx % ps].set(vq)
-        new_ks = aks.at[pg, :, 0, gidx % ps].set(ksc[..., 0])
-        new_vs = avs.at[pg, :, 0, gidx % ps].set(vsc[..., 0])
-        return q_rot, k_rot, new_ak, new_av, new_ks, new_vs
+        kq, ksc = _quantize_kv_rows(k_rot)
+        vq, vsc = _quantize_kv_rows(va)
+        return (q_rot, k_rot, store(ak, kq), store(av, vq),
+                store(aks, ksc[..., 0]), store(avs, vsc[..., 0]))
 
     ins = [arena_k_t, arena_v_t, ks_t, vs_t, q, k, v, cos, sin, table_t,
            true_len_t]
@@ -438,81 +485,34 @@ def _rope_page_scatter_quant(arena_k_t, arena_v_t, ks_t, vs_t, q, k, v, cos,
 def _page_decode_write(arena_t, new_t, tables_t, pos_t):
     """Per-slot decode write: slot s's [s_q, kv_heads, d] token K/V rows land
     at page tables[s, (pos[s]+i)//page_size] row (pos[s]+i) % page_size for
-    i < s_q.  Inactive slots run at pos 0 over an all-zero table row —
-    scratch page 0.
+    i < s_q (`_kv_store`).  Inactive slots run at pos 0 over an all-zero
+    table row — scratch page 0.
 
-    s_q == 1 is the plain decode step (kept on its own branch so the traced
-    scatter is byte-identical to the pre-speculation executable); s_q > 1 is
-    the speculative VERIFY step writing the whole draft window at once.
-    Rows whose page entry overruns the table — drafts past a slot's mapped
-    coverage, or the window tail of a slot about to hit its length bound —
-    are redirected to scratch page 0, the same rollback-by-redirect contract
-    `_page_scatter` gives prefill padding: a rejected draft's K/V is either
-    overwritten before any reader can attend it (positions >= the advanced
-    pos are rewritten by the next step's own window, writes precede
-    attention within every layer) or never lands in a mapped page at all."""
-    import jax.numpy as jnp
-
+    s_q == 1 is the plain decode step; s_q > 1 is the speculative VERIFY
+    step writing the whole draft window at once.  Rows whose page entry
+    overruns the table — drafts past a slot's mapped coverage, or the window
+    tail of a slot about to hit its length bound — are redirected to scratch
+    page 0, the same rollback-by-redirect contract prefill padding gets: a
+    rejected draft's K/V is either overwritten before any reader can attend
+    it (positions >= the advanced pos are rewritten by the next step's own
+    window, writes precede attention within every layer) or never lands in
+    a mapped page at all."""
     from ..ops.dispatch import apply
 
-    ps = arena_t.shape[2]
-
-    def f(c, n, t, p):
-        if n.shape[1] == 1:
-            entry = p // ps  # [slots]; pos < pages*ps by the admission math
-            pg = jnp.take_along_axis(t, entry[:, None], axis=1)[:, 0]
-            return c.at[pg, :, p % ps].set(n[:, 0].astype(c.dtype))
-        sq = n.shape[1]
-        idx = p[:, None] + jnp.arange(sq, dtype=p.dtype)[None, :]  # [slots, sq]
-        entry = idx // ps
-        P = t.shape[1]
-        pg = jnp.where(
-            entry < P,
-            jnp.take_along_axis(t, jnp.minimum(entry, P - 1), axis=1),
-            0,
-        )
-        return c.at[pg, :, idx % ps].set(n.astype(c.dtype))
-
-    return apply(f, [arena_t, new_t, tables_t, pos_t], name="kv_page_decode_write")
+    return apply(_kv_store, [arena_t, new_t, tables_t, pos_t],
+                 name="kv_page_decode_write")
 
 
 def _page_decode_write_quant(arena_t, scale_t, new_t, tables_t, pos_t):
     """`_page_decode_write` for an int8 arena: the full-precision decode (or
     verify-window) rows quantize per (row, kv head) in-register, then the
-    int8 values and their float32 scales scatter through the SAME page/row
-    addresses — one traced op, same branch structure (s_q == 1 plain decode
-    vs s_q > 1 verify with the scratch redirect), so the executables stay
-    byte-stable across slot churn exactly like the unquantized path.
-    Returns (new_arena, new_scales)."""
-    import jax.numpy as jnp
-
+    int8 values and their float32 scales go through the SAME addresses —
+    one traced op.  Returns (new_arena, new_scales)."""
     from ..ops.dispatch import apply
-
-    ps = arena_t.shape[2]
 
     def f(c, sc, n, t, p):
         nq, ns = _quantize_kv_rows(n)
-        ns = ns[..., 0]  # [slots, s_q, kv_heads]
-        if n.shape[1] == 1:
-            entry = p // ps  # [slots]; pos < pages*ps by the admission math
-            pg = jnp.take_along_axis(t, entry[:, None], axis=1)[:, 0]
-            return (
-                c.at[pg, :, p % ps].set(nq[:, 0]),
-                sc.at[pg, :, 0, p % ps].set(ns[:, 0]),
-            )
-        sq = n.shape[1]
-        idx = p[:, None] + jnp.arange(sq, dtype=p.dtype)[None, :]  # [slots, sq]
-        entry = idx // ps
-        P = t.shape[1]
-        pg = jnp.where(
-            entry < P,
-            jnp.take_along_axis(t, jnp.minimum(entry, P - 1), axis=1),
-            0,
-        )
-        return (
-            c.at[pg, :, idx % ps].set(nq),
-            sc.at[pg, :, 0, idx % ps].set(ns),
-        )
+        return _kv_store(c, nq, t, p), _kv_store(sc, ns[..., 0], t, p)
 
     return apply(
         f, [arena_t, scale_t, new_t, tables_t, pos_t], multi=True,

@@ -13,6 +13,7 @@ test ends, and what outlives a dead worker when the run ends."""
 import faulthandler
 import hashlib
 import os
+import re
 import shutil
 import signal
 import subprocess
@@ -274,3 +275,34 @@ def finite_difference_grad(fn, x, eps=1e-3):
         g[idx] = (fn(xp.astype(np.float32)) - fn(xm.astype(np.float32))) / (2 * eps)
         it.iternext()
     return g
+
+
+def paged_engine_steps(eng, bucket):
+    """{name: (to_static function, arguments)} of a paged engine's decode
+    step and of its fresh prefill at `bucket`, with the arguments `warmup()`
+    sends (all-zero tables): for tests that read the traced or compiled
+    program and run nothing."""
+    from paddle_tpu import to_tensor
+
+    def z(shape, dtype):
+        return to_tensor(np.zeros(shape, dtype))
+
+    s, p = eng.slots, eng.pages_per_seq
+    return {
+        "decode": (eng._decode_fn, (
+            z((s, 1), np.int32), z(s, np.int32), z(s, bool), z(s, np.float32),
+            eng._poison_zero, eng._key, z((s, p), np.int32), z(s, np.int32))),
+        "prefill": (eng._prefill_fn, (
+            z((1, bucket), np.int32), z(p, np.int32), to_tensor(np.int32(bucket)),
+            to_tensor(np.float32(0.0)), eng._key, z(1, np.int32))),
+    }
+
+
+def hlo_results(text, shape):
+    """[(instruction name, opcode)] of every instruction in an HLO module's
+    text, fused computations included, whose result has the dims `shape`."""
+    dims = ",".join(str(d) for d in shape)
+    pat = re.compile(
+        r"^\s*(?:ROOT )?%?([\w.\-]+) = \w+\[" + dims + r"\]\S* ([\w\-]+)\(", re.M
+    )
+    return [(m.group(1), m.group(2)) for m in pat.finditer(text)]

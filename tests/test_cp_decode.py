@@ -272,6 +272,39 @@ def test_cp_engine_greedy_identical_to_cp1_and_healthz(model):
     assert eng2._pool.per_shard * 2 == eng2._pool.num_pages
 
 
+@pytest.mark.parametrize("axis", ["tp", "cp"])
+def test_sharded_arena_store_moves_no_arena(model, axis):
+    """The store under GSPMD: with kv heads split over 'mp' (tp=2) or pages
+    over 'cp' (cp=2), the partitioned decode and prefill steps never hold the
+    whole arena (a gather across chips would), and no collective or copy has
+    a shard of it as its result: each device writes its own shard in place.
+    Tokens equal the unsharded engine's."""
+    from conftest import hlo_results, paged_engine_steps
+
+    prompts = [_prompt(n, seed=60 + i) for i, n in enumerate([6, 13, 27])]
+    eng1 = _paged(model)
+    want = [eng1.generate(p, max_new_tokens=5).tolist() for p in prompts]
+    if axis == "tp":
+        sharded = LlamaForCausalLM(LlamaConfig.tiny(tensor_parallel_degree=2))
+        sharded.set_state_dict(model.state_dict())
+        eng = _paged(sharded, tp=2)
+    else:
+        eng = _paged(model, cp=2)
+    arena = eng._arenas[0].k._data
+    whole, shard = tuple(arena.shape), tuple(arena.sharding.shard_shape(arena.shape))
+    assert shard != whole
+    moves = ("copy", "all-gather", "all-reduce", "all-to-all",
+             "collective-permute", "reduce-scatter")
+    # bucket 8 is one page (the whole-page form), decode the three-index form
+    for name, (fn, args) in paged_engine_steps(eng, 8).items():
+        text = fn.lowered_text(*args)
+        assert hlo_results(text, whole) == [], name
+        stored = hlo_results(text, shard)
+        assert any(op in ("scatter", "dynamic-update-slice") for _, op in stored), name
+        assert [r for r in stored if r[1].startswith(moves)] == [], name
+    assert [eng.generate(p, max_new_tokens=5).tolist() for p in prompts] == want
+
+
 def test_cp_engine_forced_fused_spec_identity(model):
     """The long-context serving configuration end to end: cp=2 with the
     fused kernel REQUIRED and speculative decode — greedy outputs identical

@@ -208,6 +208,133 @@ def test_prefix_hit_accounting(model):
 
 
 # ---------------------------------------------------------------------------
+# the store: every writer against a plain statement of what a write means
+# ---------------------------------------------------------------------------
+
+_PS, _KVH, _D, _PAGES = 8, 2, 4, 12
+
+
+def _quant_ref(x):
+    """The program's own quantizer (the store is what is under test): int8
+    values, float32 scales [..., 1]."""
+    import jax
+
+    from paddle_tpu.models.llama import _quantize_kv_rows
+
+    return tuple(np.asarray(a) for a in jax.jit(_quantize_kv_rows)(x))
+
+
+def _store_ref(arena, rows, tables, start, true_len, scales=False):
+    """A loop over rows: `arena[page, :, row] = new` (scale buffers keep
+    their rows on the last axis).  Rows at or past `true_len` and rows whose
+    page entry overruns the table go to scratch page 0, which no reader sees
+    and this reference leaves alone."""
+    out = arena.copy()
+    for n in range(rows.shape[0]):
+        for i in range(min(rows.shape[1], true_len)):
+            entry, row = divmod(int(start[n]) + i, _PS)
+            if entry >= tables.shape[1]:
+                continue
+            if scales:
+                out[tables[n, entry], :, 0, row] = rows[n, i]
+            else:
+                out[tables[n, entry], :, row] = rows[n, i]
+    return out
+
+
+# (id, writer, s, [table rows], [start per sequence], true_len, int8)
+_STORE_CASES = [
+    # three slots decode one token each: page starts, page ends, mid-page
+    ("decode-sq1", "decode", 1, [[3, 4, 0], [5, 6, 7], [8, 9, 0]], [0, 23, 12], None, False),
+    # a window of 4 across a page boundary; slot 1's runs off its 2-entry
+    # table (rows 22, 23 land, 24 and 25 are redirected)
+    ("verify-sq4-overrun", "decode", 4, [[3, 4], [5, 6], [7, 8]], [6, 14, 0], None, False),
+    # slot 1 is inactive: pos 0 over an all-zero table row
+    ("decode-inactive-slot", "decode", 1, [[3, 4], [0, 0], [5, 6]], [9, 0, 3], None, False),
+    ("verify-inactive-slot", "decode", 4, [[3, 4], [0, 0], [5, 6]], [5, 0, 12], None, False),
+    # a window as long as a page goes by whole pages, all slots at once
+    ("verify-sq8-whole-pages", "decode", 8, [[3, 4], [5, 6], [0, 0]], [5, 11, 0], None, False),
+    ("prefill-fresh-16-len11", "prefill", 16, [[3, 4, 5, 0]], None, 11, False),
+    ("prefill-fresh-24-len17", "prefill", 24, [[6, 2, 9, 4]], None, 17, False),
+    # the bucket is wider than the table: its last page has no entry
+    ("prefill-fresh-24-overrun", "prefill", 24, [[6, 2]], None, 13, False),
+    ("prefill-fresh-4-under-a-page", "prefill", 4, [[7, 8]], None, 3, False),
+    # prefix-cache hit: the suffix starts mid-page and ends mid-page
+    ("prefill-chunk-16-start13", "prefill", 16, [[3, 4, 5, 6]], [13], 9, False),
+    ("prefill-chunk-8-start3-full", "prefill", 8, [[3, 4, 5, 6]], [3], 8, False),
+    ("prefill-chunk-16-overrun", "prefill", 16, [[3, 4, 5]], [13], 16, False),
+    ("int8-decode-sq1", "decode", 1, [[3, 4, 0], [5, 6, 7], [0, 0, 0]], [7, 16, 0], None, True),
+    ("int8-verify-sq4-overrun", "decode", 4, [[3, 4], [5, 6]], [6, 14], None, True),
+    ("int8-prefill-fresh-16-len11", "prefill", 16, [[3, 4, 5, 0]], None, 11, True),
+    ("int8-prefill-chunk-16-start13", "prefill", 16, [[3, 4, 5, 6]], [13], 9, True),
+]
+
+
+@pytest.mark.parametrize("case", _STORE_CASES, ids=[c[0] for c in _STORE_CASES])
+def test_store_matches_row_loop(case):
+    """Each writer leaves every page but scratch page 0 byte-equal to the
+    row loop's: the rows it must write AND the rows it must leave alone."""
+    from paddle_tpu.models import llama
+
+    _, writer, s, tables, start, true_len, int8 = case
+    rng = np.random.RandomState(len(case[0]) * 1000 + s)
+    tables = np.asarray(tables, np.int32)
+    b = tables.shape[0]
+    shape = (_PAGES, _KVH, _PS, _D)
+    if int8:
+        ak, av = (rng.randint(-127, 128, shape).astype(np.int8) for _ in range(2))
+        ks, vs = (rng.rand(_PAGES, _KVH, 1, _PS).astype(np.float32) for _ in range(2))
+    else:
+        ak, av = (rng.randn(*shape).astype(np.float32) for _ in range(2))
+    k, v = (rng.randn(b, s, _KVH, _D).astype(np.float32) for _ in range(2))
+    starts = np.asarray([0] * b if start is None else start, np.int32)
+    n_valid = s if true_len is None else true_len
+    T = paddle.to_tensor
+
+    if writer == "decode":
+        if int8:
+            new_ak, new_ks = llama._page_decode_write_quant(
+                T(ak), T(ks), T(k), T(tables), T(starts))
+            new_av, new_vs = llama._page_decode_write_quant(
+                T(av), T(vs), T(v), T(tables), T(starts))
+        else:
+            new_ak = llama._page_decode_write(T(ak), T(k), T(tables), T(starts))
+            new_av = llama._page_decode_write(T(av), T(v), T(tables), T(starts))
+    else:
+        # the fused writers rope k on the way in; cos 1, sin 0 leaves it as
+        # it is, so what must land is k itself
+        cos = T(np.ones((64, _D), np.float32))
+        sin = T(np.zeros((64, _D), np.float32))
+        q = T(rng.randn(b, s, _KVH, _D).astype(np.float32))
+        tail = [T(tables[0]), T(np.asarray(true_len, np.int32))]
+        tail += [] if start is None else [T(starts)]
+        if int8:
+            _, _, new_ak, new_av, new_ks, new_vs = llama._rope_page_scatter_quant(
+                T(ak), T(av), T(ks), T(vs), q, T(k), T(v), cos, sin, *tail)
+        else:
+            _, _, new_ak, new_av = llama._rope_page_scatter(
+                T(ak), T(av), q, T(k), T(v), cos, sin, *tail)
+            # the unfused writer stores the same rows at the same addresses
+            alone = llama._page_scatter(T(av), T(v), *tail)
+            np.testing.assert_array_equal(alone.numpy()[1:], new_av.numpy()[1:])
+
+    pairs = [(ak, k, new_ak), (av, v, new_av)]
+    if int8:
+        (qk, sk), (qv, sv) = _quant_ref(k), _quant_ref(v)
+        pairs = [(ak, qk, new_ak), (av, qv, new_av)]
+        for old, rows, got in ((ks, sk[..., 0], new_ks), (vs, sv[..., 0], new_vs)):
+            ref = _store_ref(old, rows, tables, starts, n_valid, scales=True)
+            assert got.numpy().dtype == np.float32
+            np.testing.assert_array_equal(got.numpy()[1:], ref[1:])
+    for old, rows, got in pairs:
+        ref = _store_ref(old, rows, tables, starts, n_valid)
+        assert got.numpy().dtype == old.dtype
+        np.testing.assert_array_equal(got.numpy()[1:], ref[1:])
+        # and the case is not vacuous: some mapped row did change
+        assert (ref[1:] != old[1:]).any() or tables.max() == 0
+
+
+# ---------------------------------------------------------------------------
 # bench gate helper (lenet_eager regression satellite): the >=55 steps/s
 # logic is a plain function, testable without a TPU or a bench run
 # ---------------------------------------------------------------------------

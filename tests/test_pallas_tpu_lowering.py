@@ -13,6 +13,10 @@ describes without a chip.  Run it before spending chip time on a kernel
 change:
 
     pytest tests/test_pallas_tpu_lowering.py -m slow
+
+The last two tests read a paged engine's own decode and prefill steps for
+what a KV write moves besides its rows: the traced program here, the
+program compiled for the v5e in the slow twin.
 """
 
 import functools
@@ -194,3 +198,102 @@ def test_compiles_for_v5e(case):
     """Full XLA + Mosaic compile for `TPU v5 lite` without a chip."""
     _, fn, args, degrees = case
     jax.jit(fn).lower(*_avals(args, degrees, _v5e_devices())).compile()
+
+
+# ---------------------------------------------------------------------------
+# a KV write moves its rows and leaves the arena where it lies
+# ---------------------------------------------------------------------------
+
+
+def _paged_engine(config, *, bf16=False, **engine):
+    """A paged engine that is traced and compiled here and never run."""
+    import numpy as np
+
+    import paddle_tpu as paddle
+    from paddle_tpu.inference.engine import ContinuousBatchingEngine
+    from paddle_tpu.models.llama import LlamaForCausalLM
+
+    state = paddle.get_rng_state()  # later modules' seedless models stay as they were
+    try:
+        np.random.seed(1234)
+        model = LlamaForCausalLM(config)
+        if bf16:
+            model = paddle.amp.decorate(model, level="O2", dtype="bfloat16")
+        return ContinuousBatchingEngine(model, paged=True, **engine)
+    finally:
+        paddle.set_rng_state(state)
+
+
+def _arena_shaped_equations(jaxpr, shape, found):
+    for eqn in jaxpr.eqns:
+        subs = [
+            getattr(sub, "jaxpr", sub)
+            for param in eqn.params.values()
+            for sub in (param if isinstance(param, (list, tuple)) else [param])
+        ]
+        subs = [sub for sub in subs if hasattr(sub, "eqns")]
+        for sub in subs:
+            _arena_shaped_equations(sub, shape, found)
+        # a call's results are its body's, which the walk has seen
+        if not subs and any(getattr(v.aval, "shape", None) == shape for v in eqn.outvars):
+            found.append(eqn)
+    return found
+
+
+@pytest.mark.parametrize("step", ["decode", "prefill"])
+def test_engine_step_jaxpr_holds_the_store_alone(step):
+    """In the traced `to_static` step nothing arena-shaped is made but by the
+    store's own scatter.  Under `dispatch.apply`'s `jax.vjp` a scatter whose
+    rows carry a tangent is rewritten by its JVP rule into a scatter of ids
+    over a u32 twin of the arena, compares and selects over the whole of it:
+    on every platform, every step."""
+    from conftest import paged_engine_steps
+
+    from paddle_tpu.models.llama import LlamaConfig
+
+    eng = _paged_engine(LlamaConfig.tiny(), slots=3, max_len=64,
+                        prefill_buckets=[16], page_size=8)
+    shape = tuple(eng._arenas[0].k.shape)
+    fn, args = paged_engine_steps(eng, 16)[step]
+    entry, *arrays = fn._prepare(args, {})
+    found = _arena_shaped_equations(jax.make_jaxpr(entry.jitted)(*arrays).jaxpr, shape, [])
+    names = [e.primitive.name for e in found]
+    assert names.count("scatter") == 2 * len(eng._arenas)  # K and V of each layer
+    # `stop_gradient` is the identity; the fill is the zero tangent `jax.vjp`
+    # makes for the arena it returns, a constant that nothing reads
+    fills = [e for e in found if e.primitive.name == "broadcast_in_dim"]
+    assert all(v.aval.shape == () for e in fills for v in e.invars)
+    assert set(names) <= {"scatter", "stop_gradient", "broadcast_in_dim"}, names
+
+
+@pytest.mark.slow
+@pytest.mark.parametrize("step", ["decode", "prefill"])
+def test_engine_step_compiles_for_v5e_without_arena_copies(step):
+    """The same two steps compiled for `TPU v5 lite` at the serving cell's
+    arena, bf16[513,8,128,128]: a scatter that leaves `kv_heads` as a window
+    dim between indexed dims runs in another layout, and XLA:TPU copies the
+    arena there and back around it (134 MB each way, per arena, per step)."""
+    from conftest import hlo_results, paged_engine_steps
+
+    from paddle_tpu.models.llama import LlamaConfig
+
+    config = LlamaConfig.tiny(
+        hidden_size=4096, intermediate_size=512, num_attention_heads=32,
+        num_key_value_heads=8, num_hidden_layers=1, max_position_embeddings=2048)
+    eng = _paged_engine(config, bf16=True, slots=32, max_len=2048,
+                        prefill_buckets=[256], page_size=128)
+    shape = tuple(eng._arenas[0].k.shape)
+    assert shape == (513, 8, 128, 128)
+    fn, args = paged_engine_steps(eng, 256)[step]
+    entry, *arrays = fn._prepare(args, {})
+    on_chip = SingleDeviceSharding(_v5e_devices()[0])
+    avals = jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=on_chip), arrays)
+    compiled = entry.jitted.lower(*avals).compile()
+    made = hlo_results(compiled.as_text(), shape)
+    assert any(op == "scatter" for _, op in made)
+    bad = [(n, op) for n, op in made
+           if op in ("copy", "select") or (op == "fusion" and ("copy" in n or "select" in n))]
+    assert bad == []
+    # one arena is 134.5 MB: a program that copies one needs that much room
+    assert compiled.memory_analysis().temp_size_in_bytes < 64 * 2**20

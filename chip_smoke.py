@@ -10,6 +10,9 @@ seeded random weights:
   paged KV, page size 128, decode kernel `auto`, prefix cache on) ->
   `warmup()` -> `inference.serve()` -> concurrent `POST /generate`;
 - train: `amp.decorate` O2 + `AdamW` + a `@paddle.jit.to_static` step;
+- latent serve: a small `DeepseekV32ForCausalLM` (latent and indexer caches,
+  top-k selection, held experts) through the same engine for a few tokens,
+  one prompt in chunks;
 - compile cache: where jax's persistent cache is, and its hit counters;
 - four chips (when present): the serve phase at tensor-parallel degree 4
   and one hybrid dp x mp train step through `fleet.init`.
@@ -69,6 +72,17 @@ SERVE_SIZES = dict(
     kernel_tol=1e-2, kernel_tol_int8=5e-2,
 )
 TRAIN_SIZES = dict(batch=8, seqlen=2048, steps=4)
+# DeepseekV32Config at a tenth of its widths: 8 of 64 experts held, a top-k of
+# 256 under contexts of 300-700, the 700-token prompt in chunks of 256
+LATENT_CONFIG = dict(
+    vocab_size=4096, hidden_size=1024, intermediate_size=2048, moe_intermediate_size=256,
+    num_hidden_layers=3, first_k_dense_replace=1, num_attention_heads=16,
+    num_key_value_heads=16, q_lora_rank=256, kv_lora_rank=128, qk_nope_head_dim=64,
+    qk_rope_head_dim=32, v_head_dim=64, index_n_heads=8, index_head_dim=64, index_topk=256,
+    n_routed_experts=64, experts_held=8, max_position_embeddings=1024,
+)
+LATENT_SIZES = dict(slots=4, max_len=1024, buckets=[128, 256], lengths=[300, 700, 100],
+                    new_tokens=8)
 
 
 def log(msg):
@@ -419,6 +433,43 @@ def train_phase(config, *, amp, batch, seqlen, steps, hybrid=None):
     return {"model": model, "losses": losses}
 
 
+def latent_serve_phase(config, *, dtype, slots, max_len, buckets, lengths, new_tokens,
+                       page_size=None):
+    """Serve a few tokens from a `DeepseekV32ForCausalLM`: fresh and chunk
+    prefill into the latent and indexer arenas, paged decode under top-k
+    selection, the held experts.  Checks the finishes, the frozen compile
+    counts and the step's own counters.  Returns the tokens."""
+    import paddle_tpu as paddle
+    from paddle_tpu import profiler
+    from paddle_tpu.inference.engine import ContinuousBatchingEngine
+    from paddle_tpu.models import DeepseekV32Config, DeepseekV32ForCausalLM
+
+    paddle.seed(0)
+    cfg = DeepseekV32Config(**config, dtype=dtype)
+    engine = ContinuousBatchingEngine(
+        DeepseekV32ForCausalLM(cfg), slots=slots, max_len=max_len, prefill_buckets=buckets,
+        page_size=page_size)
+    engine.warmup()
+    warm = engine.compile_counts()
+    check(warm["prefill"] == warm["chunk_prefill"] == len(buckets) and warm["decode"] == 1,
+          f"latent engine warmed {warm}")
+    profiler.reset_moe()
+    rng = np.random.default_rng(0)
+    prompts = [rng.integers(1, cfg.vocab_size, size=n).astype(np.int32) for n in lengths]
+    tokens = generate_in_order(engine, prompts, new_tokens)
+    check(all(len(t) == new_tokens for t in tokens), "a latent request ended short")
+    check(engine.compile_counts() == warm, f"latent engine compiled under traffic: "
+          f"{engine.compile_counts()} after {warm}")
+    moe, sparse = profiler.moe_summary(), profiler.sparse_attn_summary()
+    check(moe and moe["picks_held"] > 0, f"no pick landed on a held expert: {moe}")
+    over = sum(1 for n in lengths if n > cfg.index_topk)
+    check(sparse and sparse["rows_over_topk"] == over * (new_tokens - 1),
+          f"selection did not bite as the lengths say: {sparse}")
+    log(f"latent serve: {len(prompts)} requests, arenas {profiler.arena_summary()}, "
+        f"moe {moe}, sparse {sparse}")
+    return tokens
+
+
 def cache_phase():
     """Where the persistent compile cache is, and what it did so far."""
     from paddle_tpu import jit
@@ -499,6 +550,9 @@ def main():
     log_memory("serve released")
     train_phase(TRAIN_CONFIG, amp=True, **TRAIN_SIZES)
     log_memory("after train")
+    gc.collect()
+    latent_serve_phase(LATENT_CONFIG, dtype="bfloat16", **LATENT_SIZES)
+    log_memory("after latent serve")
     gc.collect()
     cache_phase()
     if device["count"] >= 4:

@@ -200,6 +200,19 @@ class ContextOverflow(ValueError):
         return out
 
 
+class UnsupportedByModel(ValueError):
+    """The engine was asked for something the served model declares it cannot
+    do (`model.engine_unsupported`): refused at construction, never inside a
+    compiled step."""
+
+    def __init__(self, model, feature, asked):
+        self.feature = feature
+        super().__init__(
+            f"{type(model).__name__} does not support {feature} ({asked}): "
+            f"it declares engine_unsupported={sorted(model.engine_unsupported)}"
+        )
+
+
 class DeadlineExceeded(TimeoutError):
     """The request's deadline expired mid-flight; its slot was evicted at
     step granularity (recycled, no recompile)."""
@@ -356,6 +369,15 @@ class ContinuousBatchingEngine:
 
         cfg = model.config
         self.model = model
+        # what the served model cannot do, by the constructor argument's name
+        # ('dense': the slot engine, paged=False); each is checked where the
+        # argument is resolved, flags included
+        unsupported = frozenset(getattr(model, "engine_unsupported", ()))
+
+        def refuse(feature, on, asked):
+            if on and feature in unsupported:
+                raise UnsupportedByModel(model, feature, asked)
+
         self.slots = int(slots if slots is not None else _fcore.flag("FLAGS_serve_slots"))
         max_len = max_len if max_len is not None else cfg.max_position_embeddings
         # rope tables (and therefore positions) top out at max_position_embeddings
@@ -387,6 +409,7 @@ class ContinuousBatchingEngine:
         from ..distributed.sharding import ShardingError, validate_tp
 
         self.tp = int(_fcore.flag("FLAGS_serve_tp") if tp is None else tp)
+        refuse("tp", self.tp > 1, f"tp={self.tp}")
         validate_tp(cfg, self.tp)
         # context-parallel serving (ISSUE 20): 'cp' composes with 'mp' —
         # the paged arena's PAGE axis block-shards over cp shards while kv
@@ -396,6 +419,7 @@ class ContinuousBatchingEngine:
         self.cp = int(_fcore.flag("FLAGS_serve_cp") if cp is None else cp)
         if self.cp < 1:
             raise ShardingError(f"cp must be >= 1, got {self.cp}")
+        refuse("cp", self.cp > 1, f"cp={self.cp}")
         self._mesh = None
         if self.tp > 1:
             if int(getattr(cfg, "tensor_parallel_degree", 1)) != self.tp:
@@ -428,11 +452,15 @@ class ContinuousBatchingEngine:
             ),
         )
 
-        head_dim = cfg.hidden_size // cfg.num_attention_heads
-        cache_dtype = model.lm_head.weight.dtype  # bf16 under AMP-O2 decorate
+        # a token's rows in each layer's cache, as the model declares them:
+        # (name, heads, width, dtype).  The handoff, the int8 arena and the
+        # fused kernel's limits speak of the first kind's geometry (K's)
+        rows = list(model.cache_rows())
+        _, kv_heads, head_dim, cache_dtype = rows[0]
         self.paged = bool(
             _fcore.flag("FLAGS_serve_paged_kv") if paged is None else paged
         )
+        refuse("dense", not self.paged, "paged=False")
         # quantized KV serving (ISSUE 18): validated HERE — typed
         # QuantConfigError at construction, never a dtype mismatch inside a
         # compiled step — and folded into every cache-key surface: the
@@ -444,6 +472,7 @@ class ContinuousBatchingEngine:
             else kv_quant,
             paged=self.paged,
         )
+        refuse("kv_quant", self.kv_quant != "none", f"kv_quant={self.kv_quant!r}")
         # disaggregated serving (ISSUE 19): the role decides which side of
         # the paged-KV handoff this engine plays.  'prefill' exports its
         # committed prompt pages at finish; 'decode' grows ONE extra
@@ -453,6 +482,7 @@ class ContinuousBatchingEngine:
         self.role = str(
             _fcore.flag("FLAGS_serve_role") if role is None else role
         ).strip().lower()
+        refuse("role", self.role in ("prefill", "decode"), f"role={self.role!r}")
         if self.role not in ("colocated", "prefill", "decode"):
             raise ValueError(
                 f"role must be colocated|prefill|decode, got {self.role!r}"
@@ -535,11 +565,11 @@ class ContinuousBatchingEngine:
                     # kv_page_bytes which counts the 4-byte f32 scale per
                     # (row, kv head)
                     full = kv_page_bytes(
-                        self.page_size, cfg.num_key_value_heads, head_dim,
+                        self.page_size, kv_heads, head_dim,
                         cache_dtype_bytes, "none",
                     )
                     q8 = kv_page_bytes(
-                        self.page_size, cfg.num_key_value_heads, head_dim,
+                        self.page_size, kv_heads, head_dim,
                         cache_dtype_bytes, "int8",
                     )
                     pp = (self.slots * self.pages_per_seq * full) // q8 + 1
@@ -551,8 +581,7 @@ class ContinuousBatchingEngine:
             self.pool_pages = int(pp)
             self._caches = None
             self._arenas = [
-                PagedKVCache(self.pool_pages, self.page_size,
-                             cfg.num_key_value_heads, head_dim, cache_dtype,
+                PagedKVCache(self.pool_pages, self.page_size, rows=rows,
                              quant=self.kv_quant)
                 for _ in range(cfg.num_hidden_layers)
             ]
@@ -562,20 +591,22 @@ class ContinuousBatchingEngine:
             # observability (ISSUE 18): arena + scale HBM bytes as set (not
             # accumulated) gauges, all layers included — /metrics renders
             # them as paddle_kv_quant_*
-            page_b = kv_page_bytes(
-                self.page_size, cfg.num_key_value_heads, head_dim,
-                cache_dtype_bytes, self.kv_quant,
-            )
             scale_b = (
-                2 * self.page_size * cfg.num_key_value_heads * 4
+                2 * self.page_size * kv_heads * 4
                 if self.kv_quant == "int8" else 0
             )
+            # bytes per row kind, all layers, as the buffers hold them
+            by_kind = {
+                n: cfg.num_hidden_layers * int(np.prod(getattr(self._arenas[0], n).shape))
+                * int(np.dtype(getattr(self._arenas[0], n)._data.dtype).itemsize)
+                for n in self._arenas[0].row_names
+            }
             _prof.record_kv_quant(
                 mode=self.kv_quant,
-                arena_bytes=cfg.num_hidden_layers * self.pool_pages
-                * (page_b - scale_b),
+                arena_bytes=sum(by_kind.values()),
                 scale_bytes=cfg.num_hidden_layers * self.pool_pages * scale_b,
             )
+            _prof.record_arena_bytes(by_kind)
             self._pool = PagePool(self.pool_pages, shards=self.cp)
             use_prefix = bool(
                 _fcore.flag("FLAGS_serve_prefix_cache")
@@ -607,7 +638,7 @@ class ContinuousBatchingEngine:
             self._copy_fn = jit.to_static(self._copy_page_body)
             # handoff geometry, captured once: submit() validates incoming
             # payloads against it and the exporter stamps it on the wire
-            self._kv_heads = int(cfg.num_key_value_heads)
+            self._kv_heads = int(kv_heads)
             self._head_dim = int(head_dim)
             self._kv_dtype_np = np.dtype(_fcore.to_jax_dtype(cache_dtype))
             # the import scatter is built ONLY for decode-role engines, so
@@ -627,7 +658,7 @@ class ContinuousBatchingEngine:
             self._import_fn = None
             self.decode_kernel = "auto"  # dense engines have no paged path
             self._caches = [
-                StaticKVCache(self.slots, self.max_len, cfg.num_key_value_heads,
+                StaticKVCache(self.slots, self.max_len, kv_heads,
                               head_dim, cache_dtype)
                 for _ in range(cfg.num_hidden_layers)
             ]
@@ -639,6 +670,7 @@ class ContinuousBatchingEngine:
         # multi-tenant LoRA (ISSUE 12): an AdapterArena whose per-slot ids
         # ride the paged executables as DATA — co-batched slots on different
         # adapters share one compiled step, id 0 is the base passthrough
+        refuse("lora", lora is not None, "lora=")
         if lora is not None and not self.paged:
             raise ValueError("LoRA serving requires the paged engine")
         self._lora = lora
@@ -653,6 +685,7 @@ class ContinuousBatchingEngine:
         sk = int(_fcore.flag("FLAGS_serve_spec_k") if spec_k is None else spec_k)
         if sk < 0:
             raise ValueError("spec_k must be >= 0")
+        refuse("spec_k", sk > 0 and self.paged, f"spec_k={sk}")
         self.spec_k = sk if self.paged else 0
         self._spec_on = self.spec_k > 0
         self._spec_ngram = int(_fcore.flag("FLAGS_serve_spec_ngram"))
@@ -718,6 +751,7 @@ class ContinuousBatchingEngine:
         self._watchdog_trip = None  # (region, deadline_s) set by the monitor
         self._last_progress = time.monotonic()
         self._step_ewma_s = None  # EWMA wall seconds per decode round
+        self._last_flush_t = 0.0  # when the last fetch of decode steps ended
         # deadline-miss RATE over terminal resolutions (EWMA, not the
         # monotonic faults counter): 1.0 for a timeout eviction, 0.0 for a
         # normal finish, blended at _MISS_EWMA_ALPHA — the autoscaler and
@@ -746,7 +780,7 @@ class ContinuousBatchingEngine:
         pos_eff = apply(
             lambda p, a: jnp.where(a, p, 0), [pos, active], name="serve_pos_mask"
         )
-        hidden, _ = self.model.llama(toks, caches=self._caches, pos=pos_eff)
+        hidden, _ = self.model.backbone(toks, caches=self._caches, pos=pos_eff)
         logits = self.model.lm_head(hidden)[:, -1]  # [S, V]
 
         def f(lg, ky, tp, p, a, po):
@@ -778,7 +812,7 @@ class ContinuousBatchingEngine:
         from ..ops.dispatch import apply
 
         views = [SlotView(c, slot) for c in self._caches]
-        hidden, _ = self.model.llama(toks, caches=views)
+        hidden, _ = self.model.backbone(toks, caches=views)
         h_last = apply(
             lambda h, n: lax.dynamic_slice_in_dim(h, n - 1, 1, 1),
             [hidden, true_len], name="serve_prefill_last",
@@ -822,7 +856,7 @@ class ContinuousBatchingEngine:
             for a in self._arenas
         ]
         lora = self._lora.view(adapters) if self._lora is not None else None
-        hidden, _ = self.model.llama(toks, caches=views, pos=pos_eff, lora=lora)
+        hidden, _ = self.model.backbone(toks, caches=views, pos=pos_eff, lora=lora)
         logits = self.model.lm_head(hidden)[:, -1]  # [S, V]
 
         def f(lg, ky, tp, p, a, po):
@@ -841,6 +875,11 @@ class ContinuousBatchingEngine:
             f, [logits, key, temps, pos, active, poison], multi=True,
             name="serve_sample",
         )
+        # a model that counts inside its step (routed picks, selected rows)
+        # hands the counters over as one more output, fetched with the tokens
+        stats = self.model.step_stats() if hasattr(self.model, "step_stats") else None
+        if stats is not None:
+            return nxt, new_pos, finite, key, stats
         return nxt, new_pos, finite, key
 
     def _verify_paged_body(self, toks, pos, active, valid_len, temps, poison,
@@ -879,7 +918,7 @@ class ContinuousBatchingEngine:
             for a in self._arenas
         ]
         lora = self._lora.view(adapters) if self._lora is not None else None
-        hidden, _ = self.model.llama(toks, caches=views, pos=pos_eff, lora=lora)
+        hidden, _ = self.model.backbone(toks, caches=views, pos=pos_eff, lora=lora)
         logits = self.model.lm_head(hidden)  # [S, k+1, V]
 
         def f(lg, tk, ky, tp, p, a, vl, po):
@@ -934,7 +973,7 @@ class ContinuousBatchingEngine:
             for a in self._arenas
         ]
         lora = self._lora.view(adapters) if self._lora is not None else None
-        hidden, _ = self.model.llama(toks, caches=views, lora=lora)
+        hidden, _ = self.model.backbone(toks, caches=views, lora=lora)
         h_last = apply(
             lambda h, n: lax.dynamic_slice_in_dim(h, n - 1, 1, 1),
             [hidden, true_len], name="serve_prefill_last",
@@ -977,7 +1016,7 @@ class ContinuousBatchingEngine:
             for a in self._arenas
         ]
         lora = self._lora.view(adapters) if self._lora is not None else None
-        hidden, _ = self.model.llama(toks, caches=views, lora=lora)
+        hidden, _ = self.model.backbone(toks, caches=views, lora=lora)
         h_last = apply(
             lambda h, n: lax.dynamic_slice_in_dim(h, n - 1, 1, 1),
             [hidden, true_len], name="serve_prefill_last",
@@ -1010,15 +1049,8 @@ class ContinuousBatchingEngine:
             return c.at[d_].set(c[s_])
 
         for a in self._arenas:
-            a.k._data = apply(f, [a.k, src, dst], name="kv_page_copy")._data
-            a.v._data = apply(f, [a.v, src, dst], name="kv_page_copy")._data
-            if a.k_scale is not None:
-                a.k_scale._data = apply(
-                    f, [a.k_scale, src, dst], name="kv_page_copy"
-                )._data
-                a.v_scale._data = apply(
-                    f, [a.v_scale, src, dst], name="kv_page_copy"
-                )._data
+            for t in a.buffers():  # every row kind, and an int8 arena's scales
+                t._data = apply(f, [t, src, dst], name="kv_page_copy")._data
         return dst
 
     def _import_page_body(self, k_tiles, v_tiles, dst):
@@ -1314,7 +1346,7 @@ class ContinuousBatchingEngine:
                         to_tensor(np.ones(srow, np.float32)),
                     ]
                 self._import_fn(*args, to_tensor(np.int32(0)))
-            _, _, _, self._key = self._decode_fn(
+            _, _, _, self._key, *_ = self._decode_fn(
                 to_tensor(np.zeros((self.slots, 1), np.int32)),
                 to_tensor(np.zeros(self.slots, np.int32)),
                 to_tensor(np.zeros(self.slots, bool)),
@@ -2337,13 +2369,18 @@ class ContinuousBatchingEngine:
                 _prof.record_session_stats(self._sessions.stats())
             row_table = self._page_table[s].copy()
         suffix = L - match_len
-        bucket = self._bucket_for(suffix)
+        # a remainder longer than the largest bucket goes in as consecutive
+        # chunks of that bucket, each at its offset through the page table
+        # (the first of a fresh prompt through the fresh-prefill program); the
+        # last chunk takes the smallest bucket that holds it.  No bucket is
+        # grown, so nothing compiles mid-traffic
+        step = self.prefill_buckets[-1]
+        chunks = [(o, min(step, L - o)) for o in range(match_len, L, step)]
+        bucket = self._bucket_for(chunks[-1][1])
         t_pf = time.perf_counter()
         if req.trace:
             _obs.record("engine.queue", req.trace[0], t0=req._submit_t,
                         t1=t_pf, parent_id=req.trace[1], req=req.id)
-        toks = np.zeros((1, bucket), np.int32)
-        toks[0, :suffix] = req.prompt[match_len:]
         try:
             # dispatch OUTSIDE the mutex (same contract as the dense path):
             # the armed region must not block submitters or a restart
@@ -2363,22 +2400,34 @@ class ContinuousBatchingEngine:
                 ad_t = to_tensor(
                     np.full(1, req.adapter_slot or 0, np.int32)
                 )
-                with self._bucket_growth(bucket):
-                    if match_len == 0:
-                        nxt, key = self._prefill_fn(
-                            to_tensor(toks), to_tensor(row_table),
-                            to_tensor(np.int32(L)),
-                            to_tensor(np.float32(req.temperature)), key,
-                            ad_t,
+                table_t = to_tensor(row_table)
+                temp_t = to_tensor(np.float32(req.temperature))
+                for offset, n in chunks:
+                    b = self._bucket_for(n)
+                    toks = np.zeros((1, b), np.int32)
+                    toks[0, :n] = req.prompt[offset:offset + n]
+                    t_ch = time.perf_counter()
+                    self._check_gen(gen)  # between chunks too: a restart owns the pages
+                    with self._bucket_growth(b):
+                        if offset == 0:
+                            nxt, key = self._prefill_fn(
+                                to_tensor(toks), table_t,
+                                to_tensor(np.int32(n)), temp_t, key, ad_t,
+                            )
+                        else:
+                            nxt, key = self._chunk_fn(
+                                to_tensor(toks), table_t,
+                                to_tensor(np.int32(n)),
+                                to_tensor(np.full(1, offset, np.int32)),
+                                temp_t, key, ad_t,
+                            )
+                    if req.trace and len(chunks) > 1:
+                        _obs.record(
+                            "engine.prefill_chunk", req.trace[0], t0=t_ch,
+                            t1=time.perf_counter(), parent_id=req.trace[1],
+                            req=req.id, offset=offset, rows=n, bucket=b,
                         )
-                    else:
-                        nxt, key = self._chunk_fn(
-                            to_tensor(toks), to_tensor(row_table),
-                            to_tensor(np.int32(suffix)),
-                            to_tensor(np.full(1, match_len, np.int32)),
-                            to_tensor(np.float32(req.temperature)), key,
-                            ad_t,
-                        )
+                # only the last chunk's token is the request's first
                 with _san.allowed_sync("prefill first-token fetch"):
                     tok = int(np.asarray(nxt.numpy()).reshape(-1)[0])
         finally:
@@ -2422,6 +2471,7 @@ class ContinuousBatchingEngine:
                 req.trace[0], t0=t_pf, t1=time.perf_counter(),
                 parent_id=req.trace[1], req=req.id, bucket=bucket, slot=s,
                 prefix_match=match_len or None,
+                chunks=len(chunks) if len(chunks) > 1 else None,
                 adapter=req.adapter.name if req.adapter is not None else None,
             )
 
@@ -2539,6 +2589,10 @@ class ContinuousBatchingEngine:
 
         with self._mu:
             self._check_gen(gen)
+            if self._dev is None:
+                # the host mirrors the device loop state is rebuilt from are
+                # whole only once every dispatched step has been fetched
+                self._flush_pending_locked()
             active_idx = [s for s in range(self.slots) if self._slot_req[s] is not None]
             if not active_idx:
                 return 0
@@ -2572,8 +2626,9 @@ class ContinuousBatchingEngine:
             "serve.decode", timeout=self._wd_timeout(),
             context=f"{len(active_idx)} active slots",
         ):
+            stats = None
             if self.paged:
-                nxt, new_pos, finite, key = self._decode_fn(
+                nxt, new_pos, finite, key, *stats = self._decode_fn(
                     toks_t, pos_t, active_t, temps_t, poison_t, key,
                     self._tables_t, self._adapters_t,
                 )
@@ -2588,21 +2643,29 @@ class ContinuousBatchingEngine:
             for s in active_idx:
                 self._pos[s] += 1
             # fetch to host only when something needs the values this step —
-            # a per-token consumer (EOS watch, streaming callback), a slot
-            # hitting its length bound, or a poisoned step that must be
-            # checked now.  Otherwise the step stays in flight and the sync
-            # lands at the next membership change, so XLA pipelines decode
-            # dispatches exactly like the lock-step loop.
-            self._pending_fetch.append((nxt, finite, active_idx, t0))
+            # an EOS watch, a slot hitting its length bound, or a poisoned
+            # step that must be checked now.  Otherwise the step stays in
+            # flight and the sync lands at the next membership change, so
+            # XLA pipelines decode dispatches exactly like the lock-step
+            # loop.  A streaming callback is served one step behind the
+            # device: the step just dispatched stays in flight while the one
+            # before it is fetched and emitted, so the host's work between
+            # two steps (fetch, callbacks, the next dispatch) runs beside
+            # the device's and not in its way.  Nothing that decides
+            # membership waits on the step in flight: a length bound or an
+            # EOS watch flushes it here; an admission, an eviction, a stop
+            # and a rebuild of the device loop state flush it there.
+            self._pending_fetch.append((nxt, finite, active_idx, t0, stats))
             depth = len(self._pending_fetch)
             if poisoned is not None or any(
                 self._slot_req[s].eos_token_id is not None
-                or self._slot_req[s].on_token is not None
                 or len(self._slot_req[s].tokens) + depth
                 >= self._slot_req[s].max_new_tokens
                 for s in active_idx
             ):
                 self._flush_pending_locked()
+            elif any(self._slot_req[s].on_token is not None for s in active_idx):
+                self._flush_pending_locked(keep=1)
             if self._ep is not None:
                 self._ep["ticks"] += 1
             _prof.record_serving_tick(
@@ -2821,9 +2884,10 @@ class ContinuousBatchingEngine:
                         accepted=ep["accepted"],
                     )
 
-    def _flush_pending_locked(self):
-        """Fetch every dispatched-but-unfetched decode step and emit its
-        tokens; a slot whose logit window went non-finite errors alone.
+    def _flush_pending_locked(self, keep=0):
+        """Fetch every dispatched-but-unfetched decode step (but the newest
+        `keep`, which stay in flight) and emit its tokens; a slot whose
+        logit window went non-finite errors alone.
         Membership is constant across buffered steps (any change flushes
         first), so each entry's active set is exact.  Caller holds _mu; the
         blocking fetch runs under the serve.fetch watchdog region and
@@ -2831,10 +2895,11 @@ class ContinuousBatchingEngine:
         the mutex may have superseded us mid-fetch)."""
         from .. import profiler as _prof
 
-        if not self._pending_fetch:
+        n = len(self._pending_fetch) - keep
+        if n <= 0:
             return
         gen0 = self._gen
-        batches, self._pending_fetch = self._pending_fetch, []
+        batches, self._pending_fetch = self._pending_fetch[:n], self._pending_fetch[n:]
         t_f0 = time.perf_counter()
         with self._watchdog.arm(
             "serve.fetch", timeout=self._wd_timeout(),
@@ -2847,8 +2912,11 @@ class ContinuousBatchingEngine:
                     idx,
                     t0,
                 )
-                for nxt, fin, idx, t0 in batches
+                for nxt, fin, idx, t0, _stats in batches
             ]
+            for *_, stats in batches:
+                if stats:  # the model's own counters of the step, same fetch
+                    self.model.record_step_stats(np.asarray(stats[0].numpy()))
         self._check_gen(gen0)
         now = time.perf_counter()
         if _obs.enabled():
@@ -2862,9 +2930,12 @@ class ContinuousBatchingEngine:
                 _obs.record("engine.fetch", r.trace[0], t0=t_f0, t1=now,
                             parent_id=r.trace[1], req=r.id,
                             steps=len(fetched))
-        # EWMA decode-round wall time: dispatch-to-fetch of this burst over
-        # its step count — feeds estimate_drain_s / Retry-After
-        per = (now - fetched[0][3]) / len(fetched)
+        # EWMA decode-round wall time: dispatch-to-fetch of this burst (from
+        # the fetch before it, where its first step was dispatched behind a
+        # step still in flight) over its step count — feeds
+        # estimate_drain_s / Retry-After
+        per = (now - max(fetched[0][3], self._last_flush_t)) / len(fetched)
+        self._last_flush_t = now
         self._step_ewma_s = (
             per if self._step_ewma_s is None
             else 0.8 * self._step_ewma_s + 0.2 * per

@@ -222,29 +222,39 @@ class PagedKVCache:
     the minor (lane) dim so a page's scales are one dense [1, page_size]
     tile — a trailing unit dim is lane-padded 128x on the TPU."""
 
-    def __init__(self, num_pages, page_size, kv_heads, head_dim,
-                 dtype="float32", quant="none"):
+    def __init__(self, num_pages, page_size, kv_heads=None, head_dim=None,
+                 dtype="float32", quant="none", rows=None):
+        """`rows` is what a served model's `cache_rows()` declares, a token's
+        rows in one layer: [(name, heads, width, dtype)].  Each becomes a
+        buffer `[num_pages, heads, page_size, width]` under its name, all on
+        the one page table.  Without it: a K and a V row of `kv_heads` heads
+        of `head_dim` in `dtype`."""
         from ..framework import core as _fcore
 
         self.page_size = int(page_size)
         self.quant = str(quant)
+        if rows is None:
+            rows = [("k", kv_heads, head_dim, dtype), ("v", kv_heads, head_dim, dtype)]
+        self.row_names = tuple(r[0] for r in rows)
+        self.k_scale = None
+        self.v_scale = None
         if self.quant == "int8":
-            zeros = np.zeros((num_pages, kv_heads, page_size, head_dim), np.int8)
-            scales = np.zeros((num_pages, kv_heads, 1, page_size), np.float32)
+            if self.row_names != ("k", "v"):
+                raise ValueError(f"an int8 arena holds K and V rows, not {self.row_names}")
+            scales = np.zeros((num_pages, rows[0][1], 1, page_size), np.float32)
             self.k_scale = Tensor(scales)
             self.v_scale = Tensor(scales.copy())
-        else:
-            zeros = np.zeros(
-                (num_pages, kv_heads, page_size, head_dim),
-                _fcore.to_jax_dtype(dtype),
-            )
-            self.k_scale = None
-            self.v_scale = None
-        self.k = Tensor(zeros)
-        self.v = Tensor(zeros.copy())
-        for t in (self.k, self.v, self.k_scale, self.v_scale):
-            if t is not None:
-                t.stop_gradient = True
+        for name, heads, width, dt in rows:
+            elem = np.int8 if self.quant == "int8" else _fcore.to_jax_dtype(dt)
+            setattr(self, name, Tensor(np.zeros((num_pages, heads, page_size, width), elem)))
+        for t in self.buffers():
+            t.stop_gradient = True
+
+    def buffers(self):
+        """Every buffer a page owns a slice of, scale buffers included: what
+        a page copy has to move together."""
+        out = [getattr(self, n) for n in self.row_names]
+        return out + [t for t in (self.k_scale, self.v_scale) if t is not None]
 
 
 class PagedPrefillView:
@@ -840,6 +850,19 @@ class LlamaForCausalLM(nn.Layer):
         else:
             self.lm_head = nn.Linear(config.hidden_size, config.vocab_size, bias_attr=False)
             self.parallel_ce = None
+
+    @property
+    def backbone(self):
+        """What the serving engine calls with `caches=` / `pos=`."""
+        return self.llama
+
+    def cache_rows(self):
+        """A token's rows in each layer's cache, for the serving engine:
+        (name, heads, width, dtype)."""
+        c = self.config
+        d = c.hidden_size // c.num_attention_heads
+        dt = self.lm_head.weight.dtype  # bf16 under AMP-O2 decorate
+        return [("k", c.num_key_value_heads, d, dt), ("v", c.num_key_value_heads, d, dt)]
 
     def forward(self, input_ids, labels=None, attn_mask=None):
         hidden = self.llama(input_ids, attn_mask)

@@ -303,6 +303,7 @@ def reset():
         _reset_session_locked()
         _flash_fallbacks.clear()
         _flash_pallas.clear()
+        _reset_moe_locked()
 
 
 def metrics_snapshot():
@@ -556,6 +557,84 @@ def kv_quant_summary():
 # per slot-step (the speculation multiplier) are answerable from the summary,
 # /metrics, and the flight-recorder header.
 # ---------------------------------------------------------------------------
+
+# -- expert layer, sparse attention, arena rows (ISSUE 29) ------------------------
+# counted inside the compiled decode step by a model that has such layers
+# (models/deepseek_v32.py) and fetched with the step's tokens: no extra sync
+
+_moe_gauges = {"steps": 0, "tokens": 0, "picks_held": 0, "experts_hit": 0, "max_load": 0}
+_sparse_attn_gauges = {"rows": 0, "rows_over_topk": 0, "selected": 0, "context": 0}
+_arena_bytes = {}  # row kind -> bytes over all layers, set at engine construction
+
+
+def record_moe_step(tokens, picks_held, experts_hit, max_load):
+    """One decode step's routing, summed over the expert layers: tokens
+    routed, picks that landed on an expert held here, held experts that got
+    any pick, the heaviest held expert's load (the largest of any step)."""
+    with _counters_lock:
+        g = _moe_gauges
+        g["steps"] += 1
+        g["tokens"] += int(tokens)
+        g["picks_held"] += int(picks_held)
+        g["experts_hit"] += int(experts_hit)
+        g["max_load"] = max(g["max_load"], int(max_load))
+
+
+def record_sparse_attn_step(rows, rows_over_topk, selected, context):
+    """One decode step of learned sparse attention: rows decoded, rows whose
+    context exceeds the top-k (selection bites), rows selected and rows in
+    context, both per layer."""
+    with _counters_lock:
+        g = _sparse_attn_gauges
+        g["rows"] += int(rows)
+        g["rows_over_topk"] += int(rows_over_topk)
+        g["selected"] += int(selected)
+        g["context"] += int(context)
+
+
+def record_arena_bytes(by_kind):
+    with _counters_lock:
+        _arena_bytes.clear()
+        _arena_bytes.update({str(k): int(v) for k, v in by_kind.items()})
+
+
+def _reset_moe_locked():
+    for g in (_moe_gauges, _sparse_attn_gauges):
+        for k in g:
+            g[k] = 0
+
+
+def reset_moe():
+    with _counters_lock:
+        _reset_moe_locked()
+
+
+def moe_summary():
+    """{} before any counted step; else the totals and `experts_hit_per_step`
+    (expert-layer hits a step, all expert layers together)."""
+    with _counters_lock:
+        g = dict(_moe_gauges)
+    if not g["steps"]:
+        return {}
+    g["experts_hit_per_step"] = g["experts_hit"] / g["steps"]
+    return g
+
+
+def sparse_attn_summary():
+    """{} before any counted step; else the totals and `selected_share`."""
+    with _counters_lock:
+        g = dict(_sparse_attn_gauges)
+    if not g["rows"]:
+        return {}
+    g["selected_share"] = g["selected"] / max(g["context"], 1)
+    return g
+
+
+def arena_summary():
+    """Bytes of the paged arena per row kind ({} before an engine is built)."""
+    with _counters_lock:
+        return dict(_arena_bytes)
+
 
 _spec_gauges = {
     "steps": 0,       # verify dispatches

@@ -65,6 +65,16 @@ def test_train_phase_tiny():
     assert len(out["losses"]) == TRAIN_SIZES["steps"]
 
 
+def test_latent_serve_phase_tiny():
+    from paddle_tpu.models import DeepseekV32Config
+
+    config = {k: v for k, v in vars(DeepseekV32Config.tiny(experts_held=4)).items() if k != "dtype"}
+    tokens = chip_smoke.latent_serve_phase(
+        config, dtype="float32", slots=2, max_len=128, buckets=[16, 32], lengths=[20, 75],
+        new_tokens=5, page_size=8)
+    assert [len(t) for t in tokens] == [5, 5]
+
+
 def test_cache_phase_names_a_directory():
     assert chip_smoke.cache_phase()["dir"]
 

@@ -22,6 +22,11 @@ from paddle_tpu.models.llama import LlamaConfig, LlamaForCausalLM
 
 @pytest.fixture(scope="module")
 def model():
+    # a module's fixture is built before the first test's `_seeded`: without a
+    # seed of its own the weights follow whatever file this worker ran before
+    # (one whole run drew weights whose greedy output repeats a token early:
+    # test_generate_eos_stops_and_trims read 7 columns for 8)
+    paddle.seed(1234)
     np.random.seed(1234)
     return LlamaForCausalLM(LlamaConfig.tiny())
 
@@ -209,6 +214,36 @@ def test_streaming_token_callbacks(model):
     eng.run_until_idle()
     out = r.wait(1)
     assert stream == out[-5:].tolist()  # streamed in generation order
+
+
+@pytest.mark.parametrize("paged", [False, True])
+def test_streaming_runs_one_step_behind_the_device(model, paged):
+    """A streaming callback gets step N's token while step N + 1 is in
+    flight: one step stays unfetched between ticks, the tokens and their
+    order are those of a request nobody streams, a second request admitted
+    midway starts from whole host mirrors, and an EOS watch, which decides
+    membership, is fetched in its own tick."""
+    kw = {} if paged else {"paged": False}
+    p, q = _prompt(5, seed=8), _prompt(6, seed=9)
+    want_p = _engine(model, **kw).generate(p, max_new_tokens=9)[-9:].tolist()
+    want_q = _engine(model, **kw).generate(q, max_new_tokens=4)[-4:].tolist()
+    eng = _engine(model, **kw)
+    sp, sq = [], []
+    r = eng.submit(p, max_new_tokens=9, on_token=sp.append)
+    eng.step()  # the prefill's token, then the first decode step, dispatched and kept
+    assert (len(sp), len(eng._pending_fetch)) == (1, 1)
+    eng.step()
+    assert (len(sp), len(eng._pending_fetch)) == (2, 1)
+    r2 = eng.submit(q, max_new_tokens=4, on_token=sq.append)
+    eng.step()  # the admission fetches what was in flight before it prefills
+    assert sp == want_p[:len(sp)] and len(sp) >= 3 and sq == want_q[:1]
+    eng.run_until_idle()
+    assert (sp, sq) == (want_p, want_q)
+    assert (list(r.tokens), list(r2.tokens)) == (want_p, want_q)
+    assert not eng._pending_fetch
+    r3 = eng.submit(p, max_new_tokens=9, eos_token_id=-1, on_token=sp.append)
+    eng.step()
+    assert len(r3.tokens) == 2 and not eng._pending_fetch
 
 
 def test_submit_queue_full_raises(model):

@@ -189,6 +189,32 @@ def _pick_bh_block(bh, n_heads, block_q, block_k, d, has_segments):
     return best
 
 
+_PAGED_WALK_VMEM_BUDGET = 4 * 1024 * 1024
+
+
+def _pick_kv_heads_block(hk, qr, page_size, d, itemsize):
+    """How many KV heads of a page one grid step of the page walk moves:
+    the largest divisor of the (local) `hk` whose VMEM estimate fits
+    `_PAGED_WALK_VMEM_BUDGET`, a quarter of the 16 MiB a kernel may scope.
+    Per head: the K and V page tiles and the q and out blocks, each
+    double-buffered by the pipeline; the f32 accumulator and the m and l
+    columns (a [qr, 1] f32 column takes whole 128-lane tiles); two live
+    [qr, page_size] f32 score tiles.  Decode and a verify window (a few q
+    rows) take every head of the page, one contiguous block of the arena;
+    a chunk prefill (hundreds of q rows a head) gets 1."""
+    per_head = (
+        2 * 2 * page_size * d * itemsize
+        + 2 * 2 * qr * d * itemsize
+        + qr * d * 4 + 2 * qr * 128 * 4
+        + 2 * qr * page_size * 4
+    )
+    best = 1
+    for hb in range(1, hk + 1):
+        if hk % hb == 0 and hb * per_head <= _PAGED_WALK_VMEM_BUDGET:
+            best = hb
+    return best
+
+
 def _pallas_flash_forward(q, k, v, causal, scale, segments=None, n_heads=1,
                           block_q=1024, block_k=1024, interpret=False,
                           carry=None, out_dtype=None, q_offset=None,
@@ -856,29 +882,43 @@ def _fused_paged_decode_forward(q, arena_k, arena_v, tables, pos, max_len,
     step — the single biggest HBM tax on the serving hot path; ROADMAP 4).
 
     q: [b, sq, h, d] (sq == 1 plain decode, sq == k+1 speculative verify);
-    arena_k/v: [num_pages, kv_h, page_size, d] — (page_size, d) minor, so
-    one (page, kv head) tile is a whole trailing [page_size, d] block of
-    the array, the only K/V block shape the Mosaic lowering accepts for
-    kv_h > 1; tables: [b, P] int32 page
-    ids (traced DATA — they index the arena inside the BlockSpec index
-    maps, fed as scalar-prefetch so the DMA engine knows each page before
-    its grid step); pos: int32 scalar or [b] per-slot positions.
+    arena_k/v: [num_pages, kv_h, page_size, d] — (page_size, d) minor, so a
+    (page, kv head) tile is a whole trailing [page_size, d] block of the
+    array, the K/V block shape the Mosaic lowering accepts for kv_h > 1,
+    and all heads of a page are one contiguous block of HBM; tables:
+    [b, P] int32 page ids (traced DATA — they index the arena inside the
+    BlockSpec index maps, fed as scalar-prefetch so the DMA engine knows
+    each page before its grid step); pos: int32 scalar or [b] per-slot
+    positions.
 
-    Grid (slot, kv head, page) with the page dim innermost-sequential: one
-    [page_size, d] K/V tile streams through VMEM per step while online
-    softmax (m, l, acc) carries in scratch — the same recurrence as
-    `_flash_fwd_kernel`, but walking pages in table order.  Each slot's q
-    rows for one kv head pack the whole GQA group x verify window
-    ([rep * sq, d], row r = group member r // sq at window offset r % sq),
-    so the un-duplicated cache tile is read ONCE per group.  In-kernel
-    masks reproduce the gather path bit-for-bit: `jid <= pos + w` is the
-    per-row causal/validity fence (also inert for inactive slots parked on
-    scratch page 0 at pos 0) and `jid < max_len` reproduces the gather's
-    `[:max_len]` slice of the trailing page's slack rows.
+    Grid (slot, kv-head block, page) with the page dim innermost-sequential:
+    one [hb, page_size, d] K tile and one V tile stream through VMEM per
+    step while online softmax (m, l, acc) carries in scratch — the same
+    recurrence as `_flash_fwd_kernel`, the head a batch dim of both dots,
+    walking pages in table order.  The walk is bound by the COUNT of its
+    grid steps, not by their bytes (PR 30, one v5e: 0.2-0.3 us a step
+    whether it moves 64 KB or nothing), so `hb` is as many heads as the
+    static shape leaves VMEM for (`_pick_kv_heads_block`): every kv head of
+    a page for decode and the verify window, one for a chunk prefill, whose
+    q rows fill VMEM.  The table the index map reads is clamped at the
+    slot's newest visible page, (pos + sq - 1) // page_size: steps past it
+    name the block already in VMEM, so the pipeline copies nothing for them
+    whatever the engine's table holds there, and `needed` skips their
+    compute.
+
+    Each slot's q rows for one kv head pack the whole GQA group x verify
+    window ([rep * sq, d], row r = group member r // sq at window offset
+    r % sq), so the un-duplicated cache tile is read ONCE per group.
+    In-kernel masks reproduce the gather path bit-for-bit: `jid <= pos + w`
+    is the per-row causal/validity fence (also inert for inactive slots
+    parked on scratch page 0 at pos 0) and `jid < max_len` reproduces the
+    gather's `[:max_len]` slice of the trailing page's slack rows.
 
     Returns [b, sq, h, d]."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
+
+    from .. import profiler as _prof
 
     b, sq, h, d = q.shape
     hk = arena_k.shape[1]
@@ -887,12 +927,22 @@ def _fused_paged_decode_forward(q, arena_k, arena_v, tables, pos, max_len,
     P = tables.shape[1]
     R = rep * sq
     qr = -(-R // 8) * 8  # f32 sublane tile; pad rows are sliced off
+    hb = _pick_kv_heads_block(hk, qr, ps, d, arena_k.dtype.itemsize)
+    _prof.record_paged_walk(
+        b=b, sq=sq, heads_per_step=hb, grid_steps=b * (hk // hb) * P,
+        kv_bytes_per_step=2 * hb * ps * d * arena_k.dtype.itemsize,
+    )
     qt = jnp.transpose(q, (0, 2, 1, 3)).reshape(b, hk, rep, sq, d)
     qg = qt.reshape(b, hk, R, d)
     if qr != R:
         qg = jnp.pad(qg, ((0, 0), (0, 0), (0, qr - R), (0, 0)))
     pos_v = jnp.broadcast_to(jnp.asarray(pos, jnp.int32).reshape(-1), (b,))
-    tab = jnp.asarray(tables, jnp.int32).reshape(-1)
+    # columns past a slot's newest visible page repeat that page's entry.
+    # Clamped here and not in the index map: there the divide and the min
+    # run for every grid step and operand (PR 30, on the chip: 0.267 ms a
+    # call against 0.248 with this, 0.246 with no clamp at all)
+    col = jnp.minimum(jnp.arange(P, dtype=jnp.int32), ((pos_v + sq - 1) // ps)[:, None])
+    tab = jnp.take_along_axis(jnp.asarray(tables, jnp.int32), col, axis=1).reshape(-1)
 
     def kernel(t_ref, p_ref, q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr):
         j = pl.program_id(2)
@@ -911,52 +961,45 @@ def _fused_paged_decode_forward(q, arena_k, arena_v, tables, pos, max_len,
 
         @pl.when(needed)
         def _compute():
-            qb = q_ref[...]  # [qr, d]
-            kb = k_ref[...]  # [ps, d] — the page this table entry names
+            qb = q_ref[...]  # [hb, qr, d]
+            kb = k_ref[...]  # [hb, ps, d] — the page this table entry names
             vb = v_ref[...]
             s = jax.lax.dot_general(
-                qb, kb, (((1,), (1,)), ((), ())),
+                qb, kb, (((2,), (2,)), ((0,), (0,))),
                 preferred_element_type=jnp.float32,
-            ) * scale  # [qr, ps]
+            ) * scale  # [hb, qr, ps]
             w = jax.lax.broadcasted_iota(jnp.int32, (qr, ps), 0) % sq
             jid = j * ps + jax.lax.broadcasted_iota(jnp.int32, (qr, ps), 1)
             s = jnp.where((jid <= p0 + w) & (jid < max_len), s, _NEG_INF)
-            m = m_scr[..., 0]
-            l = l_scr[..., 0]
-            m_new = jnp.maximum(m, s.max(-1))
-            p = jnp.exp(s - m_new[..., None])
+            m = m_scr[...]  # [hb, qr, 1]
+            m_new = jnp.maximum(m, s.max(-1, keepdims=True))
+            p = jnp.exp(s - m_new)
             alpha = jnp.exp(m - m_new)
-            m_scr[...] = m_new[..., None]
-            l_scr[...] = (alpha * l + p.sum(-1))[..., None]
-            acc_scr[...] = acc_scr[...] * alpha[..., None] + jax.lax.dot_general(
-                p.astype(vb.dtype), vb, (((1,), (0,)), ((), ())),
+            m_scr[...] = m_new
+            l_scr[...] = alpha * l_scr[...] + p.sum(-1, keepdims=True)
+            acc_scr[...] = acc_scr[...] * alpha + jax.lax.dot_general(
+                p.astype(vb.dtype), vb, (((2,), (1,)), ((0,), (0,))),
                 preferred_element_type=jnp.float32,
             )
 
         @pl.when(j == n_p - 1)
         def _finish():
-            l_safe = jnp.maximum(l_scr[..., 0], 1e-30)
-            o_ref[...] = (acc_scr[...] / l_safe[..., None]).astype(o_ref.dtype)
+            l_safe = jnp.maximum(l_scr[...], 1e-30)
+            o_ref[...] = (acc_scr[...] / l_safe).astype(o_ref.dtype)
 
+    rows = pl.BlockSpec((None, hb, qr, d), lambda s, g, j, t, p: (s, g, 0, 0))
+    page_tile = pl.BlockSpec(
+        (None, hb, ps, d), lambda s, g, j, t, p: (t[s * P + j], g, 0, 0)
+    )
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
-        grid=(b, hk, P),
-        in_specs=[
-            pl.BlockSpec((None, None, qr, d), lambda s, g, j, t, p: (s, g, 0, 0)),
-            pl.BlockSpec(
-                (None, None, ps, d), lambda s, g, j, t, p: (t[s * P + j], g, 0, 0)
-            ),
-            pl.BlockSpec(
-                (None, None, ps, d), lambda s, g, j, t, p: (t[s * P + j], g, 0, 0)
-            ),
-        ],
-        out_specs=pl.BlockSpec(
-            (None, None, qr, d), lambda s, g, j, t, p: (s, g, 0, 0)
-        ),
+        grid=(b, hk // hb, P),
+        in_specs=[rows, page_tile, page_tile],
+        out_specs=rows,
         scratch_shapes=[
-            pltpu.VMEM((qr, 1), jnp.float32),
-            pltpu.VMEM((qr, 1), jnp.float32),
-            pltpu.VMEM((qr, d), jnp.float32),
+            pltpu.VMEM((hb, qr, 1), jnp.float32),
+            pltpu.VMEM((hb, qr, 1), jnp.float32),
+            pltpu.VMEM((hb, qr, d), jnp.float32),
         ],
     )
     out = pl.pallas_call(
@@ -964,6 +1007,7 @@ def _fused_paged_decode_forward(q, arena_k, arena_v, tables, pos, max_len,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, hk, qr, d), q.dtype),
         interpret=interpret,
+        name="paged_walk_decode",
     )(tab, pos_v, qg, arena_k, arena_v)
     out = out[:, :, :R].reshape(b, hk, rep, sq, d).reshape(b, h, sq, d)
     return jnp.transpose(out, (0, 2, 1, 3))
@@ -1002,7 +1046,8 @@ _fused_paged_decode.defvjp(_fused_paged_decode_fwd, _fused_paged_decode_bwd)
 def _fused_paged_decode_quant_forward(q, arena_k, arena_v, k_scale, v_scale,
                                       tables, pos, max_len, scale,
                                       interpret=False):
-    """`_fused_paged_decode_forward` over an int8 arena (ISSUE 18): the K/V
+    """`_fused_paged_decode_forward` over an int8 arena (ISSUE 18), one
+    (page, kv head) tile a grid step (ROADMAP 3.3): the K/V
     page tiles arrive as int8 and their per-row scales ([1, page_size]
     float32 tiles from the parallel scale arenas `[num_pages, kv_h, 1,
     page_size]`, addressed by the SAME `t[s*P+j]` table lookup in their
@@ -1208,7 +1253,9 @@ def _fused_paged_decode_partials_forward(q, arena_k, arena_v, tables,
                                          v_scale=None):
     """The fused paged-decode kernel in PARTIALS form, for context-parallel
     decode (ISSUE 20): identical page-walk, GQA/verify packing, and online-
-    softmax recurrence to `_fused_paged_decode_forward`, with two changes.
+    softmax recurrence to `_fused_paged_decode_forward` (but one (page, kv
+    head) tile a grid step, that kernel's grid before PR 30; ROADMAP 3.3),
+    with two changes.
 
     (1) Table columns no longer imply token positions.  Under cp, shard s
     holds sequence pages {s, s+cp, ...} as LOCAL table columns 0..P_l-1, so

@@ -283,6 +283,27 @@ def reset_flash_pallas():
         _flash_pallas.clear()
 
 
+_paged_walks = {}  # (b, sq, heads_per_step, grid_steps, kv_bytes_per_step), in order of first trace
+
+
+def record_paged_walk(b, sq, heads_per_step, grid_steps, kv_bytes_per_step):
+    """The geometry the page-walk decode kernel took for one traced call
+    (ops/flash_attention.py picks it from the static shape): recorded at
+    trace time, like `record_flash_pallas_call`."""
+    key = (int(b), int(sq), int(heads_per_step), int(grid_steps), int(kv_bytes_per_step))
+    with _counters_lock:
+        _paged_walks[key] = None
+
+
+def paged_walk_summary():
+    """One entry per distinct walk traced since the last reset (a model's
+    layers trace the same one): slots `b`, q rows a slot `sq`, KV heads a
+    grid step moves, grid steps a call, K and V bytes a step copies."""
+    fields = ("b", "sq", "heads_per_step", "grid_steps", "kv_bytes_per_step")
+    with _counters_lock:
+        return [dict(zip(fields, key)) for key in _paged_walks]
+
+
 def reset():
     """Zero EVERY counter family (step, serving, paging, router, flash
     fallbacks) in one critical section.  bench.py calls this between legs
@@ -303,6 +324,7 @@ def reset():
         _reset_session_locked()
         _flash_fallbacks.clear()
         _flash_pallas.clear()
+        _paged_walks.clear()
         _reset_moe_locked()
 
 

@@ -81,7 +81,74 @@ def _both(q, ak, av, tables, pos, max_len):
     return np.asarray(fused), np.asarray(gather)
 
 
+@pytest.fixture
+def heads_per_step(monkeypatch):
+    """Force how many KV heads a grid step of the walk moves (the program
+    picks it from the static shape alone; a test steers it here)."""
+    def force(hb):
+        def pick(hk, *shape):
+            assert hk % hb == 0
+            return hb
+
+        monkeypatch.setattr(fa, "_pick_kv_heads_block", pick)
+
+    return force
+
+
+def _divisors(n):
+    return [i for i in range(1, n + 1) if n % i == 0]
+
+
 class TestFusedVsGather:
+    @pytest.mark.parametrize("sq", [1, 4])
+    @pytest.mark.parametrize(
+        "hk,hb", [(hk, hb) for hk in (4, 6) for hb in _divisors(hk)]
+    )
+    def test_heads_per_step_parity(self, heads_per_step, hk, hb, sq):
+        """Every divisor of `hk` as the heads a step moves gives the gather
+        oracle's result: the head is a batch dim of both dots, nothing else.
+        Ragged frontiers, GQA packing (rep 2), max_len below the table span."""
+        heads_per_step(hb)
+        ak, av = _arena(num_pages=9, ps=8, hk=hk, d=16, seed=hk)
+        r = np.random.RandomState(17 + hb)
+        q = jnp.asarray(r.rand(4, sq, 2 * hk, 16).astype(np.float32) - 0.5)
+        tables = jnp.asarray(
+            [[1, 2, 3, 4], [5, 6, 0, 0], [7, 0, 0, 0], [8, 3, 5, 1]],
+            jnp.int32,
+        )
+        pos = jnp.asarray([27, 11, 3, 20], jnp.int32)
+        profiler.reset()
+        fused, gather = _both(q, ak, av, tables, pos, max_len=28)
+        np.testing.assert_allclose(fused, gather, rtol=2e-5, atol=2e-5)
+        (walk,) = profiler.paged_walk_summary()
+        assert walk["heads_per_step"] == hb
+        assert walk["grid_steps"] == 4 * (hk // hb) * 4
+
+    @pytest.mark.parametrize("sq", [1, 4])
+    def test_stale_entries_past_the_last_page_are_inert(self, sq):
+        """A table that still names pages past a slot's newest visible one
+        (here pages of NaNs) reads as the table with zeros there: those steps
+        repeat the last visible page's block, so nothing is copied for them,
+        and `needed` skips their compute."""
+        ak, av = _arena(num_pages=9, ps=8, hk=2, d=16, seed=9)
+        ak, av = (a.at[jnp.asarray([6, 8])].set(jnp.nan) for a in (ak, av))
+        r = np.random.RandomState(19)
+        q = jnp.asarray(r.rand(3, sq, 4, 16).astype(np.float32) - 0.5)
+        clean = np.array([[1, 2, 0, 0], [5, 0, 0, 0], [7, 3, 4, 0]], np.int32)
+        stale = np.array([[1, 2, 6, 8], [5, 8, 6, 8], [7, 3, 4, 6]], np.int32)
+        # the newest visible row, pos + sq - 1, stays on the last clean page
+        pos = jnp.asarray([12 - sq, 7 - sq, 20], jnp.int32)
+        with _interpret():
+            got = [
+                np.asarray(fa.paged_decode_attention_array(
+                    q, ak, av, jnp.asarray(t), pos, 32, kernel="fused"))
+                for t in (clean, stale)
+            ]
+        np.testing.assert_array_equal(got[0], got[1])
+        gather = fa.paged_decode_attention_array(
+            q, ak, av, jnp.asarray(clean), pos, 32, kernel="gather")
+        np.testing.assert_allclose(got[1], np.asarray(gather), rtol=2e-5, atol=2e-5)
+
     @pytest.mark.parametrize("sq", [1, 4])
     def test_ragged_gqa_parity(self, sq):
         """Mixed per-slot positions (including a fresh slot at pos 0 and a
@@ -113,11 +180,15 @@ class TestFusedVsGather:
         np.testing.assert_allclose(fused, gather, rtol=2e-5, atol=2e-5)
         np.testing.assert_allclose(fused[0], fused[1], rtol=0, atol=0)
 
-    def test_spec_verify_window_with_scratch_overrun(self):
+    @pytest.mark.parametrize("hb", [None, 1, 2])
+    def test_spec_verify_window_with_scratch_overrun(self, heads_per_step, hb):
         """The [slots, k+1] verify shape: window rows attend j <= pos + i
         per row, and a window overrunning the mapped prefix reads scratch
         page 0 through table entry 0 — exactly what the gather path reads
-        for those rows, so parity covers the rejected-draft territory."""
+        for those rows, so parity covers the rejected-draft territory.
+        With the program's own pick of heads per step (None) and each forced."""
+        if hb is not None:
+            heads_per_step(hb)
         ak, av = _arena(seed=5)
         r = np.random.RandomState(13)
         q = jnp.asarray(r.rand(3, 4, 4, 16).astype(np.float32) - 0.5)
@@ -166,6 +237,47 @@ class TestFusedVsGather:
             profiler.flash_fallback_summary()["paged page_size not 8-aligned"]
             == 1
         )
+
+    def test_walk_geometry_follows_the_static_shape(self):
+        """`paged_walk_summary()` at Mistral widths and the serving cell's
+        arena, traced and not run: batch-32 decode and a verify window of 5
+        move all 8 KV heads of a page a step (512 steps for 4,096); a chunk
+        prefill, whose q rows fill VMEM, keeps one head a step."""
+        bf16 = jnp.bfloat16
+        arena = jax.ShapeDtypeStruct((513, 8, 128, 128), bf16)
+        profiler.reset()
+        for b, sq in ((32, 1), (32, 5), (1, 256), (1, 512)):
+            with _interpret():
+                jax.eval_shape(
+                    lambda q, ak, av, t, p: fa.paged_decode_attention_array(
+                        q, ak, av, t, p, 2048),
+                    jax.ShapeDtypeStruct((b, sq, 32, 128), bf16), arena, arena,
+                    jax.ShapeDtypeStruct((b, 16), jnp.int32),
+                    jax.ShapeDtypeStruct((b,), jnp.int32),
+                )
+        page = 128 * 128 * 2  # one head's K or V tile of a page, bf16
+        walks = {(w["b"], w["sq"]): w for w in profiler.paged_walk_summary()}
+        for key in ((32, 1), (32, 5)):
+            assert walks[key]["heads_per_step"] == 8
+            assert walks[key]["grid_steps"] == 32 * 16
+            assert walks[key]["kv_bytes_per_step"] == 2 * 8 * page
+        for key in ((1, 256), (1, 512)):
+            assert walks[key]["heads_per_step"] == 1
+            assert walks[key]["grid_steps"] == 8 * 16
+            assert walks[key]["kv_bytes_per_step"] == 2 * page
+        profiler.reset()
+        assert profiler.paged_walk_summary() == []
+
+    @pytest.mark.parametrize(
+        "hk,sq,want",
+        [(8, 1, 8), (8, 5, 8), (8, 256, 1), (8, 512, 1), (2, 1, 2), (2, 256, 1)],
+        ids=["decode", "verify5", "chunk256", "chunk512", "decode-tp4", "chunk256-tp4"],
+    )
+    def test_heads_per_step_picker(self, hk, sq, want):
+        """Mistral widths (4 q heads a KV head, page 128, head dim 128, bf16);
+        under tp=4 the kernel sees the local 2 KV heads."""
+        qr = -(-4 * sq // 8) * 8
+        assert fa._pick_kv_heads_block(hk, qr, 128, 128, 2) == want
 
 
 # ---------------------------------------------------------------------------

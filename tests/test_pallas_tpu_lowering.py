@@ -91,17 +91,17 @@ def _flash_cases():
         )
 
 
-def _paged_fn(quant):
+def _paged_fn(quant, max_len=MAX_LEN):
     if quant:
         return lambda q, ak, av, t, p, ks, vs: fa.paged_decode_attention_array(
-            q, ak, av, t, p, MAX_LEN, kernel="fused", k_scale=ks, v_scale=vs)
+            q, ak, av, t, p, max_len, kernel="fused", k_scale=ks, v_scale=vs)
     return lambda q, ak, av, t, p: fa.paged_decode_attention_array(
-        q, ak, av, t, p, MAX_LEN, kernel="fused")
+        q, ak, av, t, p, max_len, kernel="fused")
 
 
-def _paged_args(b, sq, h, hk, ps, quant, cp=1, mp=1):
+def _paged_args(b, sq, h, hk, ps, quant, cp=1, mp=1, max_len=MAX_LEN):
     d = 128
-    n_tab = MAX_LEN // ps
+    n_tab = max_len // ps
     pages = (b * n_tab // cp + 1) * cp
     mp_ax = "mp" if mp > 1 else None
     arena = P("cp" if cp > 1 else None, mp_ax, None, None)
@@ -141,6 +141,11 @@ def _paged_cases():
                        _paged_args(1, 256, h, hk, ps, quant), None)
                 yield (f"paged-partials-{tag}", _partials_fn(quant),
                        _paged_args(8, 1, h, hk, ps, quant), None)
+    # the serving cell's own walk (`mistral7b_serve.chat32`): 32 slots, 16
+    # table entries over a 513-page arena; decode and a verify window of 5
+    for sq in (1, 5):
+        yield (f"paged-chat32-q{sq}", _paged_fn(False, 2048),
+               _paged_args(32, sq, 32, 8, 128, False, max_len=2048), None)
     for cp, mp in ((1, 4), (2, 2)):  # the shard_map wrappers
         for quant in (False, True):
             yield (f"paged-cp{cp}-mp{mp}-{'int8' if quant else 'bf16'}",

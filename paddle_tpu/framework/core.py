@@ -421,8 +421,8 @@ define_flag(
 )
 define_flag(
     "FLAGS_serve_slots", 4,
-    "continuous-batching engine: number of KV-cache slots in the pooled "
-    "StaticKVCache (max concurrently decoding requests)",
+    "continuous-batching engine: number of slots (max concurrently "
+    "decoding requests)",
 )
 define_flag(
     "FLAGS_serve_queue_depth", 32,
@@ -465,12 +465,6 @@ define_flag(
     "plus prefix-cache holds, the free list is exact, no page leaks",
 )
 define_flag(
-    "FLAGS_serve_paged_kv", True,
-    "continuous-batching engine: back the KV cache with a block-paged pool "
-    "(per-slot page tables as traced data) instead of dense per-slot "
-    "buffers; False restores the dense slot pool (the bit-identity oracle)",
-)
-define_flag(
     "FLAGS_serve_kv_page_size", 128,
     "paged KV: tokens per page.  Clamped to the engine max_len; every "
     "sequence holds ceil(len/page_size) pages instead of a dense max_len "
@@ -479,8 +473,8 @@ define_flag(
 define_flag(
     "FLAGS_serve_kv_pool_pages", 0,
     "paged KV: total pages in the pool (page 0 is a permanent scratch page "
-    "for masked/inactive writes).  0 = auto: slots * pages_per_seq + 1, the "
-    "same HBM budget as the dense slot pool",
+    "for masked/inactive writes).  0 = auto: slots * pages_per_seq + 1, "
+    "every slot can hold a max_len sequence",
 )
 define_flag(
     "FLAGS_serve_prefix_cache", True,
@@ -504,15 +498,6 @@ define_flag(
     "speculative decoding: longest n-gram the prompt-lookup drafter matches "
     "against the slot's history (it backs off n..1 and proposes nothing on "
     "a miss — a prompt shorter than n just drafts from lower orders)",
-)
-define_flag(
-    "FLAGS_serve_decode_kernel", "auto",
-    "paged engine: attention kernel for the paged decode/verify hot path — "
-    "'auto' (fused Pallas kernel reading the arena through the page tables "
-    "in-kernel when on TPU and the shape is eligible, else gather-then-"
-    "dense), 'fused' (require the fused kernel; engine construction fails "
-    "if it cannot run), or 'gather' (force the materialized-gather oracle "
-    "the fused kernel is parity-tested against)",
 )
 define_flag(
     "FLAGS_serve_kv_quant", "none",
@@ -545,7 +530,7 @@ define_flag(
     "one device's arena; each shard runs the fused paged-decode kernel "
     "over its local page-table slice and the shards merge per-row online-"
     "softmax partials (m, l, acc) with one pmax + two psums per step.  "
-    "Requires the paged engine and role=colocated; pool auto-sizing and "
+    "Requires role=colocated; pool auto-sizing and "
     "admission headroom become per-shard quantities.  1 disables",
 )
 define_flag(
@@ -555,7 +540,7 @@ define_flag(
     "pages in the prefix cache so turn N+1 chunk-prefills only the unshared "
     "suffix; sessions beyond this bound (or under page pressure once the "
     "unpinned prefix cache is exhausted) are evicted whole, LRU first.  "
-    "Requires the paged engine with the prefix cache enabled",
+    "Requires the prefix cache enabled",
 )
 define_flag(
     "FLAGS_serve_role", "colocated",
@@ -565,8 +550,7 @@ define_flag(
     "and exports the quantized page rows + prefix-chain metadata as a "
     "handoff payload; never decodes past the first token), or 'decode' "
     "(imports handoff payloads into its own page arena via a compiled "
-    "page scatter and streams the remaining tokens).  prefill/decode "
-    "roles require the paged engine (the handoff rides the page arenas)",
+    "page scatter and streams the remaining tokens)",
 )
 define_flag(
     "FLAGS_serve_reserve_ttl_s", 30.0,

@@ -210,7 +210,7 @@ def serve(path_or_predictor, port=8866, host="127.0.0.1", block=True,
       isn't there.  Unconsumed reservations expire after ttl_s.
 
     A ContinuousBatchingEngine serves /generate with true continuous
-    batching: concurrent requests decode interleaved in the slot pool, each
+    batching: concurrent requests decode interleaved in its slots, each
     finishing on its own EOS/length (the lock-based predictors serialize).
 
     Serving fault domain (PR 6): an engine-backed server runs under a

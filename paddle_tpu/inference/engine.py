@@ -3,46 +3,45 @@ runtime's flash-decode serving path, SURVEY §2.1 L8 — scheduling layer).
 
 The lock-step `GenerationPredictor` runs every request in a batch from first
 token to last together: one long generation holds the whole batch hostage,
-and a new request waits for the batch to drain.  This engine instead owns a
-persistent SLOT POOL of `StaticKVCache` buffers (`[slots, max_len, kv_heads,
-head_dim]` per layer) and runs ONE compiled decode step whatever the
-occupancy: per-slot `pos` and `active` masks are DATA, never shapes, so
-requests joining, finishing, and slots being recycled cause zero recompiles
-after warmup.
+and a new request waits for the batch to drain.  This engine instead keeps
+`slots` sequences in flight over ONE block-paged KV ARENA per layer
+(`[num_pages, kv_heads, page_size, width]` per row kind the model declares
+through `cache_rows()`) and runs ONE compiled decode step whatever the
+occupancy: per-slot `pos`, `active` masks and the page tables
+(`[slots, max_pages_per_seq]` int32) are DATA, never shapes, so requests
+joining, finishing, and slots being recycled cause zero recompiles after
+warmup.  A request only occupies pages covering `prompt + max_new_tokens`,
+so the arena serves more concurrent sequences than `slots * max_len` rows
+would.
 
 New requests are prefilled through length-bucketed compiled prefill
 executables — the prompt pads up to its bucket, attends to itself causally,
-and its K/V land in the assigned pool slot (slot index is data too, so one
-executable per bucket serves every slot).  Prefills interleave with in-flight
-decode at step granularity; finished slots (EOS or max_new_tokens) are
-recycled immediately.
+and its K/V scatter into the pages of its table row (the row is data, so one
+executable per bucket serves every slot); a prompt longer than the largest
+bucket is admitted as consecutive chunks of it.  Prefills interleave with
+in-flight decode at step granularity; finished slots (EOS or
+max_new_tokens) are recycled immediately.
 
-Why padding garbage is safe: a prefill writes rows [0, bucket) of its slot,
-rows [true_len, bucket) holding padding K/V.  Decode at position p first
-overwrites row p, then attends rows j <= p only — every garbage row is
-overwritten by the decode step that first brings it into the attended window.
-Inactive slots decode with pos forced to 0; their row-0 write is scratch
-because the next prefill into that slot always rewrites row 0.
+Why padding garbage is safe: padding rows of a prefill never touch a mapped
+page (they fall on the scratch page, page 0 of each shard, which no sequence
+maps, or are dropped), and inactive slots decode at pos 0 through an
+all-zero table row, so their write is scratch too.  Rows of a mapped page
+past a sequence's `pos` are masked to zero weight, and the decode step that
+first brings such a row into the attended window overwrites it first.
 
-Compiled-executable budget: len(prefill_buckets) + 1 (asserted by tests via
-`compile_counts()`).  Both functions ride @to_static, so PR 3's persistent
-compile cache and AOT snapshots apply per bucket: a restarted server binds
-the previous process's executables without tracing.
-
-Paged KV (ISSUE 7, default on via FLAGS_serve_paged_kv): instead of one
-dense `[slots, max_len, ...]` buffer per layer, K/V live in a block-paged
-ARENA `[num_pages, kv_heads, page_size, head_dim]` addressed through
-per-slot page tables (`[slots, max_pages_per_seq]` int32) that ride the
-compiled steps as DATA — join/finish/recycle still cause zero recompiles.
-A request only occupies pages covering `prompt + max_new_tokens`, so the
-same KV budget serves far more concurrent sequences than `slots * max_len`
-dense rows.  A host-side `PrefixCache` indexes committed prompt pages:
-a request sharing a cached prefix maps the shared full pages READ-ONLY
+Prefix cache: a host-side `PrefixCache` indexes committed prompt pages.  A
+request sharing a cached prefix maps the shared full pages READ-ONLY
 (refcounted), copy-on-writes only a partially filled shared page, and
-prefills just the unshared suffix through a chunk-prefill executable
-(rope offset and page table as data).  The compiled budget becomes
-2 * len(buckets) + 2 (fresh + chunk per bucket, decode, page copy); the
-`compile_counts()` contract keys are prefill/chunk_prefill/decode/copy.
+prefills just the unshared suffix through a chunk-prefill executable (rope
+offset and page table as data).
+
+Compiled-executable budget: 2 * len(prefill_buckets) + 2 (fresh + chunk per
+bucket, decode, page copy), asserted by tests via `compile_counts()` (keys
+prefill/chunk_prefill/decode/copy; a decode-role engine adds `import`,
+speculation adds `verify`).  Every function rides @to_static, so the
+persistent compile cache and AOT snapshots apply per bucket: a restarted
+server binds the previous process's executables without tracing.
+
 Admission gates on pages: submit raises QueueFull when a request's worst
 case page need exceeds the pool, and the scheduler defers admission (the
 request stays at the head of the line) until free + cache-evictable pages
@@ -65,8 +64,8 @@ Serving fault domain (the serving mirror of the training fault domain):
   generation counter (the stale thread aborts at its next state touch),
   re-queues in-flight requests that emitted no tokens yet, fails the rest
   with the typed `EngineRestarted` error, and rebinds the SAME compiled
-  executables and KV pool: 0 fresh compiles, asserted by the chaos drills.
-  Reusing the pool un-scrubbed is safe by the padding-garbage invariant
+  executables and KV arena: 0 fresh compiles, asserted by the chaos drills.
+  Reusing the arena un-scrubbed is safe by the padding-garbage invariant
   above.
 - **Injectable faults** — `serve.prefill.hang` (blocks the prefill
   dispatch), `serve.decode.nan` (poisons ONE slot's logits with NaN as
@@ -74,7 +73,7 @@ Serving fault domain (the serving mirror of the training fault domain):
   are bit-identical to an unpoisoned run), `serve.loop.crash` (kills the
   scheduler thread) — armed via the usual `FLAGS_fault_inject` registry.
 
-Speculative decoding (ISSUE 11, paged engines, FLAGS_serve_spec_k > 0):
+Speculative decoding (ISSUE 11, FLAGS_serve_spec_k > 0):
 decode is HBM-bandwidth-bound — one token per step leaves the FLOPs idle —
 so the engine drafts k candidate tokens per greedy slot with a host-side
 prompt-lookup `NgramDrafter` (no second model; spec.py) and the target
@@ -116,8 +115,6 @@ from ..models.llama import (
     PagedDecodeView,
     PagedKVCache,
     PagedPrefillView,
-    SlotView,
-    StaticKVCache,
 )
 from ..tensor import Tensor
 from .paging import (
@@ -359,7 +356,7 @@ class ContinuousBatchingEngine:
     """
 
     def __init__(self, model, slots=None, max_len=None, prefill_buckets=None,
-                 queue_depth=None, seed=0, paged=None, page_size=None,
+                 queue_depth=None, seed=0, page_size=None,
                  pool_pages=None, prefix_cache=None, spec_k=None, lora=None,
                  decode_kernel=None, tp=None, kv_quant=None, role=None,
                  cp=None, session_max=None):
@@ -369,9 +366,8 @@ class ContinuousBatchingEngine:
 
         cfg = model.config
         self.model = model
-        # what the served model cannot do, by the constructor argument's name
-        # ('dense': the slot engine, paged=False); each is checked where the
-        # argument is resolved, flags included
+        # what the served model cannot do, by the constructor argument's name;
+        # each is checked where the argument is resolved, flags included
         unsupported = frozenset(getattr(model, "engine_unsupported", ()))
 
         def refuse(feature, on, asked):
@@ -457,10 +453,6 @@ class ContinuousBatchingEngine:
         # fused kernel's limits speak of the first kind's geometry (K's)
         rows = list(model.cache_rows())
         _, kv_heads, head_dim, cache_dtype = rows[0]
-        self.paged = bool(
-            _fcore.flag("FLAGS_serve_paged_kv") if paged is None else paged
-        )
-        refuse("dense", not self.paged, "paged=False")
         # quantized KV serving (ISSUE 18): validated HERE — typed
         # QuantConfigError at construction, never a dtype mismatch inside a
         # compiled step — and folded into every cache-key surface: the
@@ -469,8 +461,7 @@ class ContinuousBatchingEngine:
         # the AOT snapshot fingerprint
         self.kv_quant = validate_kv_quant(
             _fcore.flag("FLAGS_serve_kv_quant") if kv_quant is None
-            else kv_quant,
-            paged=self.paged,
+            else kv_quant
         )
         refuse("kv_quant", self.kv_quant != "none", f"kv_quant={self.kv_quant!r}")
         # disaggregated serving (ISSUE 19): the role decides which side of
@@ -487,192 +478,158 @@ class ContinuousBatchingEngine:
             raise ValueError(
                 f"role must be colocated|prefill|decode, got {self.role!r}"
             )
-        if self.role != "colocated" and not self.paged:
-            raise ValueError(
-                f"role={self.role!r} requires the paged engine: the "
-                "prefill->decode handoff rides the page arenas"
-            )
-        if self.cp > 1 and not self.paged:
-            raise ShardingError(
-                f"cp={self.cp} requires the paged engine: context "
-                "parallelism shards the page arena, not dense slot buffers"
-            )
         if self.cp > 1 and self.role != "colocated":
             raise ShardingError(
                 f"cp={self.cp} with role={self.role!r}: the disaggregated "
                 "handoff assumes single-shard page ownership; run cp on "
                 "colocated replicas"
             )
-        if self.paged:
-            ps = int(
-                page_size if page_size is not None
-                else _fcore.flag("FLAGS_serve_kv_page_size")
+        ps = int(
+            page_size if page_size is not None
+            else _fcore.flag("FLAGS_serve_kv_page_size")
+        )
+        # a page never needs to exceed a sequence; clamping keeps the
+        # default flag sane for tiny test engines
+        self.page_size = max(1, min(ps, self.max_len))
+        self.pages_per_seq = -(-self.max_len // self.page_size)
+        if self.cp > 1:
+            # per-shard geometry: sequence page k lives on shard k % cp,
+            # so the table width pads to a cp multiple (shard s's local
+            # table is exactly columns {s, s+cp, ...}) and every shard
+            # holds pages_per_seq/cp entries of a full-length sequence
+            self.pages_per_seq = -(-self.pages_per_seq // self.cp) * self.cp
+        # paged-attention kernel selection (ISSUE 13): validated HERE so
+        # a forced-fused engine fails at construction, not mid-traffic
+        # inside a compiled step
+        dk = "auto" if decode_kernel is None else str(decode_kernel)
+        if dk not in ("auto", "fused", "gather"):
+            raise ValueError(
+                f"decode_kernel must be auto|fused|gather, got {dk!r}"
             )
-            # a page never needs to exceed a sequence; clamping keeps the
-            # default flag sane for tiny test engines
-            self.page_size = max(1, min(ps, self.max_len))
-            self.pages_per_seq = -(-self.max_len // self.page_size)
-            if self.cp > 1:
-                # per-shard geometry: sequence page k lives on shard k % cp,
-                # so the table width pads to a cp multiple (shard s's local
-                # table is exactly columns {s, s+cp, ...}) and every shard
-                # holds pages_per_seq/cp entries of a full-length sequence
-                self.pages_per_seq = -(-self.pages_per_seq // self.cp) * self.cp
-            # paged-attention kernel selection (ISSUE 13): validated HERE so
-            # a forced-fused engine fails at construction, not mid-traffic
-            # inside a compiled step
-            dk = str(
-                _fcore.flag("FLAGS_serve_decode_kernel")
-                if decode_kernel is None else decode_kernel
-            )
-            if dk not in ("auto", "fused", "gather"):
+        if dk == "fused":
+            head_ok = head_dim <= 256
+            page_ok = self.page_size % 8 == 0
+            if not (head_ok and page_ok):
                 raise ValueError(
-                    f"decode_kernel must be auto|fused|gather, got {dk!r}"
+                    "decode_kernel='fused' needs head_dim <= 256 and a "
+                    f"sublane-aligned page_size (8|ps); got head_dim="
+                    f"{head_dim}, page_size={self.page_size}"
                 )
-            if dk == "fused":
-                head_ok = head_dim <= 256
-                page_ok = self.page_size % 8 == 0
-                if not (head_ok and page_ok):
-                    raise ValueError(
-                        "decode_kernel='fused' needs head_dim <= 256 and a "
-                        f"sublane-aligned page_size (8|ps); got head_dim="
-                        f"{head_dim}, page_size={self.page_size}"
-                    )
-            self.decode_kernel = dk
-            pp = int(
-                pool_pages if pool_pages is not None
-                else _fcore.flag("FLAGS_serve_kv_pool_pages")
-            )
-            cache_dtype_bytes = int(
-                np.dtype(_fcore.to_jax_dtype(cache_dtype)).itemsize
-            )
-            if pp <= 0:  # auto: every slot can hold a max_len sequence
-                pp = self.slots * self.pages_per_seq + 1
-                if self.cp > 1:
-                    # PER-SHARD auto-sizing (ISSUE 20): each shard stores
-                    # pages_per_seq/cp pages of every slot's sequence plus
-                    # its own scratch page — the pool total is cp * that,
-                    # the same per-device HBM budget as the cp=1 pool
-                    pp = self.cp * (
-                        self.slots * (self.pages_per_seq // self.cp) + 1
-                    )
-                if self.kv_quant == "int8":
-                    # same HBM budget, more pages: the auto pool holds the
-                    # BYTES of the full-precision pool, so the int8 arena's
-                    # page count scales by full_page_bytes / (int8 page +
-                    # its scale rows) — ~1.94x at bf16 head_dim=128.  Scale
-                    # bytes are charged here, not hidden: the ratio uses
-                    # kv_page_bytes which counts the 4-byte f32 scale per
-                    # (row, kv head)
-                    full = kv_page_bytes(
-                        self.page_size, kv_heads, head_dim,
-                        cache_dtype_bytes, "none",
-                    )
-                    q8 = kv_page_bytes(
-                        self.page_size, kv_heads, head_dim,
-                        cache_dtype_bytes, "int8",
-                    )
-                    pp = (self.slots * self.pages_per_seq * full) // q8 + 1
+        self.decode_kernel = dk
+        pp = int(
+            pool_pages if pool_pages is not None
+            else _fcore.flag("FLAGS_serve_kv_pool_pages")
+        )
+        cache_dtype_bytes = int(
+            np.dtype(_fcore.to_jax_dtype(cache_dtype)).itemsize
+        )
+        if pp <= 0:  # auto: every slot can hold a max_len sequence
+            pp = self.slots * self.pages_per_seq + 1
             if self.cp > 1:
-                # the pool block-shards over cp: equal per-shard ranges,
-                # each with its own scratch page at the range head
-                pp = max(pp, 2 * self.cp)
-                pp = -(-pp // self.cp) * self.cp
-            self.pool_pages = int(pp)
-            self._caches = None
-            self._arenas = [
-                PagedKVCache(self.pool_pages, self.page_size, rows=rows,
-                             quant=self.kv_quant)
-                for _ in range(cfg.num_hidden_layers)
-            ]
-            if self.tp > 1 or self.cp > 1:
-                for a in self._arenas:
-                    shard_kv_for_tp(a)
-            # observability (ISSUE 18): arena + scale HBM bytes as set (not
-            # accumulated) gauges, all layers included — /metrics renders
-            # them as paddle_kv_quant_*
-            scale_b = (
-                2 * self.page_size * kv_heads * 4
-                if self.kv_quant == "int8" else 0
-            )
-            # bytes per row kind, all layers, as the buffers hold them
-            by_kind = {
-                n: cfg.num_hidden_layers * int(np.prod(getattr(self._arenas[0], n).shape))
-                * int(np.dtype(getattr(self._arenas[0], n)._data.dtype).itemsize)
-                for n in self._arenas[0].row_names
-            }
-            _prof.record_kv_quant(
-                mode=self.kv_quant,
-                arena_bytes=sum(by_kind.values()),
-                scale_bytes=cfg.num_hidden_layers * self.pool_pages * scale_b,
-            )
-            _prof.record_arena_bytes(by_kind)
-            self._pool = PagePool(self.pool_pages, shards=self.cp)
-            use_prefix = bool(
-                _fcore.flag("FLAGS_serve_prefix_cache")
-                if prefix_cache is None else prefix_cache
-            )
-            self._prefix = PrefixCache(self.page_size) if use_prefix else None
-            # session KV (ISSUE 20): named multi-turn holds on prefix-cache
-            # chains.  Rides the prefix cache — without it, session_id still
-            # parses but every turn re-prefills statelessly.
-            self._sessions = (
-                SessionStore(capacity=int(
-                    _fcore.flag("FLAGS_serve_session_max")
-                    if session_max is None else session_max
-                ))
-                if self._prefix is not None else None
-            )
-            # ignore sub-threshold matches: an accidental few-token overlap
-            # between unrelated prompts must not flip a request onto the
-            # chunk-prefill path (and its different first-token rounding)
-            self.min_prefix_match = 8
-            self._page_table = np.zeros(
-                (self.slots, self.pages_per_seq), np.int32
-            )
-            self._slot_pages = [[] for _ in range(self.slots)]
-            self._tables_t = None  # device mirror, rebuilt with _dev
-            self._decode_fn = jit.to_static(self._decode_paged_body)
-            self._prefill_fn = jit.to_static(self._prefill_paged_body)
-            self._chunk_fn = jit.to_static(self._chunk_prefill_body)
-            self._copy_fn = jit.to_static(self._copy_page_body)
-            # handoff geometry, captured once: submit() validates incoming
-            # payloads against it and the exporter stamps it on the wire
-            self._kv_heads = int(kv_heads)
-            self._head_dim = int(head_dim)
-            self._kv_dtype_np = np.dtype(_fcore.to_jax_dtype(cache_dtype))
-            # the import scatter is built ONLY for decode-role engines, so
-            # colocated/prefill compile_counts() keep their exact dict shape
-            self._import_fn = (
-                jit.to_static(
-                    self._import_page_q8_body if self.kv_quant == "int8"
-                    else self._import_page_body
+                # PER-SHARD auto-sizing (ISSUE 20): each shard stores
+                # pages_per_seq/cp pages of every slot's sequence plus
+                # its own scratch page — the pool total is cp * that,
+                # the same per-device HBM budget as the cp=1 pool
+                pp = self.cp * (
+                    self.slots * (self.pages_per_seq // self.cp) + 1
                 )
-                if self.role == "decode" else None
+            if self.kv_quant == "int8":
+                # same HBM budget, more pages: the auto pool holds the
+                # BYTES of the full-precision pool, so the int8 arena's
+                # page count scales by full_page_bytes / (int8 page +
+                # its scale rows) — ~1.94x at bf16 head_dim=128.  Scale
+                # bytes are charged here, not hidden: the ratio uses
+                # kv_page_bytes which counts the 4-byte f32 scale per
+                # (row, kv head)
+                full = kv_page_bytes(
+                    self.page_size, kv_heads, head_dim,
+                    cache_dtype_bytes, "none",
+                )
+                q8 = kv_page_bytes(
+                    self.page_size, kv_heads, head_dim,
+                    cache_dtype_bytes, "int8",
+                )
+                pp = (self.slots * self.pages_per_seq * full) // q8 + 1
+        if self.cp > 1:
+            # the pool block-shards over cp: equal per-shard ranges,
+            # each with its own scratch page at the range head
+            pp = max(pp, 2 * self.cp)
+            pp = -(-pp // self.cp) * self.cp
+        self.pool_pages = int(pp)
+        self._arenas = [
+            PagedKVCache(self.pool_pages, self.page_size, rows=rows,
+                         quant=self.kv_quant)
+            for _ in range(cfg.num_hidden_layers)
+        ]
+        if self.tp > 1 or self.cp > 1:
+            for a in self._arenas:
+                shard_kv_for_tp(a)
+        # observability (ISSUE 18): arena + scale HBM bytes as set (not
+        # accumulated) gauges, all layers included — /metrics renders
+        # them as paddle_kv_quant_*
+        scale_b = (
+            2 * self.page_size * kv_heads * 4
+            if self.kv_quant == "int8" else 0
+        )
+        # bytes per row kind, all layers, as the buffers hold them
+        by_kind = {
+            n: cfg.num_hidden_layers * int(np.prod(getattr(self._arenas[0], n).shape))
+            * int(np.dtype(getattr(self._arenas[0], n)._data.dtype).itemsize)
+            for n in self._arenas[0].row_names
+        }
+        _prof.record_kv_quant(
+            mode=self.kv_quant,
+            arena_bytes=sum(by_kind.values()),
+            scale_bytes=cfg.num_hidden_layers * self.pool_pages * scale_b,
+        )
+        _prof.record_arena_bytes(by_kind)
+        self._pool = PagePool(self.pool_pages, shards=self.cp)
+        use_prefix = bool(
+            _fcore.flag("FLAGS_serve_prefix_cache")
+            if prefix_cache is None else prefix_cache
+        )
+        self._prefix = PrefixCache(self.page_size) if use_prefix else None
+        # session KV (ISSUE 20): named multi-turn holds on prefix-cache
+        # chains.  Rides the prefix cache — without it, session_id still
+        # parses but every turn re-prefills statelessly.
+        self._sessions = (
+            SessionStore(capacity=int(
+                _fcore.flag("FLAGS_serve_session_max")
+                if session_max is None else session_max
+            ))
+            if self._prefix is not None else None
+        )
+        # ignore sub-threshold matches: an accidental few-token overlap
+        # between unrelated prompts must not flip a request onto the
+        # chunk-prefill path (and its different first-token rounding)
+        self.min_prefix_match = 8
+        self._page_table = np.zeros(
+            (self.slots, self.pages_per_seq), np.int32
+        )
+        self._slot_pages = [[] for _ in range(self.slots)]
+        self._tables_t = None  # device mirror, rebuilt with _dev
+        self._decode_fn = jit.to_static(self._decode_paged_body)
+        self._prefill_fn = jit.to_static(self._prefill_paged_body)
+        self._chunk_fn = jit.to_static(self._chunk_prefill_body)
+        self._copy_fn = jit.to_static(self._copy_page_body)
+        # handoff geometry, captured once: submit() validates incoming
+        # payloads against it and the exporter stamps it on the wire
+        self._kv_heads = int(kv_heads)
+        self._head_dim = int(head_dim)
+        self._kv_dtype_np = np.dtype(_fcore.to_jax_dtype(cache_dtype))
+        # the import scatter is built ONLY for decode-role engines, so
+        # colocated/prefill compile_counts() keep their exact dict shape
+        self._import_fn = (
+            jit.to_static(
+                self._import_page_q8_body if self.kv_quant == "int8"
+                else self._import_page_body
             )
-        else:
-            self._arenas = None
-            self._pool = None
-            self._prefix = None
-            self._sessions = None
-            self._import_fn = None
-            self.decode_kernel = "auto"  # dense engines have no paged path
-            self._caches = [
-                StaticKVCache(self.slots, self.max_len, kv_heads,
-                              head_dim, cache_dtype)
-                for _ in range(cfg.num_hidden_layers)
-            ]
-            if self.tp > 1:
-                for c in self._caches:
-                    shard_kv_for_tp(c)
-            self._decode_fn = jit.to_static(self._decode_body)
-            self._prefill_fn = jit.to_static(self._prefill_body)
+            if self.role == "decode" else None
+        )
         # multi-tenant LoRA (ISSUE 12): an AdapterArena whose per-slot ids
-        # ride the paged executables as DATA — co-batched slots on different
+        # ride the executables as DATA — co-batched slots on different
         # adapters share one compiled step, id 0 is the base passthrough
         refuse("lora", lora is not None, "lora=")
-        if lora is not None and not self.paged:
-            raise ValueError("LoRA serving requires the paged engine")
         self._lora = lora
         if lora is not None and self.tp > 1:
             lora.shard_for_tp()
@@ -680,13 +637,13 @@ class ContinuousBatchingEngine:
         # _page_table's lifecycle: set at slot landing, cleared at recycle
         self._slot_adapter = np.zeros(self.slots, np.int32)
         self._adapters_t = None  # device mirror, rebuilt with _dev
-        # speculative decoding (paged engines only — it rides the page
-        # scatter's scratch redirect for rejected-row safety)
+        # speculative decoding: it rides the page scatter's scratch redirect
+        # for rejected-row safety
         sk = int(_fcore.flag("FLAGS_serve_spec_k") if spec_k is None else spec_k)
         if sk < 0:
             raise ValueError("spec_k must be >= 0")
-        refuse("spec_k", sk > 0 and self.paged, f"spec_k={sk}")
-        self.spec_k = sk if self.paged else 0
+        refuse("spec_k", sk > 0, f"spec_k={sk}")
+        self.spec_k = sk
         self._spec_on = self.spec_k > 0
         self._spec_ngram = int(_fcore.flag("FLAGS_serve_spec_ngram"))
         self._verify_fn = (
@@ -701,10 +658,8 @@ class ContinuousBatchingEngine:
 
         # runtime-sanitizer bookkeeping: after warmup() the scheduler tick
         # runs inside a steady_state region (every fresh trace/compile/sync
-        # in it is a finding); buckets traced so far are tracked so the
-        # legitimate over-bucket growth path can declare itself allowed
+        # in it is a finding)
         self._warmed = False
-        self._warm_buckets = set()
 
         # host-side slot table — mutated only under _mu, by the scheduler
         # generation that owns the engine (restart supersedes via _gen)
@@ -761,88 +716,25 @@ class ContinuousBatchingEngine:
 
     # -- compiled bodies ----------------------------------------------------
 
-    def _decode_body(self, toks, pos, active, temps, poison, key):
+    def _decode_paged_body(self, toks, pos, active, temps, poison, key, tables,
+                           adapters):
         """One token for every slot: toks [S,1], pos [S], active [S] bool,
         temps [S] f32 (0 = greedy, >0 = sampled — per-slot, as data), poison
         [S] bool (chaos-only NaN injection — identity when all-False), key
         uint32[2].  Inactive slots run at pos 0 (scratch, see module doc).
-        Returns (next tokens [S,1], advanced pos [S], finite [S], key): the
-        loop state is device-resident and threads straight back in — between
-        membership changes a decode step costs one executable dispatch plus
-        the [S] token fetch, zero host->device transfers.  `finite` is the
-        per-slot non-finite-logit-window watch: a poisoned/diverged slot
-        errors alone, its co-batched rows are independent."""
-        import jax
-        import jax.numpy as jnp
-
-        from ..ops.dispatch import apply
-
-        pos_eff = apply(
-            lambda p, a: jnp.where(a, p, 0), [pos, active], name="serve_pos_mask"
-        )
-        hidden, _ = self.model.backbone(toks, caches=self._caches, pos=pos_eff)
-        logits = self.model.lm_head(hidden)[:, -1]  # [S, V]
-
-        def f(lg, ky, tp, p, a, po):
-            lgf = lg.astype(jnp.float32)
-            lgf = jnp.where(po[:, None], jnp.nan, lgf)
-            finite = jnp.all(jnp.isfinite(lgf), axis=-1) | ~a
-            greedy = jnp.argmax(lgf, axis=-1).astype(jnp.int32)
-            ky, sub = jax.random.split(ky)
-            samp = jax.random.categorical(
-                sub, lgf / jnp.maximum(tp, 1e-6)[:, None], axis=-1
-            ).astype(jnp.int32)
-            nxt = jnp.where(tp > 0.0, samp, greedy)
-            return nxt[:, None], jnp.where(a, p + 1, p), finite, ky
-
-        nxt, new_pos, finite, key = apply(
-            f, [logits, key, temps, pos, active, poison], multi=True,
-            name="serve_sample",
-        )
-        return nxt, new_pos, finite, key
-
-    def _prefill_body(self, toks, slot, true_len, temp, key):
-        """Bucketed prefill: toks [1, bucket] (right-padded), slot / true_len
-        scalars (data).  Writes K/V into pool rows [0, bucket) of `slot` and
-        returns the first generated token from the logits at true_len - 1."""
-        import jax
-        import jax.numpy as jnp
-        from jax import lax
-
-        from ..ops.dispatch import apply
-
-        views = [SlotView(c, slot) for c in self._caches]
-        hidden, _ = self.model.backbone(toks, caches=views)
-        h_last = apply(
-            lambda h, n: lax.dynamic_slice_in_dim(h, n - 1, 1, 1),
-            [hidden, true_len], name="serve_prefill_last",
-        )
-        logits = self.model.lm_head(h_last)[:, -1]  # [1, V]
-
-        def f(lg, ky, tp):
-            lgf = lg.astype(jnp.float32)
-            greedy = jnp.argmax(lgf, axis=-1).astype(jnp.int32)
-            ky, sub = jax.random.split(ky)
-            samp = jax.random.categorical(
-                sub, lgf / jnp.maximum(tp, 1e-6), axis=-1
-            ).astype(jnp.int32)
-            return jnp.where(tp > 0.0, samp, greedy), ky
-
-        nxt, key = apply(f, [logits, key, temp], multi=True, name="serve_sample1")
-        return nxt, key
-
-    def _decode_paged_body(self, toks, pos, active, temps, poison, key, tables,
-                           adapters):
-        """_decode_body over the paged arena: identical math, but each slot's
-        K/V rows are gathered through its page-table row (`tables`
-        [slots, max_pages_per_seq] int32 — DATA, so remaps never retrace).
-        `adapters` [slots] int32 (data too) names each slot's LoRA arena row;
-        with an arena attached every projection adds the gathered low-rank
-        delta, and row 0 (all-zero factors) keeps base-model slots
-        bit-identical.  Bit-identical tokens to the dense decode given
-        identical cache rows: the gather reproduces the dense
-        [slots, max_len] geometry exactly and rows beyond `pos` are masked
-        to zero weight either way."""
+        Each slot's K/V rows are reached through its page-table row (`tables`
+        [slots, max_pages_per_seq] int32 — DATA, so remaps never retrace);
+        rows beyond `pos` are masked to zero weight.  `adapters` [slots]
+        int32 (data too) names each slot's LoRA arena row; with an arena
+        attached every projection adds the gathered low-rank delta, and row
+        0 (all-zero factors) keeps base-model slots bit-identical.
+        Returns (next tokens [S,1], advanced pos [S], finite [S], key[,
+        stats]): the loop state is device-resident and threads straight back
+        in — between membership changes a decode step costs one executable
+        dispatch plus the [S] token fetch, zero host->device transfers.
+        `finite` is the per-slot non-finite-logit-window watch: a
+        poisoned/diverged slot errors alone, its co-batched rows are
+        independent."""
         import jax
         import jax.numpy as jnp
 
@@ -955,12 +847,12 @@ class ContinuousBatchingEngine:
 
     def _prefill_paged_body(self, toks, row_table, true_len, temp, key,
                             adapters):
-        """_prefill_body for a fresh paged prefill: the prompt attends to
-        itself causally (the exact dense-SlotView math — bit-identical first
-        tokens) while its K/V scatter into the pages of `row_table`
-        ([max_pages_per_seq] int32, data).  `adapters` ([1] int32, data) is
-        the request's LoRA arena row (0 = base).  Padding rows land on
-        scratch."""
+        """Bucketed fresh prefill: toks [1, bucket] (right-padded), true_len
+        a scalar (data).  The prompt attends to itself causally while its
+        K/V scatter into the pages of `row_table` ([max_pages_per_seq]
+        int32, data); returns the first generated token from the logits at
+        true_len - 1.  `adapters` ([1] int32, data) is the request's LoRA
+        arena row (0 = base).  Padding rows land on scratch."""
         import jax
         import jax.numpy as jnp
         from jax import lax
@@ -1120,7 +1012,7 @@ class ContinuousBatchingEngine:
         or 0 = base model) — AdapterUnknown propagates for unregistered
         names, so clients see the typed 404 before the request ever
         queues.  Disaggregated serving (ISSUE 19): `export_kv=True` makes
-        a paged engine read the request's committed prompt pages into a
+        the engine read the request's committed prompt pages into a
         handoff payload (`req.kv_export`) when it finishes; `handoff`
         carries such a payload INTO a decode-role engine — the prompt's KV
         is imported through the compiled page scatter instead of
@@ -1142,10 +1034,8 @@ class ContinuousBatchingEngine:
             # body says exactly how much context this tier holds
             raise ContextOverflow(
                 ids.size, self.max_len, cp=self.cp,
-                pages_per_shard=(
-                    (self.pages_per_seq // self.cp) if self.paged else 0
-                ),
-                page_size=self.page_size if self.paged else 0,
+                pages_per_shard=self.pages_per_seq // self.cp,
+                page_size=self.page_size,
             )
         if max_new_tokens < 1:
             raise ValueError("max_new_tokens must be >= 1")
@@ -1168,21 +1058,15 @@ class ContinuousBatchingEngine:
                     f"adapter {adapter_obj.name!r} rank {adapter_obj.rank} "
                     f"exceeds the arena rank_max {self._lora.rank_max}"
                 )
-        if export_kv and not self.paged:
-            raise ValueError(
-                "export_kv requires the paged engine (the handoff payload "
-                "is the committed page rows)"
-            )
         handoff_state = None
         if handoff is not None:
             # typed validation BEFORE the request queues: wrong role,
             # foreign arena geometry, or corrupt rows must surface as a
             # client error, never inside a compiled step
-            if not (self.paged and self.role == "decode"):
+            if self.role != "decode":
                 raise ValueError(
-                    "handoff import requires a paged engine in the 'decode' "
-                    f"role (this engine: paged={self.paged}, "
-                    f"role={self.role!r})"
+                    "handoff import requires an engine in the 'decode' "
+                    f"role (this engine: role={self.role!r})"
                 )
             if adapter_obj is not None:
                 raise ValueError(
@@ -1227,23 +1111,22 @@ class ContinuousBatchingEngine:
                     f"queue-drain estimate {est:.2f}s",
                     retry_after_s=est,
                 )
-        if self.paged:
-            # page-aware admission: a request whose WORST-CASE page need
-            # (no prefix sharing assumed) exceeds the pool can never be
-            # scheduled — fail fast with the same 503 family the queue
-            # bound uses instead of parking it forever
-            need = self._pages_for(ids.size, max_new_tokens)
-            # under cp the binding bound is PER SHARD: sequence page k only
-            # ever comes from shard k % cp, so the worst shard must hold
-            # ceil(need / cp) pages out of its per_shard - 1 usable
-            if -(-need // self.cp) > self._pool.per_shard - 1:
-                raise QueueFull(
-                    f"request needs {need} KV pages (prompt {ids.size} + "
-                    f"max_new {max_new_tokens} at page size {self.page_size})"
-                    f" but the pool holds {self._pool.usable_pages}"
-                    + (f" across cp={self.cp} shards" if self.cp > 1 else ""),
-                    retry_after_s=self._shed_retry_after(deadline_s),
-                )
+        # page-aware admission: a request whose WORST-CASE page need
+        # (no prefix sharing assumed) exceeds the pool can never be
+        # scheduled — fail fast with the same 503 family the queue
+        # bound uses instead of parking it forever
+        need = self._pages_for(ids.size, max_new_tokens)
+        # under cp the binding bound is PER SHARD: sequence page k only
+        # ever comes from shard k % cp, so the worst shard must hold
+        # ceil(need / cp) pages out of its per_shard - 1 usable
+        if -(-need // self.cp) > self._pool.per_shard - 1:
+            raise QueueFull(
+                f"request needs {need} KV pages (prompt {ids.size} + "
+                f"max_new {max_new_tokens} at page size {self.page_size})"
+                f" but the pool holds {self._pool.usable_pages}"
+                + (f" across cp={self.cp} shards" if self.cp > 1 else ""),
+                retry_after_s=self._shed_retry_after(deadline_s),
+            )
         req = EngineRequest(
             next(self._req_ids), ids, max_new_tokens, temperature,
             eos_token_id, on_token, deadline_s=deadline_s, trace=trace,
@@ -1255,8 +1138,8 @@ class ContinuousBatchingEngine:
         if session_id is not None:
             if self._sessions is None:
                 raise ValueError(
-                    "session_id requires a paged engine with a prefix cache "
-                    "(construct with paged=True, prefix_cache=True)"
+                    "session_id requires an engine with a prefix cache "
+                    "(construct with prefix_cache=True)"
                 )
             if handoff_state is not None:
                 raise ValueError(
@@ -1298,120 +1181,96 @@ class ContinuousBatchingEngine:
     def warmup(self):
         """Trace/compile (or AOT-load via FLAGS_compile_cache_dir) every
         prefill bucket and the decode step before traffic arrives.  Dummy
-        data through the real executables; the rows it scribbles into slot 0
-        are rewritten by that slot's next real prefill.  Call before start().
-        """
+        data through the real executables, every write aimed at the scratch
+        page.  Call before start()."""
         from .. import to_tensor
 
-        if self.paged:
-            # all-zero tables aim every warmup write at scratch page 0;
-            # all-zero adapter ids ride the base (zero-delta) arena row
-            zero_row = to_tensor(np.zeros(self.pages_per_seq, np.int32))
-            zero_ad1 = to_tensor(np.zeros(1, np.int32))
-            zero_ads = to_tensor(np.zeros(self.slots, np.int32))
-            for b in self.prefill_buckets:
-                # analysis: allow GRAFT010 — warmup runs before the scheduler thread exists; steady-state _key writes hold _mu
-                _, self._key = self._prefill_fn(
-                    to_tensor(np.zeros((1, b), np.int32)), zero_row,
-                    to_tensor(np.int32(b)), to_tensor(np.float32(0.0)),
-                    self._key, zero_ad1,
-                )
-                _, self._key = self._chunk_fn(
-                    to_tensor(np.zeros((1, b), np.int32)), zero_row,
-                    to_tensor(np.int32(b)),
-                    to_tensor(np.zeros(1, np.int32)),
-                    to_tensor(np.float32(0.0)), self._key, zero_ad1,
-                )
-            self._copy_fn(  # scratch onto itself: a no-op through the real fn
-                to_tensor(np.int32(0)), to_tensor(np.int32(0))
-            )
-            if self._import_fn is not None:
-                # decode role: warm the handoff import scatter with zero
-                # tiles aimed at scratch page 0 (already zeros — a no-op
-                # through the real executable, like the copy warm above)
-                nl = len(self._arenas)
-                elem = (
-                    np.dtype(np.int8) if self.kv_quant == "int8"
-                    else self._kv_dtype_np
-                )
-                tile = (nl, self._kv_heads, self.page_size, self._head_dim)
-                args = [
-                    to_tensor(np.zeros(tile, elem)),
-                    to_tensor(np.zeros(tile, elem)),
-                ]
-                if self.kv_quant == "int8":
-                    srow = (nl, self._kv_heads, 1, self.page_size)
-                    args += [
-                        to_tensor(np.ones(srow, np.float32)),
-                        to_tensor(np.ones(srow, np.float32)),
-                    ]
-                self._import_fn(*args, to_tensor(np.int32(0)))
-            _, _, _, self._key, *_ = self._decode_fn(
-                to_tensor(np.zeros((self.slots, 1), np.int32)),
-                to_tensor(np.zeros(self.slots, np.int32)),
-                to_tensor(np.zeros(self.slots, bool)),
-                to_tensor(np.zeros(self.slots, np.float32)),
-                self._poison_zero,
-                self._key,
-                to_tensor(np.zeros((self.slots, self.pages_per_seq), np.int32)),
-                zero_ads,
-            )
-            if self._spec_on:
-                # the one extra executable speculation buys: all-inactive
-                # rows aim every window write at scratch page 0
-                _, _, _, _, self._key = self._verify_fn(
-                    to_tensor(np.zeros((self.slots, self.spec_k + 1), np.int32)),
-                    to_tensor(np.zeros(self.slots, np.int32)),
-                    to_tensor(np.zeros(self.slots, bool)),
-                    to_tensor(np.ones(self.slots, np.int32)),
-                    to_tensor(np.zeros(self.slots, np.float32)),
-                    self._poison_zero,
-                    self._key,
-                    to_tensor(
-                        np.zeros((self.slots, self.pages_per_seq), np.int32)
-                    ),
-                    zero_ads,
-                )
-            with self._mu:
-                self._warm_buckets = set(self.prefill_buckets)
-            self._warmed = True
-            return self
+        # all-zero tables aim every warmup write at scratch page 0;
+        # all-zero adapter ids ride the base (zero-delta) arena row
+        zero_row = to_tensor(np.zeros(self.pages_per_seq, np.int32))
+        zero_ad1 = to_tensor(np.zeros(1, np.int32))
+        zero_ads = to_tensor(np.zeros(self.slots, np.int32))
         for b in self.prefill_buckets:
+            # analysis: allow GRAFT010 — warmup runs before the scheduler thread exists; steady-state _key writes hold _mu
             _, self._key = self._prefill_fn(
-                to_tensor(np.zeros((1, b), np.int32)),
-                to_tensor(np.int32(0)), to_tensor(np.int32(b)),
-                to_tensor(np.float32(0.0)), self._key,
+                to_tensor(np.zeros((1, b), np.int32)), zero_row,
+                to_tensor(np.int32(b)), to_tensor(np.float32(0.0)),
+                self._key, zero_ad1,
             )
-        _, _, _, self._key = self._decode_fn(
+            _, self._key = self._chunk_fn(
+                to_tensor(np.zeros((1, b), np.int32)), zero_row,
+                to_tensor(np.int32(b)),
+                to_tensor(np.zeros(1, np.int32)),
+                to_tensor(np.float32(0.0)), self._key, zero_ad1,
+            )
+        self._copy_fn(  # scratch onto itself: a no-op through the real fn
+            to_tensor(np.int32(0)), to_tensor(np.int32(0))
+        )
+        if self._import_fn is not None:
+            # decode role: warm the handoff import scatter with zero
+            # tiles aimed at scratch page 0 (already zeros — a no-op
+            # through the real executable, like the copy warm above)
+            nl = len(self._arenas)
+            elem = (
+                np.dtype(np.int8) if self.kv_quant == "int8"
+                else self._kv_dtype_np
+            )
+            tile = (nl, self._kv_heads, self.page_size, self._head_dim)
+            args = [
+                to_tensor(np.zeros(tile, elem)),
+                to_tensor(np.zeros(tile, elem)),
+            ]
+            if self.kv_quant == "int8":
+                srow = (nl, self._kv_heads, 1, self.page_size)
+                args += [
+                    to_tensor(np.ones(srow, np.float32)),
+                    to_tensor(np.ones(srow, np.float32)),
+                ]
+            self._import_fn(*args, to_tensor(np.int32(0)))
+        _, _, _, self._key, *_ = self._decode_fn(
             to_tensor(np.zeros((self.slots, 1), np.int32)),
             to_tensor(np.zeros(self.slots, np.int32)),
             to_tensor(np.zeros(self.slots, bool)),
             to_tensor(np.zeros(self.slots, np.float32)),
             self._poison_zero,
             self._key,
+            to_tensor(np.zeros((self.slots, self.pages_per_seq), np.int32)),
+            zero_ads,
         )
-        with self._mu:
-            self._warm_buckets = set(self.prefill_buckets)
+        if self._spec_on:
+            # the one extra executable speculation buys: all-inactive
+            # rows aim every window write at scratch page 0
+            _, _, _, _, self._key = self._verify_fn(
+                to_tensor(np.zeros((self.slots, self.spec_k + 1), np.int32)),
+                to_tensor(np.zeros(self.slots, np.int32)),
+                to_tensor(np.zeros(self.slots, bool)),
+                to_tensor(np.ones(self.slots, np.int32)),
+                to_tensor(np.zeros(self.slots, np.float32)),
+                self._poison_zero,
+                self._key,
+                to_tensor(
+                    np.zeros((self.slots, self.pages_per_seq), np.int32)
+                ),
+                zero_ads,
+            )
         self._warmed = True
         return self
 
     def compile_counts(self):
-        """{prefill, decode} trace counts + AOT snapshot hits — the test
-        contract is prefill == len(buckets used) and decode == 1, forever
-        (engine restarts included: restart rebinds the same executables).
-        Paged engines add chunk_prefill (== buckets warmed) and copy (== 1):
-        prefix-cache hits and COW copies ride those executables with zero
-        fresh traces.  Speculation adds verify (== 1): acceptance churn is
-        data, the [slots, k+1] shape never changes."""
+        """Trace counts per executable + AOT snapshot hits — the test
+        contract is prefill == chunk_prefill == len(buckets warmed), decode
+        == 1 and copy == 1, forever (engine restarts included: restart
+        rebinds the same executables; prefix-cache hits and COW copies ride
+        them with zero fresh traces).  Speculation adds verify (== 1):
+        acceptance churn is data, the [slots, k+1] shape never changes."""
+        fns = (self._prefill_fn, self._decode_fn, self._chunk_fn, self._copy_fn)
         out = {
             "prefill": self._prefill_fn.trace_count,
             "decode": self._decode_fn.trace_count,
-            "aot_hits": self._prefill_fn.aot_hits + self._decode_fn.aot_hits,
+            "aot_hits": sum(f.aot_hits for f in fns),
+            "chunk_prefill": self._chunk_fn.trace_count,
+            "copy": self._copy_fn.trace_count,
         }
-        if self.paged:
-            out["chunk_prefill"] = self._chunk_fn.trace_count
-            out["copy"] = self._copy_fn.trace_count
-            out["aot_hits"] += self._chunk_fn.aot_hits + self._copy_fn.aot_hits
         if self._import_fn is not None:
             # decode role only (ISSUE 19): the handoff import scatter is one
             # executable forever — payload churn is data
@@ -1477,8 +1336,8 @@ class ContinuousBatchingEngine:
         draining, or dead (restart budget exhausted) — plus occupancy,
         queue depth, restart count, and the queue-drain estimate.  Also
         carries the load signals a fleet router needs to pick a replica:
-        page-pool free fraction (dense engines report free slot fraction),
-        prefix-cache size, and the EWMA decode-round wall time."""
+        page-pool free fraction, prefix-cache size, and the EWMA
+        decode-round wall time."""
         t = self._thread
         if self._dead:
             status = "dead"
@@ -1488,16 +1347,13 @@ class ContinuousBatchingEngine:
             status = "ready"
         else:
             status = "live"
-        if self.paged:
-            usable = max(1, self._pool.usable_pages)
-            # live reservations are spoken-for headroom: the router's
-            # decode-side scoring must see pages a pending handoff will
-            # consume as already gone, or it over-admits into the gap
-            page_free = max(
-                0, self._pool.free_count() - self._reserved_pages
-            ) / usable
-        else:
-            page_free = (self.slots - self.active_slots) / self.slots
+        usable = max(1, self._pool.usable_pages)
+        # live reservations are spoken-for headroom: the router's
+        # decode-side scoring must see pages a pending handoff will
+        # consume as already gone, or it over-admits into the gap
+        page_free = max(
+            0, self._pool.free_count() - self._reserved_pages
+        ) / usable
         ew = self._step_ewma_s
         out = {
             "status": status,
@@ -1568,10 +1424,6 @@ class ContinuousBatchingEngine:
         it pins no specific pages and takes no pool refs — and it expires
         after `ttl_s` (FLAGS_serve_reserve_ttl_s default): a router that
         dies mid-handoff just lets the TTL return the headroom."""
-        if not self.paged:
-            raise EngineUnavailable(
-                "page reservations require the paged engine"
-            )
         if self._dead:
             raise EngineUnavailable(
                 "engine is dead (restart budget exhausted); restart the server"
@@ -1768,26 +1620,25 @@ class ContinuousBatchingEngine:
                 if req is None or req.finished.is_set():
                     continue
                 (requeue if not req.tokens else fail).append(req)
-            if self.paged:
-                # warm restart keeps the POOL and the PREFIX CACHE: only the
-                # per-slot mappings drop (an admission interrupted mid-
-                # dispatch also parked pages here — release those too, its
-                # stale thread bails at the generation fence).  Re-queued
-                # requests re-prefill and re-hit the cache.
-                for s in range(self.slots):
-                    self._release_slot_pages_locked(s)
-                self._tables_t = None
-                if self._lora is not None:
-                    # warm restart keeps the ARENA too: binding refs drop
-                    # (re-queued requests re-acquire at re-admission) but
-                    # residency holds survive — resident adapters stay
-                    # uploaded, zero re-loads after the restart
-                    for req in requeue:
-                        self._release_adapter_locked(req)
-                    for req in fail:
-                        self._release_adapter_locked(req)
-                    self._slot_adapter[:] = 0
-                    self._adapters_t = None
+            # warm restart keeps the POOL and the PREFIX CACHE: only the
+            # per-slot mappings drop (an admission interrupted mid-
+            # dispatch also parked pages here — release those too, its
+            # stale thread bails at the generation fence).  Re-queued
+            # requests re-prefill and re-hit the cache.
+            for s in range(self.slots):
+                self._release_slot_pages_locked(s)
+            self._tables_t = None
+            if self._lora is not None:
+                # warm restart keeps the ARENA too: binding refs drop
+                # (re-queued requests re-acquire at re-admission) but
+                # residency holds survive — resident adapters stay
+                # uploaded, zero re-loads after the restart
+                for req in requeue:
+                    self._release_adapter_locked(req)
+                for req in fail:
+                    self._release_adapter_locked(req)
+                self._slot_adapter[:] = 0
+                self._adapters_t = None
             self._pos[:] = 0
             self._last_tok[:] = 0
             self._temps[:] = 0.0
@@ -1850,15 +1701,14 @@ class ContinuousBatchingEngine:
                 except queue.Empty:
                     break
             self._queued_new_tokens = 0
-            if self.paged:
-                for s in range(self.slots):
-                    self._release_slot_pages_locked(s)
-                self._tables_t = None
-                if self._lora is not None:
-                    for req in pending:
-                        self._release_adapter_locked(req)
-                    self._slot_adapter[:] = 0
-                    self._adapters_t = None
+            for s in range(self.slots):
+                self._release_slot_pages_locked(s)
+            self._tables_t = None
+            if self._lora is not None:
+                for req in pending:
+                    self._release_adapter_locked(req)
+                self._slot_adapter[:] = 0
+                self._adapters_t = None
             self._pos[:] = 0
             self._last_tok[:] = 0
             self._temps[:] = 0.0
@@ -1916,31 +1766,10 @@ class ContinuousBatchingEngine:
 
     # -- internals ----------------------------------------------------------
 
-    @contextlib.contextmanager
-    def _bucket_growth(self, bucket):
-        """Sanctioned fresh trace: an over-bucket prompt grew a new prefill
-        bucket after warmup (one extra compile by design, then cached like
-        any other).  Declares the dispatch allowed to the sanitizer and
-        marks the bucket warmed once it lands."""
-        if not self._warmed or bucket in self._warm_buckets:
-            yield
-            return
-        with _san.allow(f"prefill bucket growth to {bucket}"):
-            yield
-        with self._mu:
-            self._warm_buckets.add(bucket)
-
     def _bucket_for(self, n):
-        for b in self.prefill_buckets:
-            if n <= b:
-                return b
-        # over-bucket prompt: grow a next-power-of-two bucket (one extra
-        # compile, then cached/snapshotted like any other)
-        b = min(1 << (n - 1).bit_length(), self.max_len - 1)
-        with self._mu:
-            self.prefill_buckets.append(b)
-            self.prefill_buckets.sort()
-        return b
+        """The smallest prefill bucket that holds n rows (a chunk is never
+        longer than the largest bucket)."""
+        return next(b for b in self.prefill_buckets if n <= b)
 
     # -- paged-KV allocator ---------------------------------------------------
 
@@ -2068,8 +1897,7 @@ class ContinuousBatchingEngine:
         with self._mu:
             self._check_gen(gen)
             now = time.perf_counter()
-            if self.paged:
-                self._purge_reservations_locked(now)
+            self._purge_reservations_locked(now)
             victims = []
             for s, req in enumerate(self._slot_req):
                 if req is None:
@@ -2130,78 +1958,77 @@ class ContinuousBatchingEngine:
                 req = self._pop_request()
                 if req is None:
                     break
-                if self.paged:
-                    # a handoff admission consumes its reservation FIRST:
-                    # inside this same critical section the returned
-                    # headroom flows straight into the check below, so the
-                    # hold converts into the pages it promised (ISSUE 19)
-                    if req.reservation is not None:
-                        self._consume_reservation_locked(req.reservation)
-                        req.reservation = None
-                    # prefix-aware admission: pages a cache hit will map by
-                    # incref cost no fresh allocation, so only the unshared
-                    # remainder counts against headroom — this is what lets
-                    # shared-prefix traffic pack >|dense slots| concurrent
-                    # sequences into the same page budget.  Safe to check
-                    # here and act in _prefill_into_paged: this scheduler
-                    # thread is the only inserter/evictor, so the match
-                    # cannot shrink in between.  Matched pages are excluded
-                    # from the evictable count — they are about to be pinned.
-                    # Handoff imports always land ALL pages fresh (they
-                    # commit to the cache after, so future prompts share).
-                    coverage = self._pages_for(
-                        req.prompt.size, req.max_new_tokens
+                # a handoff admission consumes its reservation FIRST:
+                # inside this same critical section the returned
+                # headroom flows straight into the check below, so the
+                # hold converts into the pages it promised (ISSUE 19)
+                if req.reservation is not None:
+                    self._consume_reservation_locked(req.reservation)
+                    req.reservation = None
+                # prefix-aware admission: pages a cache hit will map by
+                # incref cost no fresh allocation, so only the unshared
+                # remainder counts against headroom — this is what lets
+                # shared-prefix traffic pack more than `slots * max_len` rows of
+                # sequences into the same page budget.  Safe to check
+                # here and act in _prefill_into_paged: this scheduler
+                # thread is the only inserter/evictor, so the match
+                # cannot shrink in between.  Matched pages are excluded
+                # from the evictable count — they are about to be pinned.
+                # Handoff imports always land ALL pages fresh (they
+                # commit to the cache after, so future prompts share).
+                coverage = self._pages_for(
+                    req.prompt.size, req.max_new_tokens
+                )
+                need = coverage
+                exclude = ()
+                if self._prefix is not None and req.handoff is None:
+                    m, fulls, tail, _rows = self._prefix.lookup(
+                        req.prompt, adapter=self._req_adapter_id(req)
                     )
-                    need = coverage
-                    exclude = ()
-                    if self._prefix is not None and req.handoff is None:
-                        m, fulls, tail, _rows = self._prefix.lookup(
-                            req.prompt, adapter=self._req_adapter_id(req)
-                        )
-                        if m >= self.min_prefix_match:
-                            need -= len(fulls)
-                            exclude = set(fulls)
-                            if tail is not None:
-                                exclude.add(tail)
-                    if self.cp > 1:
-                        # per-shard admission (ISSUE 20): fresh pages land at
-                        # sequence indices [coverage - need, coverage), shard
-                        # k % cp each — every shard must cover its slice
-                        head = self._page_fresh_headroom_by_shard_locked(
-                            exclude
-                        )
-                        by_shard = self._fresh_need_by_shard(
-                            coverage - need, coverage
-                        )
-                        short = any(
-                            n > h for n, h in zip(by_shard, head)
-                        )
-                    else:
-                        short = need > self._page_fresh_headroom_locked(
-                            exclude
-                        )
-                    if short:
-                        # page pressure: park the request at the head of the
-                        # line (FIFO preserved) until draining slots release
-                        # enough pages — submit guaranteed need <= pool, so
-                        # progress is certain
+                    if m >= self.min_prefix_match:
+                        need -= len(fulls)
+                        exclude = set(fulls)
+                        if tail is not None:
+                            exclude.add(tail)
+                if self.cp > 1:
+                    # per-shard admission (ISSUE 20): fresh pages land at
+                    # sequence indices [coverage - need, coverage), shard
+                    # k % cp each — every shard must cover its slice
+                    head = self._page_fresh_headroom_by_shard_locked(
+                        exclude
+                    )
+                    by_shard = self._fresh_need_by_shard(
+                        coverage - need, coverage
+                    )
+                    short = any(
+                        n > h for n, h in zip(by_shard, head)
+                    )
+                else:
+                    short = need > self._page_fresh_headroom_locked(
+                        exclude
+                    )
+                if short:
+                    # page pressure: park the request at the head of the
+                    # line (FIFO preserved) until draining slots release
+                    # enough pages — submit guaranteed need <= pool, so
+                    # progress is certain
+                    self._requeue.insert(0, req)
+                    self._queued_new_tokens += req.max_new_tokens
+                    break
+                if req.adapter is not None:
+                    # arena admission AFTER the page check, so a parked
+                    # request never sits in the queue holding a binding
+                    from ..lora.arena import AdapterArenaFull
+
+                    try:
+                        req.adapter_slot = self._lora.acquire(req.adapter)
+                    except AdapterArenaFull:
+                        # every arena slot is pinned by in-flight work:
+                        # park exactly like page pressure — a finishing
+                        # request's release unblocks us
                         self._requeue.insert(0, req)
                         self._queued_new_tokens += req.max_new_tokens
                         break
-                    if req.adapter is not None:
-                        # arena admission AFTER the page check, so a parked
-                        # request never sits in the queue holding a binding
-                        from ..lora.arena import AdapterArenaFull
-
-                        try:
-                            req.adapter_slot = self._lora.acquire(req.adapter)
-                        except AdapterArenaFull:
-                            # every arena slot is pinned by in-flight work:
-                            # park exactly like page pressure — a finishing
-                            # request's release unblocks us
-                            self._requeue.insert(0, req)
-                            self._queued_new_tokens += req.max_new_tokens
-                            break
                 self._admitting = req
                 req.state = "prefilling"
             try:
@@ -2215,7 +2042,7 @@ class ContinuousBatchingEngine:
                     if self._slot_req[s] is req:
                         self._finish(s, req, "error")
                     else:
-                        if self.paged and gen == self._gen:
+                        if gen == self._gen:
                             # the prefill died after mapping pages but before
                             # the slot landed — unmap them (a restart raced
                             # ahead releases them itself) and drop the
@@ -2230,70 +2057,16 @@ class ContinuousBatchingEngine:
         return emitted
 
     def _prefill_into(self, s, req, gen):
-        if self.paged and req.handoff is not None:
+        if req.handoff is not None:
             return self._import_into_paged(s, req, gen)
-        if self.paged:
-            return self._prefill_into_paged(s, req, gen)
-        from .. import to_tensor
-
-        with self._mu:
-            self._check_gen(gen)
-            # the rebuild after this membership change reads _last_tok — it
-            # must reflect every step already dispatched
-            self._flush_pending_locked()
-            key = self._key
-        L = int(req.prompt.size)
-        bucket = self._bucket_for(L)
-        t_pf = time.perf_counter()
-        if req.trace:
-            _obs.record("engine.queue", req.trace[0], t0=req._submit_t,
-                        t1=t_pf, parent_id=req.trace[1], req=req.id)
-        # cache rows run out at max_len: the last writable decode row is
-        # max_len - 1, giving max_len - L generatable tokens
-        req.max_new_tokens = min(req.max_new_tokens, self.max_len - L)
-        toks = np.zeros((1, bucket), np.int32)
-        toks[0, :L] = req.prompt
-        # dispatch OUTSIDE the mutex: the armed region (and the injected
-        # hang standing in for a wedged device) must not block submitters
-        # or a restart
-        with self._watchdog.arm(
-            "serve.prefill", timeout=self._wd_timeout(), context=f"req {req.id}"
-        ):
-            _inj.inject_hang("serve.prefill.hang", context=f"req {req.id}")
-            # a restart during the hang owns this request now — bail before
-            # dispatching a zombie prefill into the (shared) KV pool
-            self._check_gen(gen)
-            with self._bucket_growth(bucket):
-                nxt, key = self._prefill_fn(
-                    to_tensor(toks), to_tensor(np.int32(s)), to_tensor(np.int32(L)),
-                    to_tensor(np.float32(req.temperature)), key,
-                )
-            with _san.allowed_sync("prefill first-token fetch"):
-                tok = int(np.asarray(nxt.numpy()).reshape(-1)[0])
-        with self._mu:
-            self._check_gen(gen)  # a restart while we dispatched owns req now
-            self._key = key
-            req.ttft_s = time.perf_counter() - req._submit_t
-            self._slot_req[s] = req
-            self._pos[s] = L
-            self._last_tok[s] = tok
-            self._temps[s] = req.temperature
-            req.state = "decoding"
-            self._obs_epoch_close()
-            self._dev = None  # membership changed: rebuild device loop state
-            self._emit(s, req, tok)
-        if req.trace:
-            _obs.record("engine.prefill", req.trace[0], t0=t_pf,
-                        t1=time.perf_counter(), parent_id=req.trace[1],
-                        req=req.id, bucket=bucket, slot=s)
+        return self._prefill_into_paged(s, req, gen)
 
     def _prefill_into_paged(self, s, req, gen):
         """Paged admission: prefix-cache lookup, page mapping (shared fulls
         read-only, COW for a matched partial page, fresh pages for the
         rest), then either a fresh bucketed prefill or a chunk prefill of
-        just the unshared suffix — dispatched outside the mutex like the
-        dense path.  Commits the prompt's pages to the prefix cache after
-        the prefill lands."""
+        just the unshared suffix — dispatched outside the mutex.  Commits
+        the prompt's pages to the prefix cache after the prefill lands."""
         from .. import profiler as _prof
         from .. import to_tensor
 
@@ -2382,8 +2155,9 @@ class ContinuousBatchingEngine:
             _obs.record("engine.queue", req.trace[0], t0=req._submit_t,
                         t1=t_pf, parent_id=req.trace[1], req=req.id)
         try:
-            # dispatch OUTSIDE the mutex (same contract as the dense path):
-            # the armed region must not block submitters or a restart
+            # dispatch OUTSIDE the mutex: the armed region (and the injected
+            # hang standing in for a wedged device) must not block submitters
+            # or a restart
             with self._watchdog.arm(
                 "serve.prefill", timeout=self._wd_timeout(),
                 context=f"req {req.id}",
@@ -2408,19 +2182,18 @@ class ContinuousBatchingEngine:
                     toks[0, :n] = req.prompt[offset:offset + n]
                     t_ch = time.perf_counter()
                     self._check_gen(gen)  # between chunks too: a restart owns the pages
-                    with self._bucket_growth(b):
-                        if offset == 0:
-                            nxt, key = self._prefill_fn(
-                                to_tensor(toks), table_t,
-                                to_tensor(np.int32(n)), temp_t, key, ad_t,
-                            )
-                        else:
-                            nxt, key = self._chunk_fn(
-                                to_tensor(toks), table_t,
-                                to_tensor(np.int32(n)),
-                                to_tensor(np.full(1, offset, np.int32)),
-                                temp_t, key, ad_t,
-                            )
+                    if offset == 0:
+                        nxt, key = self._prefill_fn(
+                            to_tensor(toks), table_t,
+                            to_tensor(np.int32(n)), temp_t, key, ad_t,
+                        )
+                    else:
+                        nxt, key = self._chunk_fn(
+                            to_tensor(toks), table_t,
+                            to_tensor(np.int32(n)),
+                            to_tensor(np.full(1, offset, np.int32)),
+                            temp_t, key, ad_t,
+                        )
                     if req.trace and len(chunks) > 1:
                         _obs.record(
                             "engine.prefill_chunk", req.trace[0], t0=t_ch,
@@ -2606,13 +2379,12 @@ class ContinuousBatchingEngine:
                     to_tensor(self._pos.copy()), to_tensor(active),
                     to_tensor(self._temps.copy()),
                 )
-                if self.paged:
-                    # page tables (and adapter bindings) change exactly when
-                    # membership does — the same events that invalidate _dev
-                    # — so one H2D mirror per membership change covers every
-                    # following step
-                    self._tables_t = to_tensor(self._page_table.copy())
-                    self._adapters_t = to_tensor(self._slot_adapter.copy())
+                # page tables (and adapter bindings) change exactly when
+                # membership does — the same events that invalidate _dev
+                # — so one H2D mirror per membership change covers every
+                # following step
+                self._tables_t = to_tensor(self._page_table.copy())
+                self._adapters_t = to_tensor(self._slot_adapter.copy())
                 self._obs_epoch_open(active_idx)
             toks_t, pos_t, active_t, temps_t = self._dev
             key = self._key
@@ -2626,16 +2398,10 @@ class ContinuousBatchingEngine:
             "serve.decode", timeout=self._wd_timeout(),
             context=f"{len(active_idx)} active slots",
         ):
-            stats = None
-            if self.paged:
-                nxt, new_pos, finite, key, *stats = self._decode_fn(
-                    toks_t, pos_t, active_t, temps_t, poison_t, key,
-                    self._tables_t, self._adapters_t,
-                )
-            else:
-                nxt, new_pos, finite, key = self._decode_fn(
-                    toks_t, pos_t, active_t, temps_t, poison_t, key
-                )
+            nxt, new_pos, finite, key, *stats = self._decode_fn(
+                toks_t, pos_t, active_t, temps_t, poison_t, key,
+                self._tables_t, self._adapters_t,
+            )
         with self._mu:
             self._check_gen(gen)
             self._key = key
@@ -2672,21 +2438,20 @@ class ContinuousBatchingEngine:
                 len(active_idx) / self.slots, self._queue.qsize(),
                 time.perf_counter() - t0,
             )
-            if self.paged:
-                _prof.record_paging_tick(
-                    self._pool.used_count(), self._pool.usable_pages
+            _prof.record_paging_tick(
+                self._pool.used_count(), self._pool.usable_pages
+            )
+            if self.kv_quant == "int8":
+                # per-layer work divided out: one KV row-pair quantized
+                # per active slot, every mapped page dequantized in the
+                # kernel's page walk
+                _prof.record_kv_quant_event(
+                    "quantize", len(active_idx)
                 )
-                if self.kv_quant == "int8":
-                    # per-layer work divided out: one KV row-pair quantized
-                    # per active slot, every mapped page dequantized in the
-                    # kernel's page walk
-                    _prof.record_kv_quant_event(
-                        "quantize", len(active_idx)
-                    )
-                    _prof.record_kv_quant_event(
-                        "dequantize",
-                        sum(len(self._slot_pages[s]) for s in active_idx),
-                    )
+                _prof.record_kv_quant_event(
+                    "dequantize",
+                    sum(len(self._slot_pages[s]) for s in active_idx),
+                )
         return len(active_idx)
 
     def _decode_once_spec(self, gen):
@@ -2972,7 +2737,7 @@ class ContinuousBatchingEngine:
 
     def _finish(self, s, req, reason):
         if (
-            self.paged and req.export_kv and req.kv_export is None
+            req.export_kv and req.kv_export is None
             and reason in ("eos", "length")
         ):
             # disaggregated prefill (ISSUE 19): read the committed prompt
@@ -2987,7 +2752,7 @@ class ContinuousBatchingEngine:
                     "disagg: page export failed for request %d", req.id
                 )
         if (
-            self.paged and self._sessions is not None
+            self._sessions is not None
             and req.session_id is not None and reason in ("eos", "length")
         ):
             # session KV (ISSUE 20): commit + pin the FULL committed
@@ -3010,15 +2775,14 @@ class ContinuousBatchingEngine:
         self._last_tok[s] = 0
         self._temps[s] = 0.0
         self._drafters[s] = None
-        if self.paged:
-            # mappings drop; committed prefix pages live on through the
-            # cache's own hold, everything else returns to the free list
-            self._release_slot_pages_locked(s)
-            if self._lora is not None:
-                # the binding ref drops; residency survives, so the adapter
-                # stays warm until arena LRU pressure needs its slot
-                self._slot_adapter[s] = 0
-                self._release_adapter_locked(req)
+        # mappings drop; committed prefix pages live on through the
+        # cache's own hold, everything else returns to the free list
+        self._release_slot_pages_locked(s)
+        if self._lora is not None:
+            # the binding ref drops; residency survives, so the adapter
+            # stays warm until arena LRU pressure needs its slot
+            self._slot_adapter[s] = 0
+            self._release_adapter_locked(req)
         self._obs_epoch_close()
         self._dev = None  # membership changed: rebuild device loop state
         self._resolve(req, reason)
@@ -3192,8 +2956,7 @@ class ContinuousBatchingEngine:
                     "slot invariant: queued-token accounting went negative "
                     f"({self._queued_new_tokens})"
                 )
-            if self.paged:
-                self._check_page_invariants_locked()
+            self._check_page_invariants_locked()
             if self._lora is not None:
                 bindings = {}
                 for s in range(self.slots):

@@ -75,26 +75,19 @@ KV_QUANT_MODES = ("none", "int8")
 
 class QuantConfigError(ValueError):
     """Raised at engine CONSTRUCTION time for an invalid KV-quantization
-    configuration (unknown mode, quantized arena on a dense engine), so the
-    operator sees a typed, actionable error instead of a mid-traffic shape
-    or dtype mismatch inside a compiled step — the same contract as
-    distributed.sharding.ShardingError (ISSUE 14)."""
+    configuration (an unknown mode), so the operator sees a typed,
+    actionable error instead of a mid-traffic shape or dtype mismatch inside
+    a compiled step — the same contract as distributed.sharding.ShardingError
+    (ISSUE 14)."""
 
 
-def validate_kv_quant(mode, paged=True):
+def validate_kv_quant(mode):
     """Typed validation of a kv_quant mode string (QuantConfigError on
-    violation); returns the normalized mode.  Quantization requires the
-    paged engine — the dense slot pool has no scale-arena plumbing and is
-    kept as the full-precision bit-identity oracle."""
+    violation); returns the normalized mode."""
     mode = "none" if mode is None else str(mode).strip().lower()
     if mode not in KV_QUANT_MODES:
         raise QuantConfigError(
             f"kv_quant must be one of {'|'.join(KV_QUANT_MODES)}, got {mode!r}"
-        )
-    if mode != "none" and not paged:
-        raise QuantConfigError(
-            f"kv_quant={mode!r} requires the paged engine (paged=True): the "
-            "dense slot pool stays full-precision as the bit-identity oracle"
         )
     return mode
 
@@ -149,17 +142,15 @@ def check_scale_arenas(arenas, num_pages, page_size):
 
 
 # Canonical tensor-parallel layout of every KV cache buffer (ISSUE 14):
-# paged arenas are [num_pages, kv_heads, page_size, head_dim] and dense slot
-# pools are [slots, max_len, kv_heads, head_dim] — both split their KV HEADS
-# axis over the 'mp' mesh axis, so each device stores and streams only its
-# local heads' rows.  Page identity, table entries, and every piece of
+# paged arenas are [num_pages, kv_heads, page_size, head_dim] and split their
+# KV HEADS axis over the 'mp' mesh axis, so each device stores and streams
+# only its local heads' rows.  Page identity, table entries, and every piece of
 # host-side bookkeeping in this module stay device-count-agnostic: a page
 # is the SAME page on every shard, just narrower.
 def shard_kv_for_tp(cache):
-    """Place a KV cache's k/v buffers on the installed serving mesh: kv
-    heads (dim 1 of a paged arena, dim 2 of a dense slot pool) split over
-    'mp' and — for paged arenas under context parallelism (ISSUE 20) — the
-    PAGE axis (dim 0) block-split over 'cp', so shard s physically holds
+    """Place a paged arena's k/v buffers on the installed serving mesh: kv
+    heads (dim 1) split over 'mp' and — under context parallelism
+    (ISSUE 20) — the PAGE axis (dim 0) block-split over 'cp', so shard s physically holds
     pages [s*per_shard, (s+1)*per_shard) and the cp decode kernel streams
     only local pages.  No-op without a mesh, so the engine calls it
     unconditionally; returns the cache for chaining."""
@@ -171,10 +162,7 @@ def shard_kv_for_tp(cache):
     if _mesh.get_mesh() is None or (_mesh.axis_size("mp") <= 1 and cp <= 1):
         return cache
     mp_axis = "mp" if _mesh.axis_size("mp") > 1 else None
-    if hasattr(cache, "page_size"):  # PagedKVCache
-        spec = P("cp" if cp > 1 else None, mp_axis, None, None)
-    else:  # dense slot pool: dim 0 is SLOTS — never cp-sharded
-        spec = P(None, None, mp_axis, None)
+    spec = P("cp" if cp > 1 else None, mp_axis, None, None)
     _mesh.shard_tensor_(cache.k, spec)
     _mesh.shard_tensor_(cache.v, spec)
     # int8 arenas (ISSUE 18): scale buffers are [pages, kv_heads, 1,

@@ -20,8 +20,7 @@ and serving cold starts are user-visible latency):
    skipped entirely; stale fingerprints auto-invalidate instead of loading.
 
 `cache_info()` is the single observability surface over both tiers plus
-the eager dispatch executable cache (printed by profiler.summary and
-bench.py so the cold-start win is tracked in the perf trajectory).
+the eager dispatch executable cache (printed by profiler.summary).
 """
 
 from __future__ import annotations
@@ -365,7 +364,7 @@ def cache_info():
 
 
 def cache_report():
-    """Human-readable cache_info (profiler.summary, bench logs)."""
+    """Human-readable cache_info (profiler.summary)."""
     info = cache_info()
     p, a, t, e = info["persistent"], info["aot"], info["trace"], info["eager"]
     lines = [

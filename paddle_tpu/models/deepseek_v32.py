@@ -756,10 +756,10 @@ class DeepseekV32ForCausalLM(nn.Layer):
     (called with `caches=` / `pos=`), `lm_head`, `cache_rows()`, and for a
     model that cannot do all the engine offers, `engine_unsupported`."""
 
-    # the latent rows have no int8 form, no tensor/context-parallel layout, no
-    # handoff format and no dense-slot twin; the decode path takes one token a
-    # slot (no verify window) and the projections take no LoRA delta
-    engine_unsupported = frozenset({"tp", "cp", "kv_quant", "lora", "spec_k", "role", "dense"})
+    # the latent rows have no int8 form, no tensor/context-parallel layout and
+    # no handoff format; the decode path takes one token a slot (no verify
+    # window) and the projections take no LoRA delta
+    engine_unsupported = frozenset({"tp", "cp", "kv_quant", "lora", "spec_k", "role"})
 
     def __init__(self, config):
         super().__init__()

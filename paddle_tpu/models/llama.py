@@ -93,7 +93,7 @@ def apply_rotary_pos_emb(q, k, cos, sin, position_offset=0):
     position_offset may be a python int, a scalar int Tensor (the compiled
     decode step passes the position as data so one executable serves every
     token), or a [b] int Tensor of PER-ROW offsets (the continuous-batching
-    engine's slot pool: every slot sits at its own position, still one
+    engine's slots: every slot sits at its own position, still one
     executable)."""
     import jax.numpy as jnp
     from jax import lax
@@ -154,53 +154,16 @@ class StaticKVCache:
 
 
 def _cache_write(cache_t, new_t, pos_t):
-    """dynamic_update_slice of this chunk's K or V at the absolute position.
-    pos may be a scalar (lock-step decode: whole batch at one position) or a
-    [b] vector (slot-pooled decode: each slot writes at its own position)."""
-    import jax
-
+    """dynamic_update_slice of this chunk's K or V at the absolute position
+    `pos` (a scalar: lock-step decode has the whole batch at one position)."""
     from jax import lax
 
     from ..ops.dispatch import apply
 
-    per_row = len(pos_t.shape) == 1 if isinstance(pos_t, Tensor) else False
-
     def f(c, n, p):
-        if per_row:
-            return jax.vmap(
-                lambda cb, nb, pb: lax.dynamic_update_slice_in_dim(
-                    cb, nb.astype(cb.dtype), pb, 0
-                )
-            )(c, n, p)
         return lax.dynamic_update_slice_in_dim(c, n.astype(c.dtype), p, 1)
 
     return apply(f, [cache_t, new_t, pos_t], name="kv_cache_write")
-
-
-class SlotView:
-    """Write-only view of ONE slot of a pooled StaticKVCache, used by the
-    continuous-batching engine's compiled prefill: the prompt's K/V land in
-    rows [0, bucket) of pool row `slot` (a scalar int Tensor — data, not a
-    shape), while attention runs over the fresh prompt only.  Rows beyond the
-    true prompt length hold padding garbage; they are safe because decode
-    overwrites row `pos` before ever attending to it and masks j > pos."""
-
-    def __init__(self, pool, slot):
-        self.pool = pool
-        self.slot = slot
-
-
-def _slot_write(pool_t, new_t, slot_t):
-    """Write a [1, s, kv_heads, d] chunk into rows [0, s) of pool slot
-    `slot_t` ([slots, max_len, kv_heads, d] buffer; slot index is data)."""
-    from jax import lax
-
-    from ..ops.dispatch import apply
-
-    def f(c, n, s_):
-        return lax.dynamic_update_slice(c, n.astype(c.dtype), (s_, 0, 0, 0))
-
-    return apply(f, [pool_t, new_t, slot_t], name="kv_slot_write")
 
 
 class PagedKVCache:
@@ -259,9 +222,9 @@ class PagedKVCache:
 
 class PagedPrefillView:
     """Prefill into a paged arena.  Fresh prefill (`start is None`): the
-    prompt attends to itself causally — the exact SlotView math, so paged
-    and dense engines stay bit-identical — while its K/V scatter into the
-    pages of `table` ([max_pages_per_seq] int32, data).  Chunk prefill
+    prompt attends to itself causally (rope offset 0, plain causal SDPA)
+    while its K/V scatter into the pages of `table` ([max_pages_per_seq]
+    int32, data).  Chunk prefill
     (`start` an int32[1] Tensor): a prefix-cache hit prefills only the
     unshared suffix at rope offset `start`, attending the shared pages
     through a table gather.  Rows past `true_len` (bucket padding) and rows
@@ -282,8 +245,7 @@ class PagedDecodeView:
     """Compiled decode over the paged arena: `tables` is the full
     [slots, max_pages_per_seq] int32 page table (data), each slot writes
     its token at page `tables[s, pos//page_size]` row `pos % page_size`
-    and attends the gathered pages sliced back to [slots, max_len] — the
-    same attended geometry as the dense slot pool, bit for bit.
+    and attends the gathered pages sliced back to [slots, max_len].
 
     Multi-query verify (speculative decoding): the same view serves a
     [slots, k+1] token window — row i writes page entry (pos+i)//page_size
@@ -596,11 +558,10 @@ class LlamaAttention(nn.Layer):
         if isinstance(cache, PagedPrefillView):
             quant = getattr(cache.arena, "quant", "none") == "int8"
             if cache.start is None:
-                # fresh paged prefill: identical math to the dense SlotView
-                # path (rope offset 0, causal SDPA over the prompt) — only
-                # WHERE the K/V rows land differs, so paged and dense
-                # engines produce bit-identical tokens.  RoPE + both page
-                # scatters run as ONE fused op (no activation round-trip).
+                # fresh paged prefill: rope offset 0, causal SDPA over the
+                # prompt, its K/V rows landing in the table's pages.  RoPE +
+                # both page scatters run as ONE fused op (no activation
+                # round-trip).
                 # Under an int8 arena the scatter quantizes on write, but
                 # the prompt's own attention below still runs on the full-
                 # precision k/v in register — first tokens stay exact
@@ -656,8 +617,7 @@ class LlamaAttention(nn.Layer):
             out = out.reshape([b, s, self.num_heads * self.head_dim])
             return _lora_add(lora, "o_proj", self.o_proj(out), out), cache
         if isinstance(cache, PagedDecodeView):
-            # paged compiled decode: same per-row rope and attended geometry
-            # as the dense StaticKVCache path; the page-table indirection
+            # paged compiled decode: per-row rope; the page-table indirection
             # happens inside the compiled step (tables are data) — fused
             # in-kernel on the Pallas path, gather-then-dense otherwise
             quant = getattr(cache.arena, "quant", "none") == "int8"
@@ -686,17 +646,6 @@ class LlamaAttention(nn.Layer):
                 k_scale=cache.arena.k_scale if quant else None,
                 v_scale=cache.arena.v_scale if quant else None,
             )
-            out = out.reshape([b, s, self.num_heads * self.head_dim])
-            return _lora_add(lora, "o_proj", self.o_proj(out), out), cache
-        if isinstance(cache, SlotView):
-            # compiled prefill into a pooled cache: the prompt attends to
-            # itself (plain causal attention) while its K/V are written into
-            # rows [0, s) of the assigned pool slot — slot index is data, so
-            # one executable per prompt bucket serves every slot
-            q, k = apply_rotary_pos_emb(q, k, self.rope_cos, self.rope_sin, 0)
-            cache.pool.k._data = _slot_write(cache.pool.k, k, cache.slot)._data
-            cache.pool.v._data = _slot_write(cache.pool.v, v, cache.slot)._data
-            out = F.scaled_dot_product_attention(q, k, v, is_causal=True)
             out = out.reshape([b, s, self.num_heads * self.head_dim])
             return _lora_add(lora, "o_proj", self.o_proj(out), out), cache
         if isinstance(cache, StaticKVCache):

@@ -742,8 +742,8 @@ def decode_attention_array(q, k, v, pos, scale=None):
 
     q: [b, sq, h, d] (the fresh chunk); k,v: [b, L, kv_h, d] cache buffers
     (every slot, written or not); pos: scalar int32 — absolute position of
-    q row 0 — or int32[b] PER-BATCH-ROW positions (the continuous-batching
-    slot pool: each slot decodes at its own length, still one executable).
+    q row 0 — or int32[b] PER-BATCH-ROW positions (each row decodes at its
+    own length, still one executable).
     Row i attends cache slots j <= pos + i.  Pallas on TPU (or under
     interpret); a fused dense XLA path elsewhere — both take validity from
     `pos`, never from a mask array.  Vector pos always takes the dense path
@@ -852,8 +852,8 @@ def flash_decode(query, key, value, pos, scale=None):
 def paged_gather_kv(arena, tables, max_len):
     """Gather a paged arena [num_pages, kv_h, page_size, d] back into dense
     per-sequence buffers [b, max_len, kv_h, d] through the page tables
-    ([b, P] int32).  The reshape-then-slice keeps the attended geometry
-    identical to the dense slot pool (P * page_size >= max_len; the slack
+    ([b, P] int32).  The reshape-then-slice gives each sequence a dense
+    [max_len] cache (P * page_size >= max_len; the slack
     rows come from the sequence's own trailing page and are masked by pos
     downstream anyway)."""
     b = tables.shape[0]
@@ -1644,8 +1644,7 @@ def paged_decode_attention_array(q, arena_k, arena_v, tables, pos, max_len,
     kernel="gather": force the gather-then-dense oracle (`paged_gather_kv`
     materializes each sequence's KV densely, then the exact dense-cache
     decode math runs on the result) — the bit-parity baseline the fused
-    kernel is tested against.  Both paths are bit-identical to the dense
-    slot pool given bit-identical cache rows.
+    kernel is tested against.
 
     Under a tensor-parallel 'mp' mesh the fused kernel goes through
     `shard_map` (kv_heads axis sharded; see `_fused_paged_decode_tp`) and

@@ -306,10 +306,9 @@ def paged_walk_summary():
 
 def reset():
     """Zero EVERY counter family (step, serving, paging, router, flash
-    fallbacks) in one critical section.  bench.py calls this between legs
-    so one leg's router/serving gauges can't leak into the next leg's
-    printed summary; the per-family reset_*() helpers remain for callers
-    that want to keep the others."""
+    fallbacks) in one critical section, so one run's router/serving gauges
+    can't leak into the next run's printed summary; the per-family
+    reset_*() helpers remain for callers that want to keep the others."""
     with _counters_lock:
         _reset_step_locked()
         _reset_serving_locked()

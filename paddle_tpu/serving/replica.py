@@ -476,8 +476,8 @@ def main(argv=None):
     p.add_argument(
         "--lora", default="",
         help="comma list of adapter specs name[:rank] to register and serve "
-             "(forces the paged engine; weights are seeded by list position, "
-             "so identical --lora strings mean identical adapters fleet-wide)",
+             "(weights are seeded by list position, so identical --lora "
+             "strings mean identical adapters fleet-wide)",
     )
     p.add_argument(
         "--tp", type=int, default=1,
@@ -492,12 +492,12 @@ def main(argv=None):
         choices=("colocated", "prefill", "decode"),
         help="disaggregated serving role (ISSUE 19): 'prefill' workers "
              "answer /prefill with exported page payloads, 'decode' workers "
-             "import them via /generate handoffs (both force the paged "
-             "engine; 'colocated' is the classic do-everything replica)",
+             "import them via /generate handoffs; 'colocated' is the "
+             "classic do-everything replica",
     )
     p.add_argument(
         "--kv-quant", default="none", choices=("none", "int8"),
-        help="KV-cache storage precision (forces the paged engine): 'int8' "
+        help="KV-cache storage precision: 'int8' "
              "stores K/V pages as int8 with per-row float32 scales, roughly "
              "doubling the page pool the same HBM budget buys; the fused "
              "decode kernel dequantizes per page tile in VMEM",
@@ -529,16 +529,12 @@ def main(argv=None):
         for i, spec in enumerate(args.lora.split(",")):
             name, _, rank = spec.partition(":")
             make_random(reg, name, rank=int(rank) if rank else 4, seed=i + 1)
-        extra.update(paged=True, page_size=8, lora=AdapterArena(reg))
+        extra.update(page_size=8, lora=AdapterArena(reg))
     if args.kv_quant != "none":
-        # quantized arenas only exist on the paged engine; the flag opts
-        # the replica into paging rather than erroring on the dense cache
-        extra.update(paged=True, kv_quant=args.kv_quant)
+        extra["kv_quant"] = args.kv_quant
         extra.setdefault("page_size", 8)
     if args.role != "colocated":
-        # disaggregated roles are page-handoff roles by definition: the
-        # wire format IS the page arena rows, so both ends must be paged
-        extra.update(paged=True, role=args.role)
+        extra["role"] = args.role
         extra.setdefault("page_size", 8)
     eng = ContinuousBatchingEngine(
         model,

@@ -97,7 +97,6 @@ def _paged(model, **kw):
     kw.setdefault("prefill_buckets", [8, 32])
     kw.setdefault("queue_depth", 16)
     kw.setdefault("seed", 0)
-    kw.setdefault("paged", True)
     kw.setdefault("page_size", 8)
     return ContinuousBatchingEngine(model, **kw)
 

@@ -254,7 +254,7 @@ def test_prefix_cache_hit_copies_both_row_kinds_and_changes_no_token():
 @pytest.mark.parametrize("kwargs,feature", [
     ({"tp": 2}, "tp"), ({"cp": 2}, "cp"), ({"kv_quant": "int8"}, "kv_quant"),
     ({"lora": object()}, "lora"), ({"spec_k": 2}, "spec_k"), ({"role": "decode"}, "role"),
-    ({"role": "prefill"}, "role"), ({"paged": False}, "dense")])
+    ({"role": "prefill"}, "role")])
 def test_what_the_model_cannot_do_is_refused_at_construction(kwargs, feature):
     model = DeepseekV32ForCausalLM(config(num_hidden_layers=2))
     with pytest.raises(E.UnsupportedByModel) as err:
@@ -284,7 +284,7 @@ def test_llama_through_the_contract_compiles_and_decodes_as_before():
     assert model.backbone is model.llama and not hasattr(model, "engine_unsupported")
     assert [r[:3] for r in model.cache_rows()] == [("k", 2, 16), ("v", 2, 16)]
     eng = ContinuousBatchingEngine(model, slots=2, max_len=64, prefill_buckets=[16, 32],
-                                   page_size=8, paged=True).warmup()
+                                   page_size=8).warmup()
     warm = eng.compile_counts()
     assert warm == {"prefill": 2, "decode": 1, "aot_hits": 0, "chunk_prefill": 2, "copy": 1}
     arena = eng._arenas[0]

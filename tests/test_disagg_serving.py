@@ -71,7 +71,6 @@ def _engine(model, role="colocated", **kw):
     kw.setdefault("prefill_buckets", [8, 16])
     kw.setdefault("queue_depth", 16)
     kw.setdefault("seed", 0)
-    kw.setdefault("paged", True)
     kw.setdefault("page_size", 8)
     return ContinuousBatchingEngine(model, role=role, **kw)
 
@@ -271,7 +270,7 @@ def test_engine_handoff_int8_bit_identical_frozen_compiles(model):
 
 def test_role_and_handoff_validation(model):
     with pytest.raises(ValueError):
-        _engine(model, role="prefill", paged=False)
+        _engine(model, role="router")
     # a handoff only lands on a decode-role engine; colocated and prefill
     # engines reject it typed instead of corrupting their arenas
     for role in ("colocated", "prefill"):
@@ -279,12 +278,6 @@ def test_role_and_handoff_validation(model):
         with pytest.raises(ValueError):
             eng.submit(_prompt(4), max_new_tokens=2,
                        handoff={"version": HANDOFF_VERSION})
-    dense = ContinuousBatchingEngine(
-        model, slots=2, max_len=64, prefill_buckets=[8], queue_depth=4,
-        seed=0, paged=False,
-    )
-    with pytest.raises(ValueError):
-        dense.submit(_prompt(4), max_new_tokens=2, export_kv=True)
 
 
 def test_reservations_gate_admission_and_expire(model):

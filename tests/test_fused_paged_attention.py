@@ -53,7 +53,6 @@ def _paged(model, **kw):
     kw.setdefault("prefill_buckets", [8, 16])
     kw.setdefault("queue_depth", 16)
     kw.setdefault("seed", 0)
-    kw.setdefault("paged", True)
     kw.setdefault("page_size", 8)
     return ContinuousBatchingEngine(model, **kw)
 

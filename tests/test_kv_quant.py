@@ -76,7 +76,6 @@ def _paged(model, **kw):
     kw.setdefault("prefill_buckets", [8, 16])
     kw.setdefault("queue_depth", 16)
     kw.setdefault("seed", 0)
-    kw.setdefault("paged", True)
     kw.setdefault("page_size", 8)
     return ContinuousBatchingEngine(model, **kw)
 
@@ -233,15 +232,8 @@ class TestQuantConfig:
         assert validate_kv_quant("INT8") == "int8"
         with pytest.raises(QuantConfigError, match="int4"):
             validate_kv_quant("int4")
-        with pytest.raises(QuantConfigError, match="paged"):
-            validate_kv_quant("int8", paged=False)
 
-    def test_engine_rejects_quant_without_paging(self, model):
-        with pytest.raises(QuantConfigError, match="paged"):
-            ContinuousBatchingEngine(
-                model, slots=2, max_len=32, prefill_buckets=[8],
-                seed=0, paged=False, kv_quant="int8",
-            )
+    def test_engine_rejects_unknown_quant_mode(self, model):
         with pytest.raises(QuantConfigError, match="fp4"):
             _paged(model, kv_quant="fp4")
 

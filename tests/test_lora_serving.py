@@ -55,7 +55,6 @@ def _engine(model, **kw):
     kw.setdefault("prefill_buckets", [8, 16])
     kw.setdefault("queue_depth", 16)
     kw.setdefault("seed", 0)
-    kw.setdefault("paged", True)
     kw.setdefault("page_size", 8)
     return ContinuousBatchingEngine(model, **kw)
 

@@ -88,8 +88,7 @@ class TestResNet:
         # train-mode batch-stat BN amplifies fp32 reduction-order noise
         # through rsqrt(var+eps) on near-dead channels (random weights, few
         # elements per channel), so cross-layout agreement is inherently
-        # loose here; real layout bugs still produce O(1) errors.  Absolute
-        # numerics vs the reference are gated by bench.py's loss parity.
+        # loose here; real layout bugs still produce O(1) errors.
         m1.train()
         m2.train()
         o1 = m1(paddle.to_tensor(x)).numpy()

@@ -224,7 +224,7 @@ def _paged_engine(config, *, bf16=False, **engine):
         model = LlamaForCausalLM(config)
         if bf16:
             model = paddle.amp.decorate(model, level="O2", dtype="bfloat16")
-        return ContinuousBatchingEngine(model, paged=True, **engine)
+        return ContinuousBatchingEngine(model, **engine)
     finally:
         paddle.set_rng_state(state)
 

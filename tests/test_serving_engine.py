@@ -216,18 +216,16 @@ def test_streaming_token_callbacks(model):
     assert stream == out[-5:].tolist()  # streamed in generation order
 
 
-@pytest.mark.parametrize("paged", [False, True])
-def test_streaming_runs_one_step_behind_the_device(model, paged):
+def test_streaming_runs_one_step_behind_the_device(model):
     """A streaming callback gets step N's token while step N + 1 is in
     flight: one step stays unfetched between ticks, the tokens and their
     order are those of a request nobody streams, a second request admitted
     midway starts from whole host mirrors, and an EOS watch, which decides
     membership, is fetched in its own tick."""
-    kw = {} if paged else {"paged": False}
     p, q = _prompt(5, seed=8), _prompt(6, seed=9)
-    want_p = _engine(model, **kw).generate(p, max_new_tokens=9)[-9:].tolist()
-    want_q = _engine(model, **kw).generate(q, max_new_tokens=4)[-4:].tolist()
-    eng = _engine(model, **kw)
+    want_p = _engine(model).generate(p, max_new_tokens=9)[-9:].tolist()
+    want_q = _engine(model).generate(q, max_new_tokens=4)[-4:].tolist()
+    eng = _engine(model)
     sp, sq = [], []
     r = eng.submit(p, max_new_tokens=9, on_token=sp.append)
     eng.step()  # the prefill's token, then the first decode step, dispatched and kept
@@ -244,6 +242,21 @@ def test_streaming_runs_one_step_behind_the_device(model, paged):
     r3 = eng.submit(p, max_new_tokens=9, eos_token_id=-1, on_token=sp.append)
     eng.step()
     assert len(r3.tokens) == 2 and not eng._pending_fetch
+
+
+def test_the_engine_takes_no_paged_argument(model):
+    """There is one engine: its selector is refused, not ignored."""
+    with pytest.raises(TypeError, match="paged"):
+        ContinuousBatchingEngine(model, paged=False)
+    assert _engine(model).decode_kernel == "auto"
+
+
+@pytest.mark.parametrize("name", ["FLAGS_serve_paged_kv", "FLAGS_serve_decode_kernel"])
+def test_the_flag_that_chose_an_engine_or_a_kernel_is_gone(name):
+    with pytest.raises(KeyError, match=name):
+        paddle.get_flags([name])
+    with pytest.raises(KeyError, match=name):
+        paddle.set_flags({name: "auto"})
 
 
 def test_submit_queue_full_raises(model):

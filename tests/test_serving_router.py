@@ -108,16 +108,18 @@ def test_healthz_exports_router_load_fields(model):
         _stop_server(srv)
 
 
-def test_dense_engine_reports_slot_free_fraction(model):
+def test_engine_reports_page_free_fraction(model):
     eng = ContinuousBatchingEngine(
         model, slots=2, max_len=64, prefill_buckets=[8], queue_depth=4,
-        seed=0, paged=False,
+        seed=0, page_size=8, prefix_cache=False,
     )
     assert eng.healthz()["page_free_frac"] == 1.0
     eng.submit(_prompt(4), max_new_tokens=4)
-    eng.step()  # admit into a slot
-    assert eng.healthz()["page_free_frac"] == 0.5
+    eng.step()  # admit into a slot: its pages leave the free list
+    assert 0.0 < eng.healthz()["page_free_frac"] < 1.0
     eng.run_until_idle()
+    # nothing holds a page once the request is done (no prefix cache)
+    assert eng.healthz()["page_free_frac"] == 1.0
 
 
 # ---------------------------------------------------------------------------
@@ -849,7 +851,7 @@ def test_kill9_chaos_drill_mixed_adapters(model, tmp_path):
         make_random(reg, name, rank=4, seed=i + 1)
     ref_eng = ContinuousBatchingEngine(
         model, slots=2, max_len=64, prefill_buckets=[8, 16], queue_depth=32,
-        seed=0, paged=True, page_size=8, lora=AdapterArena(reg),
+        seed=0, page_size=8, lora=AdapterArena(reg),
     )
     n_requests = 16
     refs = []

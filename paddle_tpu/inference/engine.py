@@ -19,8 +19,10 @@ executables — the prompt pads up to its bucket, attends to itself causally,
 and its K/V scatter into the pages of its table row (the row is data, so one
 executable per bucket serves every slot); a prompt longer than the largest
 bucket is admitted as consecutive chunks of it.  Prefills interleave with
-in-flight decode at step granularity; finished slots (EOS or
-max_new_tokens) are recycled immediately.
+in-flight decode at step granularity, dispatched behind the step in flight
+and fetched with it a step late; a slot at max_new_tokens leaves the step by
+the host's count and is recycled when its last token is delivered, a tick
+later (at EOS, whose value decides, at once).
 
 Why padding garbage is safe: padding rows of a prefill never touch a mapped
 page (they fall on the scratch page, page 0 of each shard, which no sequence
@@ -256,6 +258,20 @@ class NonFiniteLogits(FloatingPointError):
 class _StaleEngine(Exception):
     """Internal: the scheduler generation this thread was started for was
     superseded by a restart; abort without touching engine state."""
+
+
+class _Unfetched:
+    """One dispatched program whose tokens are still on the device: a decode
+    step (`nxt` [S, 1], `finite` [S]) or a prefill's first token (`nxt` [1],
+    `first` the attributes of its `engine.prefill` span).  `pairs` are the (slot, request) it ran
+    for, as seated at dispatch: by the fetch the slot may hold another.
+    `eager` asks for delivery a step behind the device and no later: it holds
+    a streamed token, a first token, or a slot's last."""
+    __slots__ = ("nxt", "finite", "pairs", "t0", "stats", "first", "eager")
+
+    def __init__(self, nxt, finite, pairs, t0, stats=(), first=None, eager=False):
+        self.nxt, self.finite, self.pairs, self.t0 = nxt, finite, pairs, t0
+        self.stats, self.first, self.eager = stats, first, eager
 
 
 class EngineRequest:
@@ -667,14 +683,25 @@ class ContinuousBatchingEngine:
         self._pos = np.zeros(self.slots, np.int32)
         self._last_tok = np.zeros(self.slots, np.int32)
         self._temps = np.zeros(self.slots, np.float32)
-        # device-resident decode loop state (toks, pos, active, temps),
-        # rebuilt from the host mirrors only when slot membership changes
+        # decode steps still to dispatch for each slot: set when it is seated
+        # (max_new_tokens less the prefill's token), counted down at dispatch.
+        # At 0 the slot's last step is in flight and it runs in no further one
+        self._left = np.zeros(self.slots, np.int32)
+        # what the host knows of the decode loop's device state (pos, active,
+        # temps; with it the page tables and the adapters): None when slot
+        # membership changed, uploaded anew from the host mirrors before the
+        # next step.  No fetch: _pos is advanced at dispatch
         self._dev = None
+        # the loop's token vector [S, 1], which the host does NOT know while
+        # steps are in flight: the newest step's output, with each prefill's
+        # first token written in on the device.  None only while _last_tok is
+        # whole (nothing unfetched): then the next step uploads that
+        self._toks_t = None
         # open decode-epoch summary for tracing: {"t0", "ticks", "members"},
         # one engine.decode span per traced member when membership changes
         self._ep = None
-        # decode steps dispatched but not yet fetched to host:
-        # [(nxt, finite, active_idx, dispatch_t)]
+        # programs dispatched whose tokens the host has not fetched, in
+        # dispatch order: [_Unfetched]
         self._pending_fetch = []
         # all-False poison vector reused every un-poisoned step (no per-step
         # H2D); serve.decode.nan swaps in a one-hot row for one step
@@ -731,7 +758,9 @@ class ContinuousBatchingEngine:
         Returns (next tokens [S,1], advanced pos [S], finite [S], key[,
         stats]): the loop state is device-resident and threads straight back
         in — between membership changes a decode step costs one executable
-        dispatch plus the [S] token fetch, zero host->device transfers.
+        dispatch plus the [S] token fetch, zero host->device transfers; at
+        one the tokens stay (a prefill writes its first into them) and only
+        pos, active, temps, tables and adapters are sent again.
         `finite` is the per-slot non-finite-logit-window watch: a
         poisoned/diverged slot errors alone, its co-batched rows are
         independent."""
@@ -846,15 +875,15 @@ class ContinuousBatchingEngine:
         return out, n_emit, new_pos, finite, key
 
     def _prefill_paged_body(self, toks, row_table, true_len, temp, key,
-                            adapters):
+                            adapters, last_toks, slot):
         """Bucketed fresh prefill: toks [1, bucket] (right-padded), true_len
         a scalar (data).  The prompt attends to itself causally while its
         K/V scatter into the pages of `row_table` ([max_pages_per_seq]
         int32, data); returns the first generated token from the logits at
         true_len - 1.  `adapters` ([1] int32, data) is the request's LoRA
-        arena row (0 = base).  Padding rows land on scratch."""
-        import jax
-        import jax.numpy as jnp
+        arena row (0 = base).  Padding rows land on scratch.  `last_toks`
+        ([S, 1], the decode loop's token vector) comes back with that token
+        at row `slot` (int32 scalar, data): see `_first_token`."""
         from jax import lax
 
         from ..ops.dispatch import apply
@@ -871,21 +900,10 @@ class ContinuousBatchingEngine:
             [hidden, true_len], name="serve_prefill_last",
         )
         logits = self.model.lm_head(h_last)[:, -1]  # [1, V]
-
-        def f(lg, ky, tp):
-            lgf = lg.astype(jnp.float32)
-            greedy = jnp.argmax(lgf, axis=-1).astype(jnp.int32)
-            ky, sub = jax.random.split(ky)
-            samp = jax.random.categorical(
-                sub, lgf / jnp.maximum(tp, 1e-6), axis=-1
-            ).astype(jnp.int32)
-            return jnp.where(tp > 0.0, samp, greedy), ky
-
-        nxt, key = apply(f, [logits, key, temp], multi=True, name="serve_sample1")
-        return nxt, key
+        return self._first_token(logits, key, temp, last_toks, slot)
 
     def _chunk_prefill_body(self, toks, row_table, true_len, start, temp, key,
-                            adapters):
+                            adapters, last_toks, slot):
         """Prefix-cache-hit prefill: only the UNSHARED suffix runs through
         the model.  toks [1, bucket] holds the suffix (right-padded),
         true_len its real length, start (int32[1], data) the absolute
@@ -895,9 +913,7 @@ class ContinuousBatchingEngine:
         data) is the request's LoRA arena row — safe to combine with prefix
         sharing because cache entries are keyed by (adapter, token chain):
         a hit guarantees the shared pages were prefilled under the SAME
-        adapter."""
-        import jax
-        import jax.numpy as jnp
+        adapter.  `last_toks` and `slot` as in `_prefill_paged_body`."""
         from jax import lax
 
         from ..ops.dispatch import apply
@@ -914,18 +930,31 @@ class ContinuousBatchingEngine:
             [hidden, true_len], name="serve_prefill_last",
         )
         logits = self.model.lm_head(h_last)[:, -1]  # [1, V]
+        return self._first_token(logits, key, temp, last_toks, slot)
 
-        def f(lg, ky, tp):
+    def _first_token(self, logits, key, temp, last_toks, slot):
+        """A prefill's last step: the request's first token from `logits`
+        [1, V], and the decode loop's token vector with it written at row
+        `slot` ON THE DEVICE, so that the next decode step reads it without
+        the host having seen it.  Returns (token [1], key, vector [S, 1])."""
+        import jax
+        import jax.numpy as jnp
+        from jax import lax
+
+        from ..ops.dispatch import apply
+
+        def f(lg, ky, tp, vec, sl):
             lgf = lg.astype(jnp.float32)
             greedy = jnp.argmax(lgf, axis=-1).astype(jnp.int32)
             ky, sub = jax.random.split(ky)
             samp = jax.random.categorical(
                 sub, lgf / jnp.maximum(tp, 1e-6), axis=-1
             ).astype(jnp.int32)
-            return jnp.where(tp > 0.0, samp, greedy), ky
+            tok = jnp.where(tp > 0.0, samp, greedy)
+            return tok, ky, lax.dynamic_update_slice(vec, tok[:, None], (sl, 0))
 
-        nxt, key = apply(f, [logits, key, temp], multi=True, name="serve_sample1")
-        return nxt, key
+        return apply(f, [logits, key, temp, last_toks, slot], multi=True,
+                     name="serve_sample1")
 
     def _copy_page_body(self, src, dst):
         """Copy-on-write: duplicate arena page `src` into `dst` (scalar int32
@@ -1190,18 +1219,21 @@ class ContinuousBatchingEngine:
         zero_row = to_tensor(np.zeros(self.pages_per_seq, np.int32))
         zero_ad1 = to_tensor(np.zeros(1, np.int32))
         zero_ads = to_tensor(np.zeros(self.slots, np.int32))
+        zero_toks = to_tensor(np.zeros((self.slots, 1), np.int32))
+        slot0 = to_tensor(np.int32(0))
         for b in self.prefill_buckets:
             # analysis: allow GRAFT010 — warmup runs before the scheduler thread exists; steady-state _key writes hold _mu
-            _, self._key = self._prefill_fn(
+            _, self._key, _ = self._prefill_fn(
                 to_tensor(np.zeros((1, b), np.int32)), zero_row,
                 to_tensor(np.int32(b)), to_tensor(np.float32(0.0)),
-                self._key, zero_ad1,
+                self._key, zero_ad1, zero_toks, slot0,
             )
-            _, self._key = self._chunk_fn(
+            _, self._key, _ = self._chunk_fn(
                 to_tensor(np.zeros((1, b), np.int32)), zero_row,
                 to_tensor(np.int32(b)),
                 to_tensor(np.zeros(1, np.int32)),
-                to_tensor(np.float32(0.0)), self._key, zero_ad1,
+                to_tensor(np.float32(0.0)), self._key, zero_ad1, zero_toks,
+                slot0,
             )
         self._copy_fn(  # scratch onto itself: a no-op through the real fn
             to_tensor(np.int32(0)), to_tensor(np.int32(0))
@@ -1228,7 +1260,7 @@ class ContinuousBatchingEngine:
                 ]
             self._import_fn(*args, to_tensor(np.int32(0)))
         _, _, _, self._key, *_ = self._decode_fn(
-            to_tensor(np.zeros((self.slots, 1), np.int32)),
+            zero_toks,
             to_tensor(np.zeros(self.slots, np.int32)),
             to_tensor(np.zeros(self.slots, bool)),
             to_tensor(np.zeros(self.slots, np.float32)),
@@ -1545,7 +1577,7 @@ class ContinuousBatchingEngine:
             self._thread = None
         with self._mu:
             try:
-                self._flush_pending_locked()
+                self._flush_pending_locked(cause="stop")
             except _StaleEngine:
                 pass
             except Exception:
@@ -1642,12 +1674,14 @@ class ContinuousBatchingEngine:
             self._pos[:] = 0
             self._last_tok[:] = 0
             self._temps[:] = 0.0
+            self._left[:] = 0
             # drafters rebuild cleanly at re-admission (reset from prompt +
             # first token) — stale host n-gram state must not outlive the
             # slot assignment it indexed
             self._drafters = [None] * self.slots
             self._ep = None  # epoch members were restarted; drop, don't record
             self._dev = None
+            self._toks_t = None  # nothing unfetched is kept: _last_tok is whole
             self._pending_fetch = []
             self._watchdog_trip = None
             self._last_progress = time.monotonic()
@@ -1712,9 +1746,11 @@ class ContinuousBatchingEngine:
             self._pos[:] = 0
             self._last_tok[:] = 0
             self._temps[:] = 0.0
+            self._left[:] = 0
             self._drafters = [None] * self.slots
             self._ep = None
             self._dev = None
+            self._toks_t = None  # nothing unfetched is kept: _last_tok is whole
             self._pending_fetch = []
         finally:
             if locked:
@@ -1759,6 +1795,7 @@ class ContinuousBatchingEngine:
                     if gen != self._gen:
                         return
                     self._pending_fetch.clear()
+                    self._toks_t = None
                     for s, req in enumerate(self._slot_req):
                         if req is not None:
                             req.error = e
@@ -1908,7 +1945,9 @@ class ContinuousBatchingEngine:
                     victims.append((s, req, "timeout"))
             if not victims:
                 return
-            self._flush_pending_locked()  # emit what was already dispatched
+            # emit what was already dispatched: a victim keeps every token
+            # it was given a step for
+            self._flush_pending_locked(cause="evict")
             for s, req, reason in victims:
                 if self._slot_req[s] is not req:
                     continue  # resolved during the flush (eos/length/nan)
@@ -2065,18 +2104,23 @@ class ContinuousBatchingEngine:
         """Paged admission: prefix-cache lookup, page mapping (shared fulls
         read-only, COW for a matched partial page, fresh pages for the
         rest), then either a fresh bucketed prefill or a chunk prefill of
-        just the unshared suffix — dispatched outside the mutex.  Commits
-        the prompt's pages to the prefix cache after the prefill lands."""
+        just the unshared suffix — dispatched outside the mutex, BEHIND
+        whatever decode step is in flight and without waiting for it: the
+        device runs its programs in the order of their dispatch, so pages a
+        finished slot gave back are safe to map, and the pages committed to
+        the prefix cache here are written before any later program reads
+        them.  The first token is not fetched either: the prefill writes it
+        into the decode loop's token vector on the device, and the host gets
+        it (and the request its `ttft_s`) with the next flush."""
         from .. import profiler as _prof
         from .. import to_tensor
 
-        ps = self.page_size
         L = int(req.prompt.size)
         pinned = None  # COW source, kept alive across our own allocations
         with self._mu:
             self._check_gen(gen)
-            self._flush_pending_locked()
             key = self._key
+            last_toks = self._token_vector_locked()
             req.max_new_tokens = min(req.max_new_tokens, self.max_len - L)
             coverage = self._pages_for(L, req.max_new_tokens)
             match_len, shared_full, tail_page, tail_rows = 0, [], None, 0
@@ -2176,23 +2220,27 @@ class ContinuousBatchingEngine:
                 )
                 table_t = to_tensor(row_table)
                 temp_t = to_tensor(np.float32(req.temperature))
+                slot_t = to_tensor(np.int32(s))
                 for offset, n in chunks:
                     b = self._bucket_for(n)
                     toks = np.zeros((1, b), np.int32)
                     toks[0, :n] = req.prompt[offset:offset + n]
                     t_ch = time.perf_counter()
                     self._check_gen(gen)  # between chunks too: a restart owns the pages
+                    # only the last chunk's token is the request's first: of
+                    # an earlier chunk's vector nothing is kept
                     if offset == 0:
-                        nxt, key = self._prefill_fn(
+                        nxt, key, seated = self._prefill_fn(
                             to_tensor(toks), table_t,
                             to_tensor(np.int32(n)), temp_t, key, ad_t,
+                            last_toks, slot_t,
                         )
                     else:
-                        nxt, key = self._chunk_fn(
+                        nxt, key, seated = self._chunk_fn(
                             to_tensor(toks), table_t,
                             to_tensor(np.int32(n)),
                             to_tensor(np.full(1, offset, np.int32)),
-                            temp_t, key, ad_t,
+                            temp_t, key, ad_t, last_toks, slot_t,
                         )
                     if req.trace and len(chunks) > 1:
                         _obs.record(
@@ -2200,9 +2248,6 @@ class ContinuousBatchingEngine:
                             t1=time.perf_counter(), parent_id=req.trace[1],
                             req=req.id, offset=offset, rows=n, bucket=b,
                         )
-                # only the last chunk's token is the request's first
-                with _san.allowed_sync("prefill first-token fetch"):
-                    tok = int(np.asarray(nxt.numpy()).reshape(-1)[0])
         finally:
             if pinned is not None:
                 with self._mu:
@@ -2210,6 +2255,7 @@ class ContinuousBatchingEngine:
         with self._mu:
             self._check_gen(gen)  # a restart while we dispatched owns req now
             self._key = key
+            self._toks_t = seated
             if self._prefix is not None:
                 inserted = self._prefix.commit(
                     req.prompt, pages, self._pool,
@@ -2217,36 +2263,64 @@ class ContinuousBatchingEngine:
                 )
                 if inserted:
                     _prof.record_paging_event("cache_commits", inserted)
-            req.ttft_s = time.perf_counter() - req._submit_t
-            self._slot_req[s] = req
-            self._pos[s] = L
-            self._last_tok[s] = tok
-            self._temps[s] = req.temperature
-            self._slot_adapter[s] = req.adapter_slot or 0
-            if self._spec_on and req.temperature == 0.0 and (
-                req.spec_k is None or req.spec_k > 0
-            ):
-                # greedy slots draft from their own history (prompt + first
-                # token); sampled slots ride the verify step undrafted —
-                # greedy equivalence is the only acceptance rule we prove
-                self._drafters[s] = NgramDrafter(self._spec_ngram).reset(
-                    [int(t) for t in req.prompt] + [tok]
-                )
-            else:
-                self._drafters[s] = None
-            req.state = "decoding"
-            self._obs_epoch_close()
-            self._dev = None  # membership changed: rebuild device loop state
-            self._emit(s, req, tok)
-        if req.trace:
-            _obs.record(
-                "engine.chunk_prefill" if match_len else "engine.prefill",
-                req.trace[0], t0=t_pf, t1=time.perf_counter(),
-                parent_id=req.trace[1], req=req.id, bucket=bucket, slot=s,
-                prefix_match=match_len or None,
-                chunks=len(chunks) if len(chunks) > 1 else None,
-                adapter=req.adapter.name if req.adapter is not None else None,
+            self._seat_locked(s, req, L)
+            self._pending_fetch.append(_Unfetched(
+                nxt, None, [(s, req)], t_pf, eager=True,
+                first={
+                    "bucket": bucket, "prefix_match": match_len or None,
+                    "chunks": len(chunks) if len(chunks) > 1 else None,
+                },
+            ))
+            if self._spec_on:
+                # a draft is made on the host from the tokens so far, the
+                # first among them
+                self._flush_pending_locked(cause="first_token")
+
+    def _token_vector_locked(self):
+        """The decode loop's token vector on the device.  Where there is none
+        (a fresh or restarted engine, a handoff import) it is uploaded from
+        _last_tok, which is whole only once nothing is unfetched.  Caller
+        holds _mu."""
+        from .. import to_tensor
+
+        if self._toks_t is None:
+            self._flush_pending_locked(cause="rebuild")
+            self._toks_t = to_tensor(self._last_tok.reshape(self.slots, 1))
+        return self._toks_t
+
+    def _seat_locked(self, s, req, L):
+        """Slot `s` is `req`'s from the next decode step on, at position L
+        with one token made (by its prefill, or by the worker that handed it
+        over).  Caller holds _mu."""
+        from .. import profiler as _prof
+
+        self._slot_req[s] = req
+        self._pos[s] = L
+        self._left[s] = req.max_new_tokens - 1
+        self._temps[s] = req.temperature
+        self._slot_adapter[s] = req.adapter_slot or 0
+        self._drafters[s] = None
+        req.state = "decoding"
+        self._obs_epoch_close()
+        self._dev = None  # membership changed: upload the host's part anew
+        _prof.record_membership_change()
+
+    def _first_token_locked(self, s, req, tok, now):
+        """The host has `req`'s first token (fetched, or handed over): it is
+        the slot's last token, the drafter's seed, and the request's first
+        emission.  Caller holds _mu."""
+        req.ttft_s = now - req._submit_t
+        self._last_tok[s] = tok
+        if self._spec_on and req.temperature == 0.0 and (
+            req.spec_k is None or req.spec_k > 0
+        ):
+            # greedy slots draft from their own history (prompt + first
+            # token); sampled slots ride the verify step undrafted —
+            # greedy equivalence is the only acceptance rule we prove
+            self._drafters[s] = NgramDrafter(self._spec_ngram).reset(
+                [int(t) for t in req.prompt] + [tok]
             )
+        self._emit(s, req, tok)
 
     def _import_into_paged(self, s, req, gen):
         """Disaggregated admission (ISSUE 19): the prompt's KV arrives in
@@ -2268,7 +2342,7 @@ class ContinuousBatchingEngine:
         n_prompt_pages = -(-L // ps)
         with self._mu:
             self._check_gen(gen)
-            self._flush_pending_locked()
+            self._flush_pending_locked(cause="admission")
             req.max_new_tokens = min(req.max_new_tokens, self.max_len - L)
             coverage = self._pages_for(L, req.max_new_tokens)
             pages = [
@@ -2326,27 +2400,15 @@ class ContinuousBatchingEngine:
                 )
                 if inserted:
                     _prof.record_paging_event("cache_commits", inserted)
-            req.ttft_s = time.perf_counter() - req._submit_t
-            self._slot_req[s] = req
-            self._pos[s] = L
-            self._last_tok[s] = first_tok
-            self._temps[s] = req.temperature
-            self._slot_adapter[s] = 0  # handoffs never carry an adapter
-            if self._spec_on and req.temperature == 0.0 and (
-                req.spec_k is None or req.spec_k > 0
-            ):
-                self._drafters[s] = NgramDrafter(self._spec_ngram).reset(
-                    [int(t) for t in req.prompt] + [first_tok]
-                )
-            else:
-                self._drafters[s] = None
-            req.state = "decoding"
             req.handoff = None  # the arena owns the rows now; free the copy
-            self._obs_epoch_close()
-            self._dev = None  # membership changed: rebuild device loop state
             _prof.record_disagg_event("imports")
             _prof.record_disagg_event("import_pages", n_prompt_pages)
-            self._emit(s, req, first_tok)
+            self._seat_locked(s, req, L)  # handoffs never carry an adapter
+            # the first token came over the wire, so the host has it and the
+            # device does not: the next step uploads _last_tok, which the
+            # flush above made whole
+            self._toks_t = None
+            self._first_token_locked(s, req, first_tok, time.perf_counter())
         if req.trace:
             _obs.record(
                 "engine.import", req.trace[0], t0=t_pf,
@@ -2362,41 +2424,56 @@ class ContinuousBatchingEngine:
 
         with self._mu:
             self._check_gen(gen)
-            if self._dev is None:
-                # the host mirrors the device loop state is rebuilt from are
-                # whole only once every dispatched step has been fetched
-                self._flush_pending_locked()
-            active_idx = [s for s in range(self.slots) if self._slot_req[s] is not None]
-            if not active_idx:
+            toks_t = self._token_vector_locked()
+            run = [
+                s for s in range(self.slots)
+                if self._slot_req[s] is not None and self._left[s] > 0
+            ]
+            if not run:
+                # every seated slot has its last step in flight (or none is
+                # seated): nothing to dispatch beside the fetch.  With work
+                # queued behind those slots that is a drain the length bound
+                # forced; with none the device is out of work anyway
+                self._flush_pending_locked(
+                    cause="length" if self.pending else None
+                )
                 return 0
             t0 = time.perf_counter()
             if self._dev is None:
+                # membership changed: what the host knows goes up anew, and
+                # the host knows it without a fetch (_pos is advanced at
+                # dispatch).  What it does not know, the tokens, stays where
+                # it is: the newest step's output, first tokens written in
                 self._obs_epoch_close()
                 active = np.zeros(self.slots, bool)
-                active[active_idx] = True
+                active[run] = True
                 self._dev = (
-                    to_tensor(self._last_tok.reshape(self.slots, 1)),
                     to_tensor(self._pos.copy()), to_tensor(active),
                     to_tensor(self._temps.copy()),
                 )
                 # page tables (and adapter bindings) change exactly when
                 # membership does — the same events that invalidate _dev
                 # — so one H2D mirror per membership change covers every
-                # following step
-                self._tables_t = to_tensor(self._page_table.copy())
+                # following step.  A slot that sits out (its last step in
+                # flight, its finish a tick away) still maps its pages: its
+                # row goes up as zeros, so that the inactive slot's write at
+                # pos 0 lands on scratch and not on its prompt's first row
+                tables = np.zeros_like(self._page_table)
+                tables[run] = self._page_table[run]
+                self._tables_t = to_tensor(tables)
                 self._adapters_t = to_tensor(self._slot_adapter.copy())
-                self._obs_epoch_open(active_idx)
-            toks_t, pos_t, active_t, temps_t = self._dev
+                self._obs_epoch_open(run)
+            pos_t, active_t, temps_t = self._dev
             key = self._key
             poison_t, poisoned = self._poison_zero, None
-            if _inj.should_fire("serve.decode.nan", context=f"slot {active_idx[0]}"):
-                poisoned = active_idx[0]
+            if _inj.should_fire("serve.decode.nan", context=f"slot {run[0]}"):
+                poisoned = run[0]
                 pz = np.zeros(self.slots, bool)
                 pz[poisoned] = True
                 poison_t = to_tensor(pz)
         with self._watchdog.arm(
             "serve.decode", timeout=self._wd_timeout(),
-            context=f"{len(active_idx)} active slots",
+            context=f"{len(run)} active slots",
         ):
             nxt, new_pos, finite, key, *stats = self._decode_fn(
                 toks_t, pos_t, active_t, temps_t, poison_t, key,
@@ -2405,37 +2482,46 @@ class ContinuousBatchingEngine:
         with self._mu:
             self._check_gen(gen)
             self._key = key
-            self._dev = (nxt, new_pos, active_t, temps_t)
-            for s in active_idx:
+            self._toks_t = nxt
+            self._dev = (new_pos, active_t, temps_t)
+            pairs = [(s, self._slot_req[s]) for s in run]
+            for s in run:
                 self._pos[s] += 1
-            # fetch to host only when something needs the values this step —
-            # an EOS watch, a slot hitting its length bound, or a poisoned
-            # step that must be checked now.  Otherwise the step stays in
-            # flight and the sync lands at the next membership change, so
-            # XLA pipelines decode dispatches exactly like the lock-step
-            # loop.  A streaming callback is served one step behind the
-            # device: the step just dispatched stays in flight while the one
-            # before it is fetched and emitted, so the host's work between
-            # two steps (fetch, callbacks, the next dispatch) runs beside
-            # the device's and not in its way.  Nothing that decides
-            # membership waits on the step in flight: a length bound or an
-            # EOS watch flushes it here; an admission, an eviction, a stop
-            # and a rebuild of the device loop state flush it there.
-            self._pending_fetch.append((nxt, finite, active_idx, t0, stats))
-            depth = len(self._pending_fetch)
-            if poisoned is not None or any(
-                self._slot_req[s].eos_token_id is not None
-                or len(self._slot_req[s].tokens) + depth
-                >= self._slot_req[s].max_new_tokens
-                for s in active_idx
-            ):
-                self._flush_pending_locked()
-            elif any(self._slot_req[s].on_token is not None for s in active_idx):
+                self._left[s] -= 1
+            last = any(self._left[s] == 0 for s in run)
+            if last:
+                # the host counted: this was some slot's last step.  It is
+                # inactive from the next one on (a new mask, no fetch), and
+                # its request finishes when its last token is delivered
+                self._dev = None
+            # The host fetches a step only when something needs the values.
+            # What decides membership by VALUE is fetched in its own tick: an
+            # EOS watch, a poisoned step.  Everything else stays a step
+            # behind the device: the step just dispatched stays in flight
+            # while those before it are fetched and emitted, so the host's
+            # work between two steps (fetch, callbacks, a finish, an
+            # admission, the next dispatch) runs beside the device's and not
+            # in its way.  A length bound waits on nothing: the slot leaves
+            # the mask by count, and `_finish` runs a tick later with the
+            # delivery of its last token.  Nor does an admission: see
+            # `_prefill_into_paged`.  An entry nobody is waiting for (no
+            # stream, no first token, no last) stays unfetched until one
+            # behind it is, so a batch that streams nothing is fetched once
+            # per finish, as deep as the shortest remaining length.
+            self._pending_fetch.append(_Unfetched(
+                nxt, finite, pairs, t0, stats,
+                eager=last or any(r.on_token is not None for _, r in pairs),
+            ))
+            if poisoned is not None:
+                self._flush_pending_locked(cause="poison")
+            elif any(r.eos_token_id is not None for _, r in pairs):
+                self._flush_pending_locked(cause="eos_watch")
+            elif any(e.eager for e in self._pending_fetch[:-1]):
                 self._flush_pending_locked(keep=1)
             if self._ep is not None:
                 self._ep["ticks"] += 1
             _prof.record_serving_tick(
-                len(active_idx) / self.slots, self._queue.qsize(),
+                len(run) / self.slots, self._queue.qsize(),
                 time.perf_counter() - t0,
             )
             _prof.record_paging_tick(
@@ -2445,14 +2531,12 @@ class ContinuousBatchingEngine:
                 # per-layer work divided out: one KV row-pair quantized
                 # per active slot, every mapped page dequantized in the
                 # kernel's page walk
-                _prof.record_kv_quant_event(
-                    "quantize", len(active_idx)
-                )
+                _prof.record_kv_quant_event("quantize", len(run))
                 _prof.record_kv_quant_event(
                     "dequantize",
-                    sum(len(self._slot_pages[s]) for s in active_idx),
+                    sum(len(self._slot_pages[s]) for s in run),
                 )
-        return len(active_idx)
+        return len(run)
 
     def _decode_once_spec(self, gen):
         """One speculative round for every active slot: draft on the host
@@ -2649,68 +2733,62 @@ class ContinuousBatchingEngine:
                         accepted=ep["accepted"],
                     )
 
-    def _flush_pending_locked(self, keep=0):
-        """Fetch every dispatched-but-unfetched decode step (but the newest
-        `keep`, which stay in flight) and emit its tokens; a slot whose
-        logit window went non-finite errors alone.
-        Membership is constant across buffered steps (any change flushes
-        first), so each entry's active set is exact.  Caller holds _mu; the
-        blocking fetch runs under the serve.fetch watchdog region and
-        re-checks the generation after it (a restart that could not take
-        the mutex may have superseded us mid-fetch)."""
+    def _flush_pending_locked(self, keep=0, cause=None):
+        """Fetch every dispatched-but-unfetched entry (but the newest
+        `keep`, which stay in flight) and deliver its tokens, in dispatch
+        order: a request's first token before its decode tokens.  Each goes
+        to the request that held the slot when the program was dispatched,
+        if it still holds it: one that left since (an error, an eviction) is
+        owed nothing, and the slot's next tenant nothing of its.  A slot
+        whose logit window went non-finite errors alone.  `cause` names why
+        NOTHING may stay in flight (a drain, counted by cause in
+        `profiler.serving_summary()`).  Caller holds _mu; the blocking fetch
+        runs under the serve.fetch watchdog region and re-checks the
+        generation after it (a restart that could not take the mutex may
+        have superseded us mid-fetch)."""
         from .. import profiler as _prof
 
         n = len(self._pending_fetch) - keep
         if n <= 0:
             return
+        if cause is not None:
+            _prof.record_serving_drain(cause)
         gen0 = self._gen
         batches, self._pending_fetch = self._pending_fetch[:n], self._pending_fetch[n:]
         t_f0 = time.perf_counter()
-        with self._watchdog.arm(
-            "serve.fetch", timeout=self._wd_timeout(),
-            context=f"{len(batches)} buffered steps",
-        ), _san.allowed_sync("batched decode-token flush"):
-            fetched = [
-                (
-                    np.asarray(nxt.numpy()).reshape(-1),
-                    np.asarray(fin.numpy()).reshape(-1),
-                    idx,
-                    t0,
-                )
-                for nxt, fin, idx, t0, _stats in batches
-            ]
-            for *_, stats in batches:
-                if stats:  # the model's own counters of the step, same fetch
-                    self.model.record_step_stats(np.asarray(stats[0].numpy()))
-        self._check_gen(gen0)
-        now = time.perf_counter()
-        if _obs.enabled():
-            flushed = {}
-            for _nxt, _fin, idx, _t0 in fetched:
-                for s in idx:
-                    r = self._slot_req[s]
-                    if r is not None and r.trace:
-                        flushed[r.id] = r
-            for r in flushed.values():
-                _obs.record("engine.fetch", r.trace[0], t0=t_f0, t1=now,
-                            parent_id=r.trace[1], req=r.id,
-                            steps=len(fetched))
-        # EWMA decode-round wall time: dispatch-to-fetch of this burst (from
-        # the fetch before it, where its first step was dispatched behind a
-        # step still in flight) over its step count — feeds
-        # estimate_drain_s / Retry-After
-        per = (now - max(fetched[0][3], self._last_flush_t)) / len(fetched)
-        self._last_flush_t = now
-        self._step_ewma_s = (
-            per if self._step_ewma_s is None
-            else 0.8 * self._step_ewma_s + 0.2 * per
-        )
-        for nxt_np, fin_np, idx, _t0 in fetched:
-            for s in idx:
-                req = self._slot_req[s]
-                if req is None:  # finished earlier in this flush
-                    continue
-                if not fin_np[s]:
+        steps, t_first, t_step = 0, None, t_f0
+        # entry by entry, each delivered as soon as it is fetched: a step's
+        # tokens do not wait for the prefill dispatched behind it
+        for e in batches:
+            with self._watchdog.arm(
+                "serve.fetch", timeout=self._wd_timeout(),
+                context=f"{len(batches)} buffered steps",
+            ), _san.allowed_sync("batched decode-token flush"):
+                nxt_np = np.asarray(e.nxt.numpy()).reshape(-1)
+                if e.first is None:
+                    fin_np = np.asarray(e.finite.numpy()).reshape(-1)
+                if e.stats:  # the model's own counters of the step, same fetch
+                    self.model.record_step_stats(np.asarray(e.stats[0].numpy()))
+            self._check_gen(gen0)
+            now = time.perf_counter()
+            if e.first is None:
+                steps, t_step = steps + 1, now
+                t_first = e.t0 if t_first is None else t_first
+            for s, req in e.pairs:
+                if self._slot_req[s] is not req:
+                    continue  # left the slot since this was dispatched
+                if e.first is not None:
+                    if req.trace:
+                        _obs.record(
+                            "engine.chunk_prefill" if e.first["prefix_match"]
+                            else "engine.prefill",
+                            req.trace[0], t0=e.t0, t1=now,
+                            parent_id=req.trace[1], req=req.id, slot=s,
+                            adapter=req.adapter.name if req.adapter is not None else None,
+                            **e.first,
+                        )
+                    self._first_token_locked(s, req, int(nxt_np[0]), now)
+                elif not fin_np[s]:
                     _prof.record_serving_fault("nonfinite")
                     req.error = NonFiniteLogits(
                         f"request {req.id}: non-finite logit window at "
@@ -2718,10 +2796,28 @@ class ContinuousBatchingEngine:
                         "was evicted — co-batched requests are unaffected"
                     )
                     self._finish(s, req, "error")
-                    continue
-                tok = int(nxt_np[s])
-                self._last_tok[s] = tok
-                self._emit(s, req, tok)
+                else:
+                    tok = int(nxt_np[s])
+                    self._last_tok[s] = tok
+                    self._emit(s, req, tok)
+        now = time.perf_counter()
+        if _obs.enabled():
+            flushed = {r.id: r for e in batches for _, r in e.pairs if r.trace}
+            for r in flushed.values():
+                _obs.record("engine.fetch", r.trace[0], t0=t_f0, t1=now,
+                            parent_id=r.trace[1], req=r.id,
+                            steps=len(batches))
+        # EWMA decode-round wall time: dispatch-to-fetch of this burst's
+        # decode steps (from the fetch before it, where its first step was
+        # dispatched behind a step still in flight) over their count — feeds
+        # estimate_drain_s / Retry-After
+        if steps:
+            per = (t_step - max(t_first, self._last_flush_t)) / steps
+            self._step_ewma_s = (
+                per if self._step_ewma_s is None
+                else 0.8 * self._step_ewma_s + 0.2 * per
+            )
+        self._last_flush_t = now
 
     def _emit(self, s, req, tok):
         req.tokens.append(tok)
@@ -2736,6 +2832,8 @@ class ContinuousBatchingEngine:
             self._finish(s, req, "length")
 
     def _finish(self, s, req, reason):
+        from .. import profiler as _prof
+
         if (
             req.export_kv and req.kv_export is None
             and reason in ("eos", "length")
@@ -2772,6 +2870,7 @@ class ContinuousBatchingEngine:
         # prefill overwrites rows [0, bucket) and decode masks the rest
         self._slot_req[s] = None
         self._pos[s] = 0
+        self._left[s] = 0
         self._last_tok[s] = 0
         self._temps[s] = 0.0
         self._drafters[s] = None
@@ -2784,7 +2883,8 @@ class ContinuousBatchingEngine:
             self._slot_adapter[s] = 0
             self._release_adapter_locked(req)
         self._obs_epoch_close()
-        self._dev = None  # membership changed: rebuild device loop state
+        self._dev = None  # membership changed: upload the host's part anew
+        _prof.record_membership_change()
         self._resolve(req, reason)
 
     def _bind_session_locked(self, s, req):

@@ -153,6 +153,10 @@ _serving_gauges = {
     # resolution (a rate, not an accumulated counter; last writer wins —
     # one engine per serving process in production)
     "deadline_miss_rate": 0.0,
+    # slots seated and slots left (a request makes two), and the times the
+    # engine let NOTHING stay in flight on the device, by what forced it
+    "membership_changes": 0,
+    "drains": {},
 }
 
 # serving fault-domain counter kinds (PR 6): engine restarts, requests
@@ -170,6 +174,34 @@ def record_serving_fault(kind, n=1):
     with _counters_lock:
         f = _serving_gauges["faults"]
         f[kind] = f.get(kind, 0) + int(n)
+
+
+# why the engine fetched everything it had dispatched, leaving the device
+# nothing to run while the host worked: a request that watches for EOS (its
+# values decide membership), a length bound with work queued behind it and no
+# slot left to run, an admission that must have the host's mirrors whole (a
+# handoff import), a first token that a draft is made from (speculation), an
+# eviction, an upload of the token vector from the host, a stop, a poisoned
+# step.  Since PR 32 a finish, an admission and a prefill's first token are
+# none of them: `length`, `admission` and `first_token` read 0 on plain
+# streaming traffic.  All are rendered, at 0 too
+_DRAIN_CAUSES = (
+    "eos_watch", "length", "admission", "first_token", "evict", "rebuild",
+    "stop", "poison",
+)
+
+
+def record_serving_drain(cause):
+    """The engine fetched every step in flight, for `cause`."""
+    with _counters_lock:
+        d = _serving_gauges["drains"]
+        d[cause] = d.get(cause, 0) + 1
+
+
+def record_membership_change():
+    """A slot was seated or left."""
+    with _counters_lock:
+        _serving_gauges["membership_changes"] += 1
 
 
 def record_deadline_miss_rate(rate):
@@ -213,6 +245,7 @@ def _reset_serving_locked():
         requests=0, tokens=0, ttfts_s=[], busy_s=0.0, ticks=0,
         occupancy_sum=0.0, occupancy_peak=0.0, queue_depth_sum=0,
         queue_depth_max=0, faults={}, deadline_miss_rate=0.0,
+        membership_changes=0, drains={},
     )
 
 
@@ -336,6 +369,7 @@ def metrics_snapshot():
         serving = dict(_serving_gauges)
         serving["ttfts_s"] = list(serving["ttfts_s"])
         serving["faults"] = dict(serving["faults"])
+        serving["drains"] = dict(serving["drains"])
         router = dict(_router_gauges)
         router["replica_states"] = dict(router["replica_states"])
         return {
@@ -950,14 +984,18 @@ def _pctl(sorted_vals, q):
 
 def serving_summary():
     """Aggregated serving metrics: requests, tokens, aggregate tokens/s over
-    the busy window, TTFT p50/p95, mean slot occupancy, queue depth avg/max —
-    plus a nested `speculation` block (acceptance rate, tokens/step) when
-    any verify step ran."""
+    the busy window, TTFT p50/p95, mean slot occupancy, queue depth avg/max,
+    the slots seated and left (`membership_changes`) and the engine's
+    `drains` by cause (see `_DRAIN_CAUSES`) — plus a nested `speculation`
+    block (acceptance rate, tokens/step) when any verify step ran."""
     with _counters_lock:
         g = dict(_serving_gauges)
         g["ttfts_s"] = list(g["ttfts_s"])
         g["faults"] = dict(g["faults"])
-    out = {"requests": g["requests"], "tokens": g["tokens"]}
+        drains = dict(g["drains"])
+    out = {"requests": g["requests"], "tokens": g["tokens"],
+           "membership_changes": g["membership_changes"],
+           "drains": {c: drains.get(c, 0) for c in _DRAIN_CAUSES}}
     if g["busy_s"] > 0:
         out["tokens_per_s"] = g["tokens"] / g["busy_s"]
     ttfts = sorted(g["ttfts_s"])

@@ -294,7 +294,8 @@ def paged_engine_steps(eng, bucket):
             eng._poison_zero, eng._key, z((s, p), np.int32), z(s, np.int32))),
         "prefill": (eng._prefill_fn, (
             z((1, bucket), np.int32), z(p, np.int32), to_tensor(np.int32(bucket)),
-            to_tensor(np.float32(0.0)), eng._key, z(1, np.int32))),
+            to_tensor(np.float32(0.0)), eng._key, z(1, np.int32),
+            z((s, 1), np.int32), to_tensor(np.int32(0)))),
     }
 
 

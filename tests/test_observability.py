@@ -311,6 +311,28 @@ def test_profiler_reset_zeroes_every_family():
     assert snap["flash_fallbacks"] == {}
 
 
+def test_serving_summary_names_membership_changes_and_drains_by_cause():
+    """The two fields by which a benchmark's counters say whether a change
+    of membership cost the device a drain: every cause rendered, at 0 too,
+    and both zeroed by reset_serving()."""
+    causes = ("eos_watch", "length", "admission", "first_token", "evict",
+              "rebuild", "stop", "poison")
+    prof.reset_serving()
+    s = prof.serving_summary()
+    assert s["membership_changes"] == 0
+    assert s["drains"] == dict.fromkeys(causes, 0)
+    prof.record_membership_change()
+    prof.record_membership_change()
+    prof.record_serving_drain("evict")
+    s = prof.serving_summary()
+    assert s["membership_changes"] == 2
+    assert s["drains"] == dict(dict.fromkeys(causes, 0), evict=1)
+    assert prof.metrics_snapshot()["serving"]["drains"] == {"evict": 1}
+    prof.reset_serving()
+    s = prof.serving_summary()
+    assert (s["membership_changes"], sum(s["drains"].values())) == (0, 0)
+
+
 # ---------------------------------------------------------------------------
 # flight recorder: fault-event mirror, watchdog gauge, dump format
 # ---------------------------------------------------------------------------

@@ -216,32 +216,178 @@ def test_streaming_token_callbacks(model):
     assert stream == out[-5:].tolist()  # streamed in generation order
 
 
+def _drains():
+    return paddle.profiler.serving_summary()["drains"]
+
+
 def test_streaming_runs_one_step_behind_the_device(model):
     """A streaming callback gets step N's token while step N + 1 is in
-    flight: one step stays unfetched between ticks, the tokens and their
-    order are those of a request nobody streams, a second request admitted
-    midway starts from whole host mirrors, and an EOS watch, which decides
-    membership, is fetched in its own tick."""
+    flight, and a change of membership does not end that: a request admitted
+    midway is prefilled BEHIND the step in flight with nothing fetched first,
+    its first token comes with the tick's flush and before any of its decode
+    tokens, a slot at its length bound leaves by count and finishes a tick
+    later, and the tokens and their order are those of a request nobody
+    streams.  An EOS watch, whose values decide membership, is still fetched
+    in its own tick."""
     p, q = _prompt(5, seed=8), _prompt(6, seed=9)
     want_p = _engine(model).generate(p, max_new_tokens=9)[-9:].tolist()
     want_q = _engine(model).generate(q, max_new_tokens=4)[-4:].tolist()
     eng = _engine(model)
-    sp, sq = [], []
-    r = eng.submit(p, max_new_tokens=9, on_token=sp.append)
-    eng.step()  # the prefill's token, then the first decode step, dispatched and kept
-    assert (len(sp), len(eng._pending_fetch)) == (1, 1)
+    paddle.profiler.reset_serving()
+    order = []
+    r = eng.submit(p, max_new_tokens=9, on_token=lambda t: order.append(("p", t)))
+    eng.step()  # the prefill and one decode step behind it: the first token, the step kept
+    assert (len(order), len(eng._pending_fetch)) == (1, 1)
     eng.step()
-    assert (len(sp), len(eng._pending_fetch)) == (2, 1)
-    r2 = eng.submit(q, max_new_tokens=4, on_token=sq.append)
-    eng.step()  # the admission fetches what was in flight before it prefills
-    assert sp == want_p[:len(sp)] and len(sp) >= 3 and sq == want_q[:1]
+    assert (len(order), len(eng._pending_fetch)) == (2, 1)
+    r2 = eng.submit(q, max_new_tokens=4, on_token=lambda t: order.append(("q", t)))
+    eng.step()  # q's prefill goes in behind p's step in flight; q's first token after p's third
+    assert order[2:] == [("p", want_p[2]), ("q", want_q[0])]
+    assert len(eng._pending_fetch) == 1 and r2.ttft_s is not None
+    ticks = 3
+    while not r2.finished.is_set():
+        eng.step()
+        ticks += 1
+    # q's fourth token is its third decode step's, delivered a tick after
+    # the step was dispatched: the slot left the mask by count meanwhile
+    assert ticks == 3 + 3 and not r.finished.is_set()
     eng.run_until_idle()
-    assert (sp, sq) == (want_p, want_q)
-    assert (list(r.tokens), list(r2.tokens)) == (want_p, want_q)
+    assert [t for w, t in order if w == "p"] == want_p == list(r.tokens)
+    assert [t for w, t in order if w == "q"] == want_q == list(r2.tokens)
     assert not eng._pending_fetch
-    r3 = eng.submit(p, max_new_tokens=9, eos_token_id=-1, on_token=sp.append)
+    s = paddle.profiler.serving_summary()
+    assert s["membership_changes"] == 4
+    assert not any(s["drains"].values())  # nothing drained the device
+    r3 = eng.submit(p, max_new_tokens=9, eos_token_id=-1, on_token=lambda t: None)
     eng.step()
     assert len(r3.tokens) == 2 and not eng._pending_fetch
+    assert _drains()["eos_watch"] == 1
+
+
+_CHURN = [  # (prompt length, max_new_tokens): 20 is longer than the largest bucket
+    (5, 7), (9, 3), (20, 5), (4, 1), (12, 9), (7, 2), (15, 6), (6, 4),
+]
+
+
+@pytest.mark.parametrize("stream,eos", [(True, None), (False, None), (True, -1)],
+                         ids=["streamed", "unstreamed", "eos-watch"])
+def test_membership_churn_keeps_tokens_on_the_device(model, stream, eos):
+    """3 slots, 8 requests of staggered lengths: slots end and are reseated
+    while others decode, one prompt goes in chunks, one request is its
+    prefill's token alone.  Every request's tokens (and its callbacks, in
+    order) are lock-step generate's, nothing compiles, and neither a finish,
+    an admission nor a first token drains the device; a batch that watches
+    for EOS is fetched every tick, and says so."""
+    prompts = [_prompt(n, seed=60 + i) for i, (n, _) in enumerate(_CHURN)]
+    want = [
+        model.generate(paddle.to_tensor(p[None]), max_new_tokens=n).numpy()[0, -n:].tolist()
+        for p, (_, n) in zip(prompts, _CHURN)
+    ]
+    eng = _engine(model).warmup()
+    warm = eng.compile_counts()
+    paddle.profiler.reset_serving()
+    streams = [[] for _ in _CHURN]
+    reqs = [
+        eng.submit(p, max_new_tokens=n, eos_token_id=eos,
+                   on_token=streams[i].append if stream else None)
+        for i, (p, (_, n)) in enumerate(zip(prompts, _CHURN))
+    ]
+    depth = 0
+    while eng.has_work():
+        eng.step()
+        depth = max(depth, len(eng._pending_fetch))
+    assert [list(r.tokens) for r in reqs] == want
+    assert all(r.finish_reason == "length" for r in reqs)
+    if stream:
+        assert streams == want
+    assert eng.compile_counts() == warm
+    assert not eng._pending_fetch
+    s = paddle.profiler.serving_summary()
+    assert s["requests"] == 8 and s["membership_changes"] == 16
+    d = s["drains"]
+    assert (d["length"], d["admission"], d["first_token"]) == (0, 0, 0)
+    if eos is None:
+        assert not any(d.values())
+        # unstreamed, a step is fetched when a slot's last token or a first
+        # token is behind it: never deeper than the longest answer
+        assert depth <= (2 if stream else max(n for _, n in _CHURN))
+    else:
+        assert d["eos_watch"] > 0 and depth == 0
+    # a slot that sat out a step between its last and its finish wrote
+    # nothing into its own pages: its prompt's, committed to the prefix
+    # cache, serve the same prompt again to the same tokens
+    kept = [i for i, p in enumerate(prompts) if eng._prefix.lookup(p)[0] >= 8]
+    assert kept  # the pool is 3 pages: the last to finish are still cached
+    hits = paddle.profiler.paging_summary()["prefix_hits"]
+    for i in kept:
+        again = eng.submit(prompts[i], max_new_tokens=_CHURN[i][1])
+        eng.run_until_idle()
+        assert list(again.tokens) == want[i]
+    assert paddle.profiler.paging_summary()["prefix_hits"] == hits + len(kept)
+
+
+def test_a_late_entry_of_a_departed_request_never_reaches_the_tenant(model):
+    """An unfetched step belongs to the (slot, request) it was dispatched
+    for.  A request leaves its slot a step late (here a logit window found
+    non-finite when the step is fetched, one tick on) while a further step of
+    its is in flight; the slot is reseated before that step is fetched.  Its
+    token goes to nobody: the predecessor keeps what it had when it erred,
+    the tenant's tokens are generate's.  And on the plain path, one slot and
+    two requests back to back: the first gets its last token, the second
+    every one of its own."""
+    pa, pb = _prompt(5, seed=21), _prompt(7, seed=22)
+    want_a = _engine(model).generate(pa, max_new_tokens=6)[-6:].tolist()
+    want_b = _engine(model).generate(pb, max_new_tokens=5)[-5:].tolist()
+    eng = _engine(model, slots=1)
+    sa, sb = [], []
+    ra = eng.submit(pa, max_new_tokens=6, on_token=sa.append)
+    rb = eng.submit(pb, max_new_tokens=5, on_token=sb.append)
+    eng.run_until_idle()
+    assert (sa, sb) == (want_a, want_b) and ra.finish_reason == rb.finish_reason == "length"
+
+    eng = _engine(model, slots=1)
+    real, calls = eng._decode_fn, []
+
+    def decode(*a):
+        out = list(real(*a))
+        calls.append(len(calls))
+        if len(calls) == 2:  # the second decode step: slot 0's window reads non-finite
+            out[2] = paddle.to_tensor(np.zeros(eng.slots, bool))
+        return tuple(out)
+
+    eng._decode_fn = decode
+    sa, sb = [], []
+    ra = eng.submit(pa, max_new_tokens=6, on_token=sa.append)
+    for _ in range(3):  # steps 1-3 dispatched; step 2 fetched, and a erred by it
+        eng.step()
+    assert ra.finish_reason == "error" and sa == want_a[:2]
+    assert [r.id for e in eng._pending_fetch for _, r in e.pairs] == [ra.id]
+    rb = eng.submit(pb, max_new_tokens=5, on_token=sb.append)
+    eng.run_until_idle()
+    assert sa == want_a[:2] == list(ra.tokens)
+    assert sb == want_b == list(rb.tokens) and rb.finish_reason == "length"
+    assert not eng._pending_fetch
+
+
+def test_a_slot_that_sits_out_a_step_writes_nothing_into_its_pages(model):
+    """Between a slot's last step and its finish, a tick later, the others
+    run a step in which it is inactive: that step must write to the scratch
+    page and not through the slot's table row, whose first page holds the
+    prompt the prefix cache has committed.  The same prompt served again
+    from those pages gives the same tokens."""
+    pa, pb = _prompt(12, seed=31), _prompt(6, seed=32)
+    want_a = _engine(model).generate(pa, max_new_tokens=3)[-3:].tolist()
+    eng = _engine(model, slots=2)
+    ra = eng.submit(pa, max_new_tokens=3, on_token=lambda t: None)
+    rb = eng.submit(pb, max_new_tokens=12, on_token=lambda t: None)
+    while not ra.finished.is_set():
+        eng.step()
+    assert not rb.finished.is_set() and list(ra.tokens) == want_a
+    hits = paddle.profiler.paging_summary().get("prefix_hits", 0)
+    again = eng.submit(pa, max_new_tokens=3)
+    eng.run_until_idle()
+    assert paddle.profiler.paging_summary()["prefix_hits"] == hits + 1
+    assert list(again.tokens) == want_a
 
 
 def test_the_engine_takes_no_paged_argument(model):

@@ -6,7 +6,10 @@ token to last together: one long generation holds the whole batch hostage,
 and a new request waits for the batch to drain.  This engine instead keeps
 `slots` sequences in flight over ONE block-paged KV ARENA per layer
 (`[num_pages, kv_heads, page_size, width]` per row kind the model declares
-through `cache_rows()`) and runs ONE compiled decode step whatever the
+through `cache_rows()`; a model whose layers differ declares each layer's
+rows and its fixed STATE PER SLOT, `[slots, ...]` buffers beside the arenas,
+through `cache_layers()`: a layer without rows has no arena, and a model with
+state has no prefix cache) and runs ONE compiled decode step whatever the
 occupancy: per-slot `pos`, `active` masks and the page tables
 (`[slots, max_pages_per_seq]` int32) are DATA, never shapes, so requests
 joining, finishing, and slots being recycled cause zero recompiles after
@@ -469,6 +472,13 @@ class ContinuousBatchingEngine:
         # fused kernel's limits speak of the first kind's geometry (K's)
         rows = list(model.cache_rows())
         _, kv_heads, head_dim, cache_dtype = rows[0]
+        # per layer (rows, state): a model whose layers differ declares them
+        # with `cache_layers()`; state is a slot's, [(name, shape, dtype)]
+        layers = (
+            [(list(r), list(st)) for r, st in model.cache_layers()]
+            if hasattr(model, "cache_layers")
+            else [(rows, [])] * cfg.num_hidden_layers
+        )
         # quantized KV serving (ISSUE 18): validated HERE — typed
         # QuantConfigError at construction, never a dtype mismatch inside a
         # compiled step — and folded into every cache-key surface: the
@@ -572,11 +582,14 @@ class ContinuousBatchingEngine:
             pp = max(pp, 2 * self.cp)
             pp = -(-pp // self.cp) * self.cp
         self.pool_pages = int(pp)
+        # one cache object a layer: an arena for each kind of rows it
+        # declares (none: no arena), a [slots, ...] buffer for each state
         self._arenas = [
-            PagedKVCache(self.pool_pages, self.page_size, rows=rows,
-                         quant=self.kv_quant)
-            for _ in range(cfg.num_hidden_layers)
+            PagedKVCache(self.pool_pages, self.page_size, rows=r,
+                         quant=self.kv_quant, state=st, slots=self.slots)
+            for r, st in layers
         ]
+        self._has_state = any(st for _, st in layers)
         if self.tp > 1 or self.cp > 1:
             for a in self._arenas:
                 shard_kv_for_tp(a)
@@ -587,23 +600,29 @@ class ContinuousBatchingEngine:
             2 * self.page_size * kv_heads * 4
             if self.kv_quant == "int8" else 0
         )
-        # bytes per row kind, all layers, as the buffers hold them
-        by_kind = {
-            n: cfg.num_hidden_layers * int(np.prod(getattr(self._arenas[0], n).shape))
-            * int(np.dtype(getattr(self._arenas[0], n)._data.dtype).itemsize)
-            for n in self._arenas[0].row_names
-        }
+        # bytes per kind (a token's rows, a slot's state), over the layers
+        # that hold it, as the buffers hold them
+        by_kind, row_bytes = {}, 0
+        for a in self._arenas:
+            for n in a.row_names + a.state_names:
+                t = getattr(a, n)
+                nb = int(np.prod(t.shape)) * int(np.dtype(t._data.dtype).itemsize)
+                by_kind[n] = by_kind.get(n, 0) + nb
+                row_bytes += nb if n in a.row_names else 0
         _prof.record_kv_quant(
             mode=self.kv_quant,
-            arena_bytes=sum(by_kind.values()),
+            arena_bytes=row_bytes,
             scale_bytes=cfg.num_hidden_layers * self.pool_pages * scale_b,
         )
         _prof.record_arena_bytes(by_kind)
         self._pool = PagePool(self.pool_pages, shards=self.cp)
+        # a hit resumes from pages alone, which a model with state per slot
+        # cannot: asked for, it is refused; left to the flag, it is off
+        refuse("prefix_cache", bool(prefix_cache), f"prefix_cache={prefix_cache!r}")
         use_prefix = bool(
             _fcore.flag("FLAGS_serve_prefix_cache")
             if prefix_cache is None else prefix_cache
-        )
+        ) and "prefix_cache" not in unsupported
         self._prefix = PrefixCache(self.page_size) if use_prefix else None
         # session KV (ISSUE 20): named multi-turn holds on prefix-cache
         # chains.  Rides the prefix cache — without it, session_id still
@@ -773,7 +792,8 @@ class ContinuousBatchingEngine:
             lambda p, a: jnp.where(a, p, 0), [pos, active], name="serve_pos_mask"
         )
         views = [
-            PagedDecodeView(a, tables, self.max_len, kernel=self.decode_kernel)
+            PagedDecodeView(a, tables, self.max_len, kernel=self.decode_kernel,
+                            live=active)
             for a in self._arenas
         ]
         lora = self._lora.view(adapters) if self._lora is not None else None
@@ -835,7 +855,8 @@ class ContinuousBatchingEngine:
             lambda p, a: jnp.where(a, p, 0), [pos, active], name="serve_pos_mask"
         )
         views = [
-            PagedDecodeView(a, tables, self.max_len, kernel=self.decode_kernel)
+            PagedDecodeView(a, tables, self.max_len, kernel=self.decode_kernel,
+                            live=active)
             for a in self._arenas
         ]
         lora = self._lora.view(adapters) if self._lora is not None else None
@@ -890,7 +911,7 @@ class ContinuousBatchingEngine:
 
         views = [
             PagedPrefillView(a, row_table, true_len, self.max_len,
-                             kernel=self.decode_kernel)
+                             kernel=self.decode_kernel, slot=slot)
             for a in self._arenas
         ]
         lora = self._lora.view(adapters) if self._lora is not None else None
@@ -920,7 +941,7 @@ class ContinuousBatchingEngine:
 
         views = [
             PagedPrefillView(a, row_table, true_len, self.max_len, start=start,
-                             kernel=self.decode_kernel)
+                             kernel=self.decode_kernel, slot=slot)
             for a in self._arenas
         ]
         lora = self._lora.view(adapters) if self._lora is not None else None
@@ -2227,6 +2248,8 @@ class ContinuousBatchingEngine:
                     toks[0, :n] = req.prompt[offset:offset + n]
                     t_ch = time.perf_counter()
                     self._check_gen(gen)  # between chunks too: a restart owns the pages
+                    if self._has_state:
+                        _prof.record_linear_attn_prefill(n, resumed=offset != 0)
                     # only the last chunk's token is the request's first: of
                     # an earlier chunk's vector nothing is kept
                     if offset == 0:

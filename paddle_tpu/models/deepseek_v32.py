@@ -317,6 +317,54 @@ def _select_mask(score, k):
         lambda: above | (tie & (jnp.cumsum(tie, axis=1, dtype=jnp.int32) <= room)))
 
 
+def _attend_expanded(cfg, w_ukv, q_nope, q_pe, ctx_lat, n_blocks, kb, qb, scale, mask_of):
+    """Attention of s queries (q_nope [s, H, dn], q_pe [s, H, dr]) over one
+    sequence's latent rows `ctx_lat` [L, latent_width], K and V expanded from
+    them a block of `kb` keys at a time, online softmax, `qb` queries to a
+    score tile.  `mask_of(j)` -> bool [s, kb]: which keys of block j each
+    query may see.  The first `n_blocks` (data) blocks are visited.
+    Returns [s, H * dv] in q's dtype."""
+    import jax.numpy as jnp
+    from jax import lax
+
+    s = q_nope.shape[0]
+    H, dn, dv, c = cfg.num_attention_heads, cfg.qk_nope_head_dim, cfg.v_head_dim, cfg.kv_lora_rank
+    dr = cfg.qk_rope_head_dim
+    # one matmul a score tile: [q_nope | q_pe] against [k_nope | k_pe for every head].  The
+    # loop is bound by the tiles' trips through memory, and two matmuls wrote two
+    q_full = jnp.concatenate([q_nope, q_pe], -1)
+
+    def attend_block(j, carry):
+        m, l, acc = carry
+        rows = lax.dynamic_slice_in_dim(ctx_lat, j * kb, kb, 0)
+        kv = (rows[:, :c] @ w_ukv).reshape(kb, H, dn + dv)
+        v = kv[..., dn:]
+        k_full = jnp.concatenate(
+            [kv[..., :dn], jnp.broadcast_to(rows[:, None, c:c + dr], (kb, H, dr))], -1)
+        mask = mask_of(j)
+        ms, ls, accs = [], [], []
+        for i in range(0, s, qb):
+            lg = jnp.einsum("thd,lhd->thl", q_full[i:i + qb], k_full,
+                            preferred_element_type=jnp.float32)
+            lg = jnp.where(mask[i:i + qb, None, :], lg * scale, -jnp.inf)
+            m_new = jnp.maximum(m[i:i + qb], jnp.max(lg, axis=-1))
+            m_safe = jnp.where(m_new == -jnp.inf, 0.0, m_new)  # no key chosen yet
+            p = jnp.exp(lg - m_safe[..., None])
+            fade = jnp.exp(jnp.where(m[i:i + qb] == -jnp.inf, -jnp.inf, m[i:i + qb] - m_safe))
+            ms.append(m_new)
+            ls.append(l[i:i + qb] * fade + jnp.sum(p, axis=-1))
+            accs.append(acc[i:i + qb] * fade[..., None]
+                        + jnp.einsum("thl,lhd->thd", p.astype(v.dtype), v,
+                                     preferred_element_type=jnp.float32))
+        return jnp.concatenate(ms, 0), jnp.concatenate(ls, 0), jnp.concatenate(accs, 0)
+
+    m0 = jnp.full((s, H), -jnp.inf, jnp.float32)
+    _, l, acc = lax.fori_loop(
+        0, n_blocks, attend_block,
+        (m0, jnp.zeros((s, H), jnp.float32), jnp.zeros((s, H, dv), jnp.float32)))
+    return (acc / jnp.maximum(l, 1e-30)[..., None]).astype(q_nope.dtype).reshape(s, H * dv)
+
+
 def _prefill_attention(cfg, w, x, cos, sin, lat, idx_arena, table, start, true_len):
     """A chunk of one sequence: x [s, hidden] at positions start .. start + s.
     Stores the chunk's rows, then attends the sequence through the page
@@ -358,40 +406,8 @@ def _prefill_attention(cfg, w, x, cos, sin, lat, idx_arena, table, start, true_l
 
     score = lax.fori_loop(0, n_blocks, score_block, jnp.full((s, L), -jnp.inf, jnp.float32))
     chosen = _select_mask(score, cfg.index_topk)
-    w_ukv = w["kv_b_proj"]
-    # one matmul a score tile: [q_nope | q_pe] against [k_nope | k_pe for every head].  The
-    # loop is bound by the tiles' trips through memory, and two matmuls wrote two
-    q_full = jnp.concatenate([q_nope, q_pe], -1)
-
-    def attend_block(j, carry):
-        m, l, acc = carry
-        rows = lax.dynamic_slice_in_dim(ctx_lat, j * kb, kb, 0)
-        kv = (rows[:, :c] @ w_ukv).reshape(kb, H, dn + dv)
-        v = kv[..., dn:]
-        k_full = jnp.concatenate(
-            [kv[..., :dn], jnp.broadcast_to(rows[:, None, c:c + dr], (kb, H, dr))], -1)
-        mask = lax.dynamic_slice_in_dim(chosen, j * kb, kb, 1)
-        ms, ls, accs = [], [], []
-        for i in range(0, s, qb):
-            lg = jnp.einsum("thd,lhd->thl", q_full[i:i + qb], k_full,
-                            preferred_element_type=jnp.float32)
-            lg = jnp.where(mask[i:i + qb, None, :], lg * scale, -jnp.inf)
-            m_new = jnp.maximum(m[i:i + qb], jnp.max(lg, axis=-1))
-            m_safe = jnp.where(m_new == -jnp.inf, 0.0, m_new)  # no key chosen yet
-            p = jnp.exp(lg - m_safe[..., None])
-            fade = jnp.exp(jnp.where(m[i:i + qb] == -jnp.inf, -jnp.inf, m[i:i + qb] - m_safe))
-            ms.append(m_new)
-            ls.append(l[i:i + qb] * fade + jnp.sum(p, axis=-1))
-            accs.append(acc[i:i + qb] * fade[..., None]
-                        + jnp.einsum("thl,lhd->thd", p.astype(v.dtype), v,
-                                     preferred_element_type=jnp.float32))
-        return jnp.concatenate(ms, 0), jnp.concatenate(ls, 0), jnp.concatenate(accs, 0)
-
-    m0 = jnp.full((s, H), -jnp.inf, jnp.float32)
-    _, l, acc = lax.fori_loop(
-        0, n_blocks, attend_block,
-        (m0, jnp.zeros((s, H), jnp.float32), jnp.zeros((s, H, dv), jnp.float32)))
-    o = (acc / jnp.maximum(l, 1e-30)[..., None]).astype(x.dtype).reshape(s, H * dv)
+    o = _attend_expanded(cfg, w["kv_b_proj"], q_nope, q_pe, ctx_lat, n_blocks, kb, qb, scale,
+                         lambda j: lax.dynamic_slice_in_dim(chosen, j * kb, kb, 1))
     return o @ w["o_proj"], lat, idx_arena
 
 
@@ -455,6 +471,13 @@ def _routed_experts(cfg, x, experts, wts, live, w1, w3, w2):
     the number of blocks in use (data) runs one expert's SwiGLU per block
     and adds the weighted rows back to their tokens.  An expert nobody picked
     costs nothing, no pick is ever left out, and there is no capacity.
+
+    A step of at most B tokens whose picks number the router's width or more
+    (`T * K >= n_routed_experts`: every held expert expects a token or more,
+    which is a deployment's decode batch) takes `_all_held_experts` instead:
+    nearly every expert would be a block of its own, each costing the loop's
+    fixed work on top of the expert's bytes, and the step's time would follow
+    which experts the seed's router happens to hit (PERF.md, PR 33).
     Returns (y [T, hidden] f32, [tokens, picks held, experts hit, max load])."""
     import jax
     import jax.numpy as jnp
@@ -466,9 +489,13 @@ def _routed_experts(cfg, x, experts, wts, live, w1, w3, w2):
     local = experts - cfg.expert_offset
     mine = (local >= 0) & (local < held) & live[:, None]
     key = jnp.where(mine, local, held).reshape(-1)
-    order = jnp.argsort(key, stable=True).astype(jnp.int32)
     counts = jnp.sum(key[:, None] == jnp.arange(held, dtype=jnp.int32)[None, :], axis=0,
                      dtype=jnp.int32)
+    stats = jnp.stack([jnp.sum(live, dtype=jnp.int32), jnp.sum(counts), jnp.sum(counts > 0, dtype=jnp.int32),
+                       jnp.max(counts)])
+    if T <= B and T * K >= cfg.n_routed_experts:
+        return _all_held_experts(x, jnp.where(mine, local, held), wts, w1, w3, w2), stats
+    order = jnp.argsort(key, stable=True).astype(jnp.int32)
     blocks = (counts + B - 1) // B
     blk_end = jnp.cumsum(blocks)
     first_pick = jnp.cumsum(counts) - counts
@@ -486,10 +513,25 @@ def _routed_experts(cfg, x, experts, wts, live, w1, w3, w2):
                       lax.dynamic_index_in_dim(w2, e, 0, False))
         return y.at[tok].add(out.astype(jnp.float32) * wt[:, None])
 
-    y = lax.fori_loop(0, blk_end[-1], body, jnp.zeros(x.shape, jnp.float32))
-    stats = jnp.stack([jnp.sum(live, dtype=jnp.int32), jnp.sum(counts), jnp.sum(counts > 0, dtype=jnp.int32),
-                       jnp.max(counts)])
-    return y, stats
+    return lax.fori_loop(0, blk_end[-1], body, jnp.zeros(x.shape, jnp.float32)), stats
+
+
+def _all_held_experts(x, local, wts, w1, w3, w2):
+    """Every held expert's SwiGLU over every token of a small step, weighted
+    by the router, 0 where the token did not pick the expert (`local` [T, K]:
+    a pick's index among the held experts, `held` where it is not one):
+    the same sum as the loop's, each expert's output rounded as there, in
+    three batched matmuls that stream every held expert once."""
+    import jax
+    import jax.numpy as jnp
+
+    T, held = x.shape[0], w1.shape[0]
+    weight = jnp.zeros((T, held), jnp.float32).at[jnp.arange(T)[:, None], local].add(wts, mode="drop")
+    xb = jnp.broadcast_to(x[None], (held,) + x.shape)
+    mid = jax.nn.silu(jnp.einsum("etd,edf->etf", xb, w1)) * jnp.einsum("etd,edf->etf", xb, w3)
+    out = jnp.einsum("etf,efd->etd", mid, w2).astype(jnp.float32)
+    w_et = weight.T[:, :, None]
+    return jnp.sum(jnp.where(w_et > 0, out * w_et, 0.0), axis=0)
 
 
 def _moe(cfg, w, x, live):
@@ -521,8 +563,7 @@ def _dims(cfg):
     d.update(q_out=H * (cfg.qk_nope_head_dim + cfg.qk_rope_head_dim),
              kv_a_out=cfg.kv_lora_rank + cfg.qk_rope_head_dim,
              kv_b_out=H * (cfg.qk_nope_head_dim + cfg.v_head_dim),
-             o_in=H * cfg.v_head_dim, index_q_out=cfg.index_n_heads * cfg.index_head_dim,
-             shared_size=cfg.moe_intermediate_size * cfg.n_shared_experts)
+             o_in=H * cfg.v_head_dim, index_q_out=cfg.index_n_heads * cfg.index_head_dim)
     return d
 
 
@@ -641,7 +682,8 @@ class DeepseekV32MLP(_Leaves):
                             ("down_proj", cfg.intermediate_size, h)):
                 self._matrix(n + ".weight", a, b)
             return
-        held, im, sh = cfg.experts_held, cfg.moe_intermediate_size, _dims(cfg)["shared_size"]
+        held, im = cfg.experts_held, cfg.moe_intermediate_size
+        sh = im * cfg.n_shared_experts
         self._matrix("gate.weight", h, cfg.n_routed_experts)
         self._leaf("gate.e_score_correction_bias", (cfg.n_routed_experts,), I.Constant(0.0), "float32")
         self._matrix("experts.gate_proj", held, h, im)

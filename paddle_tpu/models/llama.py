@@ -186,12 +186,16 @@ class PagedKVCache:
     tile — a trailing unit dim is lane-padded 128x on the TPU."""
 
     def __init__(self, num_pages, page_size, kv_heads=None, head_dim=None,
-                 dtype="float32", quant="none", rows=None):
-        """`rows` is what a served model's `cache_rows()` declares, a token's
-        rows in one layer: [(name, heads, width, dtype)].  Each becomes a
-        buffer `[num_pages, heads, page_size, width]` under its name, all on
-        the one page table.  Without it: a K and a V row of `kv_heads` heads
-        of `head_dim` in `dtype`."""
+                 dtype="float32", quant="none", rows=None, state=(), slots=0):
+        """`rows` is what a served model declares for this layer, a token's
+        rows: [(name, heads, width, dtype)].  Each becomes a buffer
+        `[num_pages, heads, page_size, width]` under its name, all on the
+        one page table; an empty list gives a layer no arena at all.
+        Without it: a K and a V row of `kv_heads` heads of `head_dim` in
+        `dtype`.  `state` is the layer's fixed state per slot, [(name,
+        shape, dtype)]: each a buffer `[slots, *shape]` under its name,
+        zeros, which no page table reaches (a layer of linear attention
+        holds its running state there and no rows per token)."""
         from ..framework import core as _fcore
 
         self.page_size = int(page_size)
@@ -199,6 +203,7 @@ class PagedKVCache:
         if rows is None:
             rows = [("k", kv_heads, head_dim, dtype), ("v", kv_heads, head_dim, dtype)]
         self.row_names = tuple(r[0] for r in rows)
+        self.state_names = tuple(st[0] for st in state)
         self.k_scale = None
         self.v_scale = None
         if self.quant == "int8":
@@ -210,7 +215,9 @@ class PagedKVCache:
         for name, heads, width, dt in rows:
             elem = np.int8 if self.quant == "int8" else _fcore.to_jax_dtype(dt)
             setattr(self, name, Tensor(np.zeros((num_pages, heads, page_size, width), elem)))
-        for t in self.buffers():
+        for name, shape, dt in state:
+            setattr(self, name, Tensor(np.zeros((int(slots),) + tuple(shape), _fcore.to_jax_dtype(dt))))
+        for t in self.buffers() + self.state_buffers():
             t.stop_gradient = True
 
     def buffers(self):
@@ -218,6 +225,10 @@ class PagedKVCache:
         a page copy has to move together."""
         out = [getattr(self, n) for n in self.row_names]
         return out + [t for t in (self.k_scale, self.v_scale) if t is not None]
+
+    def state_buffers(self):
+        """The buffers a SLOT owns a slice of; no page copy moves them."""
+        return [getattr(self, n) for n in self.state_names]
 
 
 class PagedPrefillView:
@@ -229,16 +240,20 @@ class PagedPrefillView:
     unshared suffix at rope offset `start`, attending the shared pages
     through a table gather.  Rows past `true_len` (bucket padding) and rows
     whose page index overruns the table never touch a mapped page
-    (`_kv_store`)."""
+    (`_kv_store`).  `slot` (int32 scalar Tensor, data) is the slot the
+    prompt is seated in, for a layer that keeps state per slot: a fresh
+    prefill starts that slot's state from zero, a chunk resumes from what
+    the chunk before left there."""
 
     def __init__(self, arena, table, true_len, max_len, start=None,
-                 kernel="auto"):
+                 kernel="auto", slot=None):
         self.arena = arena
         self.table = table
         self.true_len = true_len
         self.max_len = max_len
         self.start = start
         self.kernel = kernel  # paged attention dispatch: auto|fused|gather
+        self.slot = slot
 
 
 class PagedDecodeView:
@@ -251,13 +266,16 @@ class PagedDecodeView:
     [slots, k+1] token window — row i writes page entry (pos+i)//page_size
     (overruns redirected to scratch, see `_page_decode_write`) and attends
     positions j <= pos+i through the per-row-pos decode kernel.  Row 0 of
-    a k+1 window is therefore the exact single-token decode step."""
+    a k+1 window is therefore the exact single-token decode step.  `live`
+    ([slots] bool Tensor, data) is the step's mask, for a layer that keeps
+    state per slot: an idle slot's state is left as it was."""
 
-    def __init__(self, arena, tables, max_len, kernel="auto"):
+    def __init__(self, arena, tables, max_len, kernel="auto", live=None):
         self.arena = arena
         self.tables = tables
         self.max_len = max_len
         self.kernel = kernel  # paged attention dispatch: auto|fused|gather
+        self.live = live
 
 
 def _kv_store(arena, rows, tables, start=None, true_len=None):

@@ -619,7 +619,10 @@ def kv_quant_summary():
 
 _moe_gauges = {"steps": 0, "tokens": 0, "picks_held": 0, "experts_hit": 0, "max_load": 0}
 _sparse_attn_gauges = {"rows": 0, "rows_over_topk": 0, "selected": 0, "context": 0}
-_arena_bytes = {}  # row kind -> bytes over all layers, set at engine construction
+_arena_bytes = {}  # cache kind (a token's rows, a slot's state) -> bytes over the layers that hold it, set at engine construction
+# linear-attention layers with a fixed state per slot (ISSUE 33), counted the same way
+_linear_attn_gauges = {"steps": 0, "live_slots": 0, "state_bytes_read": 0, "state_bytes_written": 0,
+                       "prefill_rows": 0, "chunks_resumed": 0}
 
 
 def record_moe_step(tokens, picks_held, experts_hit, max_load):
@@ -647,6 +650,27 @@ def record_sparse_attn_step(rows, rows_over_topk, selected, context):
         g["context"] += int(context)
 
 
+def record_linear_attn_step(live_slots, state_bytes):
+    """One decode step of the layers that hold a state per slot: live slots,
+    and the bytes of state they read and wrote again, all such layers
+    together (an idle slot's state is not touched)."""
+    with _counters_lock:
+        g = _linear_attn_gauges
+        g["steps"] += 1
+        g["live_slots"] += int(live_slots)
+        g["state_bytes_read"] += int(state_bytes)
+        g["state_bytes_written"] += int(state_bytes)
+
+
+def record_linear_attn_prefill(rows, resumed):
+    """One prefill program of such a model: the prompt rows its chunked
+    scans went over, and whether it resumed from the state an earlier chunk
+    of the same prompt left (a fresh prefill starts from zero)."""
+    with _counters_lock:
+        _linear_attn_gauges["prefill_rows"] += int(rows)
+        _linear_attn_gauges["chunks_resumed"] += int(bool(resumed))
+
+
 def record_arena_bytes(by_kind):
     with _counters_lock:
         _arena_bytes.clear()
@@ -654,7 +678,7 @@ def record_arena_bytes(by_kind):
 
 
 def _reset_moe_locked():
-    for g in (_moe_gauges, _sparse_attn_gauges):
+    for g in (_moe_gauges, _sparse_attn_gauges, _linear_attn_gauges):
         for k in g:
             g[k] = 0
 
@@ -685,8 +709,16 @@ def sparse_attn_summary():
     return g
 
 
+def linear_attn_summary():
+    """{} before any counted step or prefill; else the totals."""
+    with _counters_lock:
+        g = dict(_linear_attn_gauges)
+    return g if g["steps"] or g["prefill_rows"] else {}
+
+
 def arena_summary():
-    """Bytes of the paged arena per row kind ({} before an engine is built)."""
+    """Bytes of the engine's cache per kind: a paged arena's row kinds and a
+    slot-state buffer's names alike ({} before an engine is built)."""
     with _counters_lock:
         return dict(_arena_bytes)
 

@@ -56,9 +56,12 @@ of `[q_nope | q_pe]`; `[ckv | k_pe] = x W_dkv`, `ckv = rms(ckv)`; plain rope
 k_nope + q_pe . k_pe) * (qk_nope_head_dim + qk_rope_head_dim)^-0.5` over every
 `s <= t`; the same head-wise sigmoid gate before `W_o` (assumed).  The cache
 holds `[ckv | k_pe]` padded to whole lanes.  Decode absorbs `W_uk` / `W_uv`
-and WALKS the slot's pages (`paged_walk_decode` of `ops/flash_attention.py`,
-the latent arena as K and as V: one KV head, H query rows, the output's first
-`kv_lora_rank` columns kept); prefill expands K and V block by block.
+and WALKS the slot's pages (`paged_walk_decode` of `ops/flash_attention.py`:
+one grid step a slot, the slot's own pages in a loop inside it, 8 pages a
+copy; the latent arena is the keys AND the values, said by `arena_v=None`, so
+a page is copied once and both dots read it: one KV head, H query rows, the
+output's first `kv_lora_rank` columns kept); prefill expands K and V block by
+block.
 
 Feed-forward: a dense SwiGLU in the leading layers, else `_route` /
 `_routed_experts` / `_moe` of `deepseek_v32.py` as they stand.  Not built: the
@@ -374,8 +377,9 @@ def _mla_decode(cfg, w, x, cos, sin, lat, tables, pos, max_len):
     """One token a slot: x [S, hidden], pos [S].  Stores the token's latent
     row, then attends the slot's whole context in the latent space by a WALK
     over its pages with an online softmax: the absorbed query `[q_nope W_uk |
-    q_pe | 0]` against the arena as K and as V, one KV head, H query rows;
-    the output's first `kv_lora_rank` columns are `sum p ckv`."""
+    q_pe | 0]` against the one arena, whose rows are the keys and the values
+    (`arena_v=None`: a page is copied once), one KV head, H query rows; the
+    output's first `kv_lora_rank` columns are `sum p ckv`."""
     import jax.numpy as jnp
 
     from ..ops import flash_attention as fa
@@ -392,7 +396,7 @@ def _mla_decode(cfg, w, x, cos, sin, lat, tables, pos, max_len):
         # straight to the walk: the dispatcher's head_dim <= 256 rule is for K/V
         # heads, and a latent row's 640 columns of ONE head fit VMEM with room
         fa._log_pallas_call("paged_decode_fused")
-        o = fa._fused_paged_decode(q, lat, lat, tables, pos, max_len, _mla_scale(cfg), fa._FORCE_INTERPRET)
+        o = fa._fused_paged_decode(q, lat, None, tables, pos, max_len, _mla_scale(cfg), fa._FORCE_INTERPRET)
     else:
         ctx = fa.paged_gather_kv(lat, tables, max_len)
         o = fa.decode_attention_array(q, ctx, ctx, pos, _mla_scale(cfg))

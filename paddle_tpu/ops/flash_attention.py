@@ -190,18 +190,25 @@ def _pick_bh_block(bh, n_heads, block_q, block_k, d, has_segments):
 
 
 _PAGED_WALK_VMEM_BUDGET = 4 * 1024 * 1024
+# a q block of at most this many rows a KV head (a decode step, a verify
+# window) makes the page walk a matter of copies; more rows (a chunk
+# prefill) make it a matter of compute, a page a grid step as before
+_PAGED_WALK_LOOP_ROWS = 128
+_PAGED_WALK_MAX_PAGES = 8
 
 
 def _pick_kv_heads_block(hk, qr, page_size, d, itemsize):
-    """How many KV heads of a page one grid step of the page walk moves:
+    """How many KV heads of a page one grid step of the page walk takes:
     the largest divisor of the (local) `hk` whose VMEM estimate fits
     `_PAGED_WALK_VMEM_BUDGET`, a quarter of the 16 MiB a kernel may scope.
-    Per head: the K and V page tiles and the q and out blocks, each
-    double-buffered by the pipeline; the f32 accumulator and the m and l
+    Per head: a K and a V page tile and the q and out blocks, each
+    double-buffered; the f32 accumulator and the m and l
     columns (a [qr, 1] f32 column takes whole 128-lane tiles); two live
     [qr, page_size] f32 score tiles.  Decode and a verify window (a few q
-    rows) take every head of the page, one contiguous block of the arena;
-    a chunk prefill (hundreds of q rows a head) gets 1."""
+    rows) take every head of the page, one contiguous block of the arena,
+    and walk the slot's pages in a loop, `_pick_pages_per_step` pages a
+    copy; a chunk prefill (hundreds of q rows a head) gets 1 head and a
+    page a grid step."""
     per_head = (
         2 * 2 * page_size * d * itemsize
         + 2 * 2 * qr * d * itemsize
@@ -213,6 +220,20 @@ def _pick_kv_heads_block(hk, qr, page_size, d, itemsize):
         if hk % hb == 0 and hb * per_head <= _PAGED_WALK_VMEM_BUDGET:
             best = hb
     return best
+
+
+def _pick_pages_per_step(hb, qr, page_size, d, itemsize, kv_operands, n_cols):
+    """How many pages one copy block of the looping page walk holds: as many
+    as keep the blocks (`hb` heads of a page, one buffer per arena, two
+    blocks in flight) inside `_PAGED_WALK_VMEM_BUDGET`, and two live
+    [qr, pages * page_size] f32 score tiles a head inside another; at most
+    `_PAGED_WALK_MAX_PAGES` (more gained nothing on the chip, PR 34) and no
+    more than the table has columns.  Mistral's decode (8 heads of 128 x 128
+    bf16, K and V): 4 pages, 4 MiB.  Ling-3's latent walk (one head of
+    128 x 640 bf16, one arena): 8 pages, 2.5 MiB."""
+    block = 2 * kv_operands * hb * page_size * d * itemsize
+    scores = 2 * hb * qr * page_size * 4
+    return max(1, min(_PAGED_WALK_MAX_PAGES, n_cols, _PAGED_WALK_VMEM_BUDGET // max(block, scores)))
 
 
 def _pallas_flash_forward(q, k, v, causal, scale, segments=None, n_heads=1,
@@ -884,26 +905,38 @@ def _fused_paged_decode_forward(q, arena_k, arena_v, tables, pos, max_len,
     q: [b, sq, h, d] (sq == 1 plain decode, sq == k+1 speculative verify);
     arena_k/v: [num_pages, kv_h, page_size, d] — (page_size, d) minor, so a
     (page, kv head) tile is a whole trailing [page_size, d] block of the
-    array, the K/V block shape the Mosaic lowering accepts for kv_h > 1,
-    and all heads of a page are one contiguous block of HBM; tables:
-    [b, P] int32 page ids (traced DATA — they index the arena inside the
-    BlockSpec index maps, fed as scalar-prefetch so the DMA engine knows
-    each page before its grid step); pos: int32 scalar or [b] per-slot
-    positions.
+    array and all heads of a page are one contiguous block of HBM;
+    `arena_v=None` says the values ARE the keys' rows (MLA's absorbed
+    decode over its latent arena): the page is copied once and both dots
+    read the one buffer; tables: [b, P] int32 page ids (traced DATA, fed as
+    scalar-prefetch); pos: int32 scalar or [b] per-slot positions.
 
-    Grid (slot, kv-head block, page) with the page dim innermost-sequential:
-    one [hb, page_size, d] K tile and one V tile stream through VMEM per
-    step while online softmax (m, l, acc) carries in scratch — the same
-    recurrence as `_flash_fwd_kernel`, the head a batch dim of both dots,
-    walking pages in table order.  The walk is bound by the COUNT of its
-    grid steps, not by their bytes (PR 30, one v5e: 0.2-0.3 us a step
-    whether it moves 64 KB or nothing), so `hb` is as many heads as the
-    static shape leaves VMEM for (`_pick_kv_heads_block`): every kv head of
-    a page for decode and the verify window, one for a chunk prefill, whose
-    q rows fill VMEM.  The table the index map reads is clamped at the
-    slot's newest visible page, (pos + sq - 1) // page_size: steps past it
-    name the block already in VMEM, so the pipeline copies nothing for them
-    whatever the engine's table holds there, and `needed` skips their
+    The walk is bound by the COUNT of its grid steps, not by their bytes
+    (PR 30, one v5e: 0.2-0.3 us a step whether it moves 64 KB or nothing),
+    so a q block of few rows (`_PAGED_WALK_LOOP_ROWS`: decode, the verify
+    window) takes the walk OFF the grid.  The grid is (slot, kv-head block)
+    alone, `hb` as many heads as the static shape leaves VMEM for
+    (`_pick_kv_heads_block`: all of a page's).  The arenas stay in HBM
+    (`pl.ANY`); inside a grid step the kernel loops over the slot's OWN
+    pages, `(pos + sq - 1) // page_size + 1` of them (a traced bound: no
+    step, copy or compute exists for a column past the slot's newest
+    visible page, whatever the table holds there), `pages_per_step`
+    (`_pick_pages_per_step`) at a time by its own `make_async_copy` from
+    `arena.at[page]` into one of two VMEM blocks, the next block in flight
+    while this one is computed, and the next grid step's first block
+    started before this step's last compute, so a slot's start waits for
+    no copy of its own.  Online softmax (m, l, acc) carries in scratch block
+    by block (one dot pair a block: a dot pair a page, each under its own
+    guard, kept the scheduler from overlapping them and read 2.0 ms on the
+    chip where this reads 0.9, PR 34) — the same recurrence as
+    `_flash_fwd_kernel`, the head a batch dim of both dots, walking pages
+    in table order.  The room of a page the last block does not hold is
+    zeroed, not copied.
+
+    A q block of many rows (a chunk prefill, whose rows fill VMEM: `hb` 1)
+    keeps the grid (slot, kv-head block, page), one page tile a step through
+    the BlockSpec pipeline, the table clamped in XLA at the slot's newest
+    visible page so later steps copy nothing and `needed` skips their
     compute.
 
     Each slot's q rows for one kv head pack the whole GQA group x verify
@@ -911,8 +944,9 @@ def _fused_paged_decode_forward(q, arena_k, arena_v, tables, pos, max_len,
     r % sq), so the un-duplicated cache tile is read ONCE per group.
     In-kernel masks reproduce the gather path bit-for-bit: `jid <= pos + w`
     is the per-row causal/validity fence (also inert for inactive slots
-    parked on scratch page 0 at pos 0) and `jid < max_len` reproduces the
-    gather's `[:max_len]` slice of the trailing page's slack rows.
+    parked on scratch page 0 at pos 0, which walk exactly that page) and
+    `jid < max_len` reproduces the gather's `[:max_len]` slice of the
+    trailing page's slack rows.
 
     Returns [b, sq, h, d]."""
     from jax.experimental import pallas as pl
@@ -923,92 +957,177 @@ def _fused_paged_decode_forward(q, arena_k, arena_v, tables, pos, max_len,
     b, sq, h, d = q.shape
     hk = arena_k.shape[1]
     ps = arena_k.shape[2]
+    itemsize = arena_k.dtype.itemsize
     rep = h // hk
     P = tables.shape[1]
     R = rep * sq
     qr = -(-R // 8) * 8  # f32 sublane tile; pad rows are sliced off
-    hb = _pick_kv_heads_block(hk, qr, ps, d, arena_k.dtype.itemsize)
+    hb = _pick_kv_heads_block(hk, qr, ps, d, itemsize)
+    looped = qr <= _PAGED_WALK_LOOP_ROWS
+    if arena_v is None and not looped:
+        arena_v = arena_k  # the pipeline of the grid brings a tile an operand
+    arenas = (arena_k,) if arena_v is None else (arena_k, arena_v)
+    pp = _pick_pages_per_step(hb, qr, ps, d, itemsize, len(arenas), P) if looped else 1
     _prof.record_paged_walk(
-        b=b, sq=sq, heads_per_step=hb, grid_steps=b * (hk // hb) * P,
-        kv_bytes_per_step=2 * hb * ps * d * arena_k.dtype.itemsize,
+        b=b, sq=sq, heads_per_step=hb,
+        grid_steps=b * (hk // hb) * (1 if looped else P),
+        kv_bytes_per_step=len(arenas) * pp * hb * ps * d * itemsize,
+        pages_per_step=pp, kv_operands=len(arenas),
     )
     qt = jnp.transpose(q, (0, 2, 1, 3)).reshape(b, hk, rep, sq, d)
     qg = qt.reshape(b, hk, R, d)
     if qr != R:
         qg = jnp.pad(qg, ((0, 0), (0, 0), (0, qr - R), (0, 0)))
     pos_v = jnp.broadcast_to(jnp.asarray(pos, jnp.int32).reshape(-1), (b,))
-    # columns past a slot's newest visible page repeat that page's entry.
-    # Clamped here and not in the index map: there the divide and the min
-    # run for every grid step and operand (PR 30, on the chip: 0.267 ms a
-    # call against 0.248 with this, 0.246 with no clamp at all)
-    col = jnp.minimum(jnp.arange(P, dtype=jnp.int32), ((pos_v + sq - 1) // ps)[:, None])
-    tab = jnp.take_along_axis(jnp.asarray(tables, jnp.int32), col, axis=1).reshape(-1)
+    tab = jnp.asarray(tables, jnp.int32)
 
-    def kernel(t_ref, p_ref, q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr):
+    def _init(m_scr, l_scr, acc_scr):
+        m_scr[...] = jnp.full_like(m_scr, _NEG_INF)
+        l_scr[...] = jnp.zeros_like(l_scr)
+        acc_scr[...] = jnp.zeros_like(acc_scr)
+
+    def _compute(qb, kb, vb, first_row, p0, m_scr, l_scr, acc_scr):
+        """qb [hb, qr, d] against kb and vb [hb, n, d], the slot's rows
+        `first_row ..`: a page, or a block of pages in table order."""
+        n = kb.shape[1]
+        s = jax.lax.dot_general(
+            qb, kb, (((2,), (2,)), ((0,), (0,))),
+            preferred_element_type=jnp.float32,
+        ) * scale  # [hb, qr, n]
+        w = jax.lax.broadcasted_iota(jnp.int32, (qr, n), 0) % sq if sq > 1 else 0
+        jid = first_row + jax.lax.broadcasted_iota(jnp.int32, (qr, n), 1)
+        s = jnp.where((jid <= p0 + w) & (jid < max_len), s, _NEG_INF)
+        m = m_scr[...]  # [hb, qr, 1]
+        m_new = jnp.maximum(m, s.max(-1, keepdims=True))
+        p = jnp.exp(s - m_new)
+        alpha = jnp.exp(m - m_new)
+        m_scr[...] = m_new
+        l_scr[...] = alpha * l_scr[...] + p.sum(-1, keepdims=True)
+        acc_scr[...] = acc_scr[...] * alpha + jax.lax.dot_general(
+            p.astype(vb.dtype), vb, (((2,), (1,)), ((0,), (0,))),
+            preferred_element_type=jnp.float32,
+        )
+
+    def _finish(o_ref, l_scr, acc_scr):
+        l_safe = jnp.maximum(l_scr[...], 1e-30)
+        o_ref[...] = (acc_scr[...] / l_safe).astype(o_ref.dtype)
+
+    def grid_kernel(t_ref, p_ref, q_ref, k_ref, v_ref, o_ref, *scr):
         j = pl.program_id(2)
-        n_p = pl.num_programs(2)
         p0 = p_ref[pl.program_id(0)]
-
-        @pl.when(j == 0)
-        def _init():
-            m_scr[...] = jnp.full_like(m_scr, _NEG_INF)
-            l_scr[...] = jnp.zeros_like(l_scr)
-            acc_scr[...] = jnp.zeros_like(acc_scr)
-
+        pl.when(j == 0)(lambda: _init(*scr))
         # pages entirely beyond the newest visible position (window row
         # sq-1 sees up to pos + sq - 1) contribute nothing
-        needed = j * ps <= p0 + sq - 1
+        pl.when(j * ps <= p0 + sq - 1)(
+            lambda: _compute(q_ref[...], k_ref[...], v_ref[...], j * ps, p0, *scr))
+        pl.when(j == pl.num_programs(2) - 1)(lambda: _finish(o_ref, *scr[1:]))
 
-        @pl.when(needed)
-        def _compute():
-            qb = q_ref[...]  # [hb, qr, d]
-            kb = k_ref[...]  # [hb, ps, d] — the page this table entry names
-            vb = v_ref[...]
-            s = jax.lax.dot_general(
-                qb, kb, (((2,), (2,)), ((0,), (0,))),
-                preferred_element_type=jnp.float32,
-            ) * scale  # [hb, qr, ps]
-            w = jax.lax.broadcasted_iota(jnp.int32, (qr, ps), 0) % sq
-            jid = j * ps + jax.lax.broadcasted_iota(jnp.int32, (qr, ps), 1)
-            s = jnp.where((jid <= p0 + w) & (jid < max_len), s, _NEG_INF)
-            m = m_scr[...]  # [hb, qr, 1]
-            m_new = jnp.maximum(m, s.max(-1, keepdims=True))
-            p = jnp.exp(s - m_new)
-            alpha = jnp.exp(m - m_new)
-            m_scr[...] = m_new
-            l_scr[...] = alpha * l_scr[...] + p.sum(-1, keepdims=True)
-            acc_scr[...] = acc_scr[...] * alpha + jax.lax.dot_general(
-                p.astype(vb.dtype), vb, (((2,), (1,)), ((0,), (0,))),
-                preferred_element_type=jnp.float32,
-            )
+    n_groups = hk // hb
 
-        @pl.when(j == n_p - 1)
-        def _finish():
-            l_safe = jnp.maximum(l_scr[...], 1e-30)
-            o_ref[...] = (acc_scr[...] / l_safe).astype(o_ref.dtype)
+    def loop_kernel(t_ref, p_ref, q_ref, *rest):
+        n = len(arenas)  # the arenas, the output, m l acc, a buffer an arena
+        hbm, (o_ref, *scr), bufs, (sem, first) = rest[:n], rest[n:n + 4], rest[n + 4:-2], rest[-2:]
+        slot, g = pl.program_id(0), pl.program_id(1)
 
-    rows = pl.BlockSpec((None, hb, qr, d), lambda s, g, j, t, p: (s, g, 0, 0))
-    page_tile = pl.BlockSpec(
-        (None, hb, ps, d), lambda s, g, j, t, p: (t[s * P + j], g, 0, 0)
-    )
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=(b, hk // hb, P),
-        in_specs=[rows, page_tile, page_tile],
-        out_specs=rows,
-        scratch_shapes=[
-            pltpu.VMEM((hb, qr, 1), jnp.float32),
-            pltpu.VMEM((hb, qr, 1), jnp.float32),
-            pltpu.VMEM((hb, qr, d), jnp.float32),
-        ],
-    )
+        def n_pages(s):  # every slot holds one: an idle one is parked on page 0
+            return jnp.minimum((p_ref[s] + sq - 1) // ps + 1, P)
+
+        def block(s, grp, blk, buf, wait):
+            """Start, or wait for, the copies of block `blk` of slot `s`, head
+            group `grp`, into buffer `buf`: a copy a page the slot holds.
+            Waiting, the room of a page it does not hold (the last block's
+            tail) is zeroed in the values' buffer: what an earlier block or a
+            new kernel left there is multiplied by a weight of 0, and may not
+            be a NaN."""
+            held = n_pages(s)
+            for i in range(pp):
+                rows_i = pl.ds(i * ps, ps)
+
+                def page(i=i, rows_i=rows_i):
+                    src = t_ref[s * P + blk * pp + i]
+                    for a in range(n):
+                        tile = hbm[a].at[src] if hb == hk else hbm[a].at[src, pl.ds(grp * hb, hb)]
+                        copy = pltpu.make_async_copy(
+                            tile, bufs[a].at[buf, :, rows_i], sem.at[a, buf])
+                        copy.wait() if wait else copy.start()
+
+                def no_page(rows_i=rows_i):
+                    bufs[-1][buf, :, rows_i] = jnp.zeros((hb, ps, d), arena_k.dtype)
+
+                pl.when(blk * pp + i < held)(page)
+                if wait and i:  # a block that exists holds its first page
+                    pl.when(blk * pp + i >= held)(no_page)
+
+        @pl.when((slot == 0) & (g == 0))
+        def _first():
+            first[0] = 0
+            block(slot, g, 0, 0, wait=False)
+
+        _init(*scr)
+        p0 = p_ref[slot]
+        n_blocks = (n_pages(slot) + pp - 1) // pp
+        buf0 = first[0]  # where the step before put this step's first block
+        g_next = jnp.where(g == n_groups - 1, 0, g + 1)
+        s_next = jnp.where(g == n_groups - 1, slot + 1, slot)
+
+        def body(blk, carry):
+            buf = (buf0 + blk) % 2
+            pl.when(blk + 1 < n_blocks)(
+                lambda: block(slot, g, blk + 1, 1 - buf, wait=False))
+            # the next grid step's first block, ahead of this step's last compute
+            pl.when((blk + 1 == n_blocks) & (s_next < b))(
+                lambda: block(s_next, g_next, 0, 1 - buf, wait=False))
+            block(slot, g, blk, buf, wait=True)
+            _compute(q_ref[...], bufs[0][buf], bufs[-1][buf], blk * pp * ps, p0, *scr)
+            return carry
+
+        jax.lax.fori_loop(0, n_blocks, body, 0)
+        first[0] = (buf0 + n_blocks) % 2
+        _finish(o_ref, *scr[1:])
+
+    softmax_state = [
+        pltpu.VMEM((hb, qr, 1), jnp.float32),
+        pltpu.VMEM((hb, qr, 1), jnp.float32),
+        pltpu.VMEM((hb, qr, d), jnp.float32),
+    ]
+    if looped:
+        rows = pl.BlockSpec((None, hb, qr, d), lambda s, g, t, p: (s, g, 0, 0))
+        grid_spec = pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,
+            grid=(b, n_groups),
+            in_specs=[rows] + [pl.BlockSpec(memory_space=pl.ANY)] * len(arenas),
+            out_specs=rows,
+            scratch_shapes=softmax_state
+            + [pltpu.VMEM((2, hb, pp * ps, d), arena_k.dtype)] * len(arenas)
+            + [pltpu.SemaphoreType.DMA((len(arenas), 2)), pltpu.SMEM((1,), jnp.int32)],
+        )
+        kernel = loop_kernel
+    else:
+        # columns past a slot's newest visible page repeat that page's entry.
+        # Clamped here and not in the index map: there the divide and the min
+        # run for every grid step and operand (PR 30, on the chip: 0.267 ms a
+        # call against 0.248 with this, 0.246 with no clamp at all)
+        col = jnp.minimum(jnp.arange(P, dtype=jnp.int32), ((pos_v + sq - 1) // ps)[:, None])
+        tab = jnp.take_along_axis(tab, col, axis=1)
+        rows = pl.BlockSpec((None, hb, qr, d), lambda s, g, j, t, p: (s, g, 0, 0))
+        page_tile = pl.BlockSpec(
+            (None, hb, ps, d), lambda s, g, j, t, p: (t[s * P + j], g, 0, 0)
+        )
+        grid_spec = pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,
+            grid=(b, n_groups, P),
+            in_specs=[rows, page_tile, page_tile],
+            out_specs=rows,
+            scratch_shapes=softmax_state,
+        )
+        kernel = grid_kernel
     out = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, hk, qr, d), q.dtype),
         interpret=interpret,
         name="paged_walk_decode",
-    )(tab, pos_v, qg, arena_k, arena_v)
+    )(tab.reshape(-1), pos_v, qg, *arenas)
     out = out[:, :, :R].reshape(b, hk, rep, sq, d).reshape(b, h, sq, d)
     return jnp.transpose(out, (0, 2, 1, 3))
 

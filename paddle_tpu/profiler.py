@@ -316,14 +316,16 @@ def reset_flash_pallas():
         _flash_pallas.clear()
 
 
-_paged_walks = {}  # (b, sq, heads_per_step, grid_steps, kv_bytes_per_step), in order of first trace
+_PAGED_WALK_FIELDS = (
+    "b", "sq", "heads_per_step", "grid_steps", "kv_bytes_per_step", "pages_per_step", "kv_operands")
+_paged_walks = {}  # a tuple of `_PAGED_WALK_FIELDS` a distinct walk, in order of first trace
 
 
-def record_paged_walk(b, sq, heads_per_step, grid_steps, kv_bytes_per_step):
+def record_paged_walk(**geometry):
     """The geometry the page-walk decode kernel took for one traced call
     (ops/flash_attention.py picks it from the static shape): recorded at
     trace time, like `record_flash_pallas_call`."""
-    key = (int(b), int(sq), int(heads_per_step), int(grid_steps), int(kv_bytes_per_step))
+    key = tuple(int(geometry[f]) for f in _PAGED_WALK_FIELDS)
     with _counters_lock:
         _paged_walks[key] = None
 
@@ -331,10 +333,13 @@ def record_paged_walk(b, sq, heads_per_step, grid_steps, kv_bytes_per_step):
 def paged_walk_summary():
     """One entry per distinct walk traced since the last reset (a model's
     layers trace the same one): slots `b`, q rows a slot `sq`, KV heads a
-    grid step moves, grid steps a call, K and V bytes a step copies."""
-    fields = ("b", "sq", "heads_per_step", "grid_steps", "kv_bytes_per_step")
+    grid step takes, grid steps a call (`b * kv_heads / heads_per_step` where
+    the walk loops inside the step, times the table's columns where a page
+    is a grid step), bytes one copy block holds (`pages_per_step` pages of
+    `heads_per_step` heads from each of `kv_operands` arenas: 2, K and V,
+    or 1 where the values are the keys' rows)."""
     with _counters_lock:
-        return [dict(zip(fields, key)) for key in _paged_walks]
+        return [dict(zip(_PAGED_WALK_FIELDS, key)) for key in _paged_walks]
 
 
 def reset():

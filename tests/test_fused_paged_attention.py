@@ -69,15 +69,19 @@ def _arena(num_pages=9, ps=8, hk=2, d=16, seed=0):
 
 
 def _both(q, ak, av, tables, pos, max_len):
-    """(fused-interpret, gather) outputs for one paged attention call."""
+    """(the walk interpreted, gather); `av` None is ONE arena, the keys' rows
+    the values too: said to the kernel as `arena_v=None`, which the
+    dispatcher's K/V head-width rule never sees."""
     with _interpret():
-        fused = fa.paged_decode_attention_array(
-            q, ak, av, tables, pos, max_len, kernel="fused"
-        )
+        if av is None:
+            walked = fa._fused_paged_decode(
+                q, ak, None, tables, pos, max_len, q.shape[-1] ** -0.5, True)
+        else:
+            walked = fa.paged_decode_attention_array(
+                q, ak, av, tables, pos, max_len, kernel="fused")
     gather = fa.paged_decode_attention_array(
-        q, ak, av, tables, pos, max_len, kernel="gather"
-    )
-    return np.asarray(fused), np.asarray(gather)
+        q, ak, ak if av is None else av, tables, pos, max_len, kernel="gather")
+    return np.asarray(walked), np.asarray(gather)
 
 
 @pytest.fixture
@@ -94,8 +98,62 @@ def heads_per_step(monkeypatch):
     return force
 
 
+@pytest.fixture
+def pages_per_step(monkeypatch):
+    """Force how many pages a copy block of the looping walk holds."""
+    def force(pp):
+        monkeypatch.setattr(fa, "_pick_pages_per_step", lambda *shape: pp)
+
+    return force
+
+
 def _divisors(n):
     return [i for i in range(1, n + 1) if n % i == 0]
+
+
+def _walk_case(name):
+    """(q, arena_k, arena_v or None, tables, pos, max_len, forced pages a
+    step or None, mesh degrees or None) of one class of looping walk."""
+    r = np.random.RandomState(len(name))
+    rnd = lambda *shape: jnp.asarray(r.rand(*shape).astype(np.float32) - 0.5)
+    ps = 8
+
+    def tables(held, P, first=1):
+        """Each slot its own pages, in an order that is not the arena's; 0
+        (the scratch page) in the columns it does not hold."""
+        t = np.zeros((len(held), P), np.int32)
+        ids = r.permutation(sum(held)) + first
+        for i, n in enumerate(held):
+            t[i, :n], ids = ids[:n], ids[n:]
+        return jnp.asarray(t)
+
+    if name in ("chat32", "chat32-pp4", "tp2"):
+        # the serving cell's class: 8 KV heads of 128, 4 q heads each, 16 columns
+        pos = np.array([0, 37, 127, 64, 9], np.int32)
+        held = pos // ps + 1
+        arena = lambda: rnd(int(held.sum()) + 1, 8, ps, 128)
+        return (rnd(5, 1, 32, 128), arena(), arena(), tables(held, 16), jnp.asarray(pos), 128,
+                4 if name == "chat32-pp4" else None, {"mp": 2} if name == "tp2" else None)
+    if name in ("reason64", "reason64-pp3", "edges"):
+        # the latent walk's class: one KV head, a wide row, ONE arena; 19
+        # columns are 2 blocks of 8 and 3 over, 6 of 3 and 1 over; slots at 0,
+        # at k * ps - 1 and k * ps, one holding every column beside one holding one
+        P = 19
+        pos = np.array([0, 7, 8, 63, 64, P * ps - 1, 3, 100], np.int32)
+        held = pos // ps + 1
+        return (rnd(8, 1, 32, 640), rnd(int(held.sum()) + 1, 1, ps, 640), None, tables(held, P),
+                jnp.asarray(pos), P * ps, {"reason64": None, "reason64-pp3": 3, "edges": 1}[name], None)
+    if name in ("verify3", "verify3-one-arena"):
+        # the verify window: 3 rows a slot at pos, pos + 1, pos + 2; a window
+        # that crosses into a page the table does not map reads scratch page 0
+        pos = np.array([14, 0, 5, 29, 61], np.int32)
+        held = np.array([2, 1, 1, 4, 8])  # pos 14: rows 14..16 cross into column 2, unmapped
+        one = name.endswith("one-arena")
+        hk = 1 if one else 2
+        arena = lambda: rnd(int(held.sum()) + 1, hk, ps, 16)
+        return (rnd(5, 3, 4, 16), arena(), None if one else arena(), tables(held, 8), jnp.asarray(pos), 64,
+                2, None)
+    raise KeyError(name)
 
 
 class TestFusedVsGather:
@@ -121,14 +179,14 @@ class TestFusedVsGather:
         np.testing.assert_allclose(fused, gather, rtol=2e-5, atol=2e-5)
         (walk,) = profiler.paged_walk_summary()
         assert walk["heads_per_step"] == hb
-        assert walk["grid_steps"] == 4 * (hk // hb) * 4
+        assert walk["grid_steps"] == 4 * (hk // hb)  # the pages are a loop inside
 
     @pytest.mark.parametrize("sq", [1, 4])
     def test_stale_entries_past_the_last_page_are_inert(self, sq):
         """A table that still names pages past a slot's newest visible one
-        (here pages of NaNs) reads as the table with zeros there: those steps
-        repeat the last visible page's block, so nothing is copied for them,
-        and `needed` skips their compute."""
+        (here pages of NaNs) reads as the table with zeros there: the walk's
+        loop ends at the slot's newest visible page, so nothing is copied or
+        computed for them."""
         ak, av = _arena(num_pages=9, ps=8, hk=2, d=16, seed=9)
         ak, av = (a.at[jnp.asarray([6, 8])].set(jnp.nan) for a in (ak, av))
         r = np.random.RandomState(19)
@@ -147,6 +205,78 @@ class TestFusedVsGather:
         gather = fa.paged_decode_attention_array(
             q, ak, av, jnp.asarray(clean), pos, 32, kernel="gather")
         np.testing.assert_allclose(got[1], np.asarray(gather), rtol=2e-5, atol=2e-5)
+
+    @pytest.mark.parametrize("case", [
+        "chat32", "chat32-pp4", "reason64", "reason64-pp3", "edges", "verify3",
+        "verify3-one-arena", "tp2"])
+    def test_looped_walk_parity(self, pages_per_step, case):
+        """The walk off the grid (one grid step a slot and head block, the
+        slot's own pages in a loop, several a copy, the next block and the
+        next slot's first block in flight) against the gather oracle, in the
+        classes of shape the cells run and at the loop's edges."""
+        from paddle_tpu.distributed import mesh as pmesh
+
+        q, ak, av, tables, pos, max_len, pp, degrees = _walk_case(case)
+        if pp is not None:
+            pages_per_step(pp)
+        prev = pmesh.get_mesh()
+        try:
+            if degrees:
+                pmesh.build_mesh(devices=jax.devices()[:2], **degrees)
+            profiler.reset()
+            walked, gather = _both(q, ak, av, tables, pos, max_len)
+        finally:
+            pmesh.set_mesh(prev)
+        assert np.isfinite(walked).all()
+        np.testing.assert_allclose(walked, gather, rtol=2e-5, atol=2e-5)
+        (walk,) = profiler.paged_walk_summary()
+        local_hk = ak.shape[1] // (degrees or {}).get("mp", 1)
+        assert walk["grid_steps"] == q.shape[0] * local_hk // walk["heads_per_step"]
+        assert walk["kv_operands"] == (1 if av is None else 2)
+        if pp is not None:
+            assert walk["pages_per_step"] == pp
+
+    @pytest.mark.parametrize("one_arena", [False, True], ids=["k-and-v", "one-arena"])
+    @pytest.mark.parametrize("pp", [1, 3, None])
+    def test_looped_walk_copies_no_page_past_the_last(self, pages_per_step, pp, one_arena):
+        """Table columns past a slot's last page name a page of NaNs: the
+        output is finite and equal to the clean table's, block by block of
+        every size (a copied NaN would reach the output through a weight of
+        0); the room of a block's pages that the slot does not hold is zeroed,
+        not left as the new kernel's or the slot before's (interpret mode makes
+        scratch of NaNs)."""
+        if pp is not None:
+            pages_per_step(pp)
+        r = np.random.RandomState(23)
+        P, ps, hk = 7, 8, 1 if one_arena else 2
+        mk = lambda: jnp.asarray(r.rand(12, hk, ps, 16).astype(np.float32) - 0.5).at[11].set(jnp.nan)
+        ak, av = mk(), None if one_arena else mk()
+        q = jnp.asarray(r.rand(4, 1, 4, 16).astype(np.float32) - 0.5)
+        clean = np.array([[1, 2, 3, 4, 5, 6, 7], [5, 6, 0, 0, 0, 0, 0],
+                          [0, 0, 0, 0, 0, 0, 0], [8, 3, 5, 1, 9, 0, 0]], np.int32)
+        stale = np.where(clean == 0, 11, clean)
+        stale[2, 0] = 0  # the idle slot, parked on scratch page 0 at pos 0, walks that page
+        pos = jnp.asarray([55, 15, 0, 39], jnp.int32)
+        got = [_both(q, ak, av, jnp.asarray(t), pos, 56)[0] for t in (clean, stale)]
+        assert np.isfinite(got[1]).all()
+        np.testing.assert_array_equal(got[0], got[1])
+        gather = _both(q, ak, av, jnp.asarray(clean), pos, 56)[1]
+        np.testing.assert_allclose(got[1], gather, rtol=2e-5, atol=2e-5)
+
+    def test_many_q_rows_keep_the_grid(self):
+        """A q block of more rows than `_PAGED_WALK_LOOP_ROWS` (a chunk
+        prefill) keeps a page a grid step, both arenas through the pipeline."""
+        ak, av = _arena(num_pages=9, ps=8, hk=2, d=16, seed=4)
+        r = np.random.RandomState(29)
+        sq = fa._PAGED_WALK_LOOP_ROWS // 2 + 8  # 2 q heads a KV head
+        q = jnp.asarray(r.rand(2, sq, 4, 16).astype(np.float32) - 0.5)
+        tables = jnp.asarray(np.tile([[1, 2, 3, 4, 5, 6, 7, 8, 1, 2, 3, 4]], (2, 1)), jnp.int32)
+        profiler.reset()
+        fused, gather = _both(q, ak, av, tables, jnp.asarray([3, 20], jnp.int32), max_len=96)
+        np.testing.assert_allclose(fused, gather, rtol=2e-5, atol=2e-5)
+        (walk,) = profiler.paged_walk_summary()
+        assert walk["grid_steps"] == 2 * (2 // walk["heads_per_step"]) * 12
+        assert (walk["pages_per_step"], walk["kv_operands"]) == (1, 2)
 
     @pytest.mark.parametrize("sq", [1, 4])
     def test_ragged_gqa_parity(self, sq):
@@ -238,10 +368,13 @@ class TestFusedVsGather:
         )
 
     def test_walk_geometry_follows_the_static_shape(self):
-        """`paged_walk_summary()` at Mistral widths and the serving cell's
-        arena, traced and not run: batch-32 decode and a verify window of 5
-        move all 8 KV heads of a page a step (512 steps for 4,096); a chunk
-        prefill, whose q rows fill VMEM, keeps one head a step."""
+        """`paged_walk_summary()` at the two serving cells' published shapes,
+        traced and not run.  Mistral widths over `chat32`'s arena: batch-32
+        decode and a verify window of 5 take all 8 KV heads of a page and one
+        grid step a slot (32 for 4,096 pages of table), 4 pages a copy, K and
+        V; a chunk prefill, whose q rows fill VMEM, keeps one head and one
+        page a grid step.  Ling-3's latent walk over `reason64`'s arena: 64
+        grid steps for 16,384 columns, 8 pages a copy, ONE operand."""
         bf16 = jnp.bfloat16
         arena = jax.ShapeDtypeStruct((513, 8, 128, 128), bf16)
         profiler.reset()
@@ -255,17 +388,25 @@ class TestFusedVsGather:
                     jax.ShapeDtypeStruct((b,), jnp.int32),
                 )
         page = 128 * 128 * 2  # one head's K or V tile of a page, bf16
-        walks = {(w["b"], w["sq"]): w for w in profiler.paged_walk_summary()}
+        fields = ("heads_per_step", "grid_steps", "pages_per_step", "kv_operands", "kv_bytes_per_step")
+        walks = {(w["b"], w["sq"]): tuple(w[f] for f in fields) for w in profiler.paged_walk_summary()}
         for key in ((32, 1), (32, 5)):
-            assert walks[key]["heads_per_step"] == 8
-            assert walks[key]["grid_steps"] == 32 * 16
-            assert walks[key]["kv_bytes_per_step"] == 2 * 8 * page
+            assert walks[key] == (8, 32, 4, 2, 2 * 4 * 8 * page)
         for key in ((1, 256), (1, 512)):
-            assert walks[key]["heads_per_step"] == 1
-            assert walks[key]["grid_steps"] == 8 * 16
-            assert walks[key]["kv_bytes_per_step"] == 2 * page
+            assert walks[key] == (1, 8 * 16, 1, 2, 2 * page)
         profiler.reset()
         assert profiler.paged_walk_summary() == []
+        jax.eval_shape(
+            lambda q, lat, t, p: fa._fused_paged_decode(q, lat, None, t, p, 32768, 0.07, True),
+            jax.ShapeDtypeStruct((64, 1, 32, 640), bf16),
+            jax.ShapeDtypeStruct((16385, 1, 128, 640), bf16),
+            jax.ShapeDtypeStruct((64, 256), jnp.int32),
+            jax.ShapeDtypeStruct((64,), jnp.int32),
+        )
+        (walk,) = profiler.paged_walk_summary()
+        assert walk == {"b": 64, "sq": 1, "heads_per_step": 1, "grid_steps": 64, "pages_per_step": 8,
+                        "kv_operands": 1, "kv_bytes_per_step": 8 * 128 * 640 * 2}
+        profiler.reset()
 
     @pytest.mark.parametrize(
         "hk,sq,want",
@@ -277,6 +418,18 @@ class TestFusedVsGather:
         under tp=4 the kernel sees the local 2 KV heads."""
         qr = -(-4 * sq // 8) * 8
         assert fa._pick_kv_heads_block(hk, qr, 128, 128, 2) == want
+
+    @pytest.mark.parametrize(
+        "hb,qr,d,operands,cols,want",
+        [(8, 8, 128, 2, 16, 4), (8, 24, 128, 2, 16, 4), (2, 8, 128, 2, 16, 8), (1, 32, 640, 1, 256, 8),
+         (1, 32, 640, 1, 4, 4), (8, 8, 256, 2, 64, 2), (8, 128, 64, 2, 64, 4)],
+        ids=["chat32", "chat32-verify5", "chat32-tp4", "reason64", "few-columns", "wide-heads", "many-rows"],
+    )
+    def test_pages_per_step_picker(self, hb, qr, d, operands, cols, want):
+        """Pages of 128 bf16 rows: two blocks of `want` pages from each arena
+        fit `_PAGED_WALK_VMEM_BUDGET`, two f32 score tiles of a block too,
+        within the bound on a block's pages and the table's columns."""
+        assert fa._pick_pages_per_step(hb, qr, 128, d, 2, operands, cols) == want
 
 
 # ---------------------------------------------------------------------------

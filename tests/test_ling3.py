@@ -310,8 +310,9 @@ def test_mla_layer_matches_the_reference_through_the_latent_pages(start):
 
 
 def test_mla_decode_walks_the_pages_in_the_kernel_as_it_gathers_them():
-    """The Pallas page walk (interpreted here) over the latent arena as K and
-    as V gives what the gathered context gives."""
+    """The Pallas page walk (interpreted here) over the one latent arena, its
+    rows the keys and the values (`arena_v=None`), gives what the gathered
+    context gives."""
     from paddle_tpu.ops import flash_attention as fa
 
     cfg = config()

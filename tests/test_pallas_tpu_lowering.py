@@ -146,6 +146,13 @@ def _paged_cases():
     for sq in (1, 5):
         yield (f"paged-chat32-q{sq}", _paged_fn(False, 2048),
                _paged_args(32, sq, 32, 8, 128, False, max_len=2048), None)
+    # `ling3_serve.reason64`'s latent walk: 64 slots, 256 table entries over a
+    # 16,385-page arena of ONE 640-wide head that is keys and values
+    # (`arena_v=None`), straight to the kernel as `models/ling3.py` calls it
+    yield ("paged-reason64",
+           lambda q, lat, t, p: fa._fused_paged_decode(q, lat, None, t, p, 32768, 0.07, False),
+           [((64, 1, 32, 640), BF16, None), ((16385, 1, 128, 640), BF16, None),
+            ((64, 256), jnp.int32, None), ((64,), jnp.int32, None)], None)
     for cp, mp in ((1, 4), (2, 2)):  # the shard_map wrappers
         for quant in (False, True):
             yield (f"paged-cp{cp}-mp{mp}-{'int8' if quant else 'bf16'}",

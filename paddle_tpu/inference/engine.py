@@ -127,6 +127,7 @@ from .paging import (
     PrefixCache,
     QuantConfigError,
     SessionStore,
+    WindowPages,
     check_scale_arenas,
     check_table_bounds,
     kv_page_bytes,
@@ -468,17 +469,36 @@ class ContinuousBatchingEngine:
         )
 
         # a token's rows in each layer's cache, as the model declares them:
-        # (name, heads, width, dtype).  The handoff, the int8 arena and the
-        # fused kernel's limits speak of the first kind's geometry (K's)
+        # (name, heads, width, dtype)
         rows = list(model.cache_rows())
-        _, kv_heads, head_dim, cache_dtype = rows[0]
         # per layer (rows, state): a model whose layers differ declares them
         # with `cache_layers()`; state is a slot's, [(name, shape, dtype)]
-        layers = (
-            [(list(r), list(st)) for r, st in model.cache_layers()]
-            if hasattr(model, "cache_layers")
+        # a third entry is the REACH of the layer's rows in tokens (None: a
+        # query reads every row before it): such a layer's pages live in a
+        # second page group, released behind the window (`WindowPages`)
+        declared = (
+            list(model.cache_layers()) if hasattr(model, "cache_layers")
             else [(rows, [])] * cfg.num_hidden_layers
         )
+        layers = [(list(e[0]), list(e[1])) for e in declared]
+        reaches = [e[2] if len(e) > 2 else None for e in declared]
+        windowed = {int(r) for r in reaches if r is not None}
+        if len(windowed) > 1:
+            raise ValueError(
+                f"windowed layers of one reach make one page group; got {sorted(windowed)}"
+            )
+        # the handoff, the int8 arena and the fused kernel's limits exist for
+        # the engine's own page group alone, and speak of the geometry of ITS
+        # first kind of rows (K's), not of whatever the model declares first
+        _, kv_heads, head_dim, cache_dtype = next(
+            (r[0] for (r, _), reach in zip(layers, reaches) if r and reach is None),
+            rows[0],
+        )
+        if windowed:
+            # what rests on every layer holding the whole prefix, or on one
+            # page group: refused by name like what the model refuses itself
+            unsupported |= {"prefix_cache", "spec_k", "role", "cp", "kv_quant"}
+            refuse("cp", self.cp > 1, f"cp={self.cp}")
         # quantized KV serving (ISSUE 18): validated HERE — typed
         # QuantConfigError at construction, never a dtype mismatch inside a
         # compiled step — and folded into every cache-key surface: the
@@ -584,10 +604,19 @@ class ContinuousBatchingEngine:
         self.pool_pages = int(pp)
         # one cache object a layer: an arena for each kind of rows it
         # declares (none: no arena), a [slots, ...] buffer for each state
+        self._window = (
+            WindowPages(self.slots, self.pages_per_seq, self.page_size,
+                        windowed.pop(), self.prefill_buckets[-1])
+            if windowed else None
+        )
+        # which page group a layer's views read: 0 the engine's own, 1 the
+        # window's
+        self._layer_group = [0 if r is None else 1 for r in reaches]
         self._arenas = [
-            PagedKVCache(self.pool_pages, self.page_size, rows=r,
-                         quant=self.kv_quant, state=st, slots=self.slots)
-            for r, st in layers
+            PagedKVCache(self._window.pool_pages if g else self.pool_pages,
+                         self.page_size, rows=r, quant=self.kv_quant,
+                         state=st, slots=self.slots)
+            for (r, st), g in zip(layers, self._layer_group)
         ]
         self._has_state = any(st for _, st in layers)
         if self.tp > 1 or self.cp > 1:
@@ -603,11 +632,12 @@ class ContinuousBatchingEngine:
         # bytes per kind (a token's rows, a slot's state), over the layers
         # that hold it, as the buffers hold them
         by_kind, row_bytes = {}, 0
-        for a in self._arenas:
+        for a, g in zip(self._arenas, self._layer_group):
             for n in a.row_names + a.state_names:
                 t = getattr(a, n)
                 nb = int(np.prod(t.shape)) * int(np.dtype(t._data.dtype).itemsize)
-                by_kind[n] = by_kind.get(n, 0) + nb
+                kind = n + ".window" if g and n in a.row_names else n
+                by_kind[kind] = by_kind.get(kind, 0) + nb
                 row_bytes += nb if n in a.row_names else 0
         _prof.record_kv_quant(
             mode=self.kv_quant,
@@ -616,6 +646,7 @@ class ContinuousBatchingEngine:
         )
         _prof.record_arena_bytes(by_kind)
         self._pool = PagePool(self.pool_pages, shards=self.cp)
+        self._report_page_groups()
         # a hit resumes from pages alone, which a model with state per slot
         # cannot: asked for, it is refused; left to the flag, it is off
         refuse("prefix_cache", bool(prefix_cache), f"prefix_cache={prefix_cache!r}")
@@ -792,9 +823,9 @@ class ContinuousBatchingEngine:
             lambda p, a: jnp.where(a, p, 0), [pos, active], name="serve_pos_mask"
         )
         views = [
-            PagedDecodeView(a, tables, self.max_len, kernel=self.decode_kernel,
+            PagedDecodeView(a, t, self.max_len, kernel=self.decode_kernel,
                             live=active)
-            for a in self._arenas
+            for a, t in zip(self._arenas, self._tables_by_layer(tables))
         ]
         lora = self._lora.view(adapters) if self._lora is not None else None
         hidden, _ = self.model.backbone(toks, caches=views, pos=pos_eff, lora=lora)
@@ -822,6 +853,22 @@ class ContinuousBatchingEngine:
         if stats is not None:
             return nxt, new_pos, finite, key, stats
         return nxt, new_pos, finite, key
+
+    def _tables_by_layer(self, tables):
+        """The page table (a decode step's `[slots, P]`, a prefill's row
+        `[P]`) each layer's view reads.  An engine with a window group is
+        handed both groups' tables stacked on a leading axis, its own first:
+        one operand, data like the one table of every other engine, whose
+        programs this leaves as they were."""
+        from ..ops.dispatch import apply
+
+        if self._window is None:
+            return [tables] * len(self._arenas)
+        by_group = [
+            apply(lambda t, g=g: t[g], [tables], name="serve_group_table")
+            for g in (0, 1)
+        ]
+        return [by_group[g] for g in self._layer_group]
 
     def _verify_paged_body(self, toks, pos, active, valid_len, temps, poison,
                            key, tables, adapters):
@@ -910,9 +957,9 @@ class ContinuousBatchingEngine:
         from ..ops.dispatch import apply
 
         views = [
-            PagedPrefillView(a, row_table, true_len, self.max_len,
+            PagedPrefillView(a, t, true_len, self.max_len,
                              kernel=self.decode_kernel, slot=slot)
-            for a in self._arenas
+            for a, t in zip(self._arenas, self._tables_by_layer(row_table))
         ]
         lora = self._lora.view(adapters) if self._lora is not None else None
         hidden, _ = self.model.backbone(toks, caches=views, lora=lora)
@@ -940,9 +987,9 @@ class ContinuousBatchingEngine:
         from ..ops.dispatch import apply
 
         views = [
-            PagedPrefillView(a, row_table, true_len, self.max_len, start=start,
+            PagedPrefillView(a, t, true_len, self.max_len, start=start,
                              kernel=self.decode_kernel, slot=slot)
-            for a in self._arenas
+            for a, t in zip(self._arenas, self._tables_by_layer(row_table))
         ]
         lora = self._lora.view(adapters) if self._lora is not None else None
         hidden, _ = self.model.backbone(toks, caches=views, lora=lora)
@@ -1177,6 +1224,14 @@ class ContinuousBatchingEngine:
                 + (f" across cp={self.cp} shards" if self.cp > 1 else ""),
                 retry_after_s=self._shed_retry_after(deadline_s),
             )
+        if self._window is not None and (
+            self._window.pages_for(ids.size) > self._window.pool.usable_pages
+        ):
+            raise QueueFull(
+                f"request needs {self._window.pages_for(ids.size)} pages of "
+                f"the window group, which holds {self._window.pool.usable_pages}",
+                retry_after_s=self._shed_retry_after(deadline_s),
+            )
         req = EngineRequest(
             next(self._req_ids), ids, max_new_tokens, temperature,
             eos_token_id, on_token, deadline_s=deadline_s, trace=trace,
@@ -1237,7 +1292,8 @@ class ContinuousBatchingEngine:
 
         # all-zero tables aim every warmup write at scratch page 0;
         # all-zero adapter ids ride the base (zero-delta) arena row
-        zero_row = to_tensor(np.zeros(self.pages_per_seq, np.int32))
+        lead = () if self._window is None else (2,)  # both groups' tables, stacked
+        zero_row = to_tensor(np.zeros(lead + (self.pages_per_seq,), np.int32))
         zero_ad1 = to_tensor(np.zeros(1, np.int32))
         zero_ads = to_tensor(np.zeros(self.slots, np.int32))
         zero_toks = to_tensor(np.zeros((self.slots, 1), np.int32))
@@ -1287,7 +1343,7 @@ class ContinuousBatchingEngine:
             to_tensor(np.zeros(self.slots, np.float32)),
             self._poison_zero,
             self._key,
-            to_tensor(np.zeros((self.slots, self.pages_per_seq), np.int32)),
+            to_tensor(np.zeros(lead + (self.slots, self.pages_per_seq), np.int32)),
             zero_ads,
         )
         if self._spec_on:
@@ -1446,6 +1502,11 @@ class ContinuousBatchingEngine:
                 if self._mesh is not None else {}
             ),
         }
+        if self._window is not None:
+            # the second page group (windowed layers): its own pool
+            out["window_page_free_frac"] = round(
+                self._window.pool.free_count() / self._window.pool.usable_pages, 4
+            )
         if self.cp > 1:
             # per-shard free pages: the router's long-context scoring needs
             # the WORST shard (a sequence page can only land on its own
@@ -1922,6 +1983,21 @@ class ContinuousBatchingEngine:
             )
         return self._pool.alloc(shard)
 
+    def _report_page_groups(self):
+        """The page groups' sizes and live pages to the profiler
+        (`window_cache_summary()`).  Caller holds _mu, or is the
+        constructor."""
+        from .. import profiler as _prof
+
+        _prof.record_page_group(
+            "full", self.pool_pages, None, self._pool.used_count()
+        )
+        if self._window is not None:
+            win = self._window
+            _prof.record_page_group(
+                "window", win.pool_pages, win.reach, win.pool.used_count()
+            )
+
     def _release_slot_pages_locked(self, s):
         """Drop slot `s`'s page mappings (finish/evict/restart): every mapped
         page holds one ref for the mapping — shared prefix pages stay alive
@@ -1930,6 +2006,9 @@ class ContinuousBatchingEngine:
             self._pool.decref(p)
         self._slot_pages[s] = []
         self._page_table[s, :] = 0
+        if self._window is not None:
+            self._window.release(s)
+            self._report_page_groups()
 
     # -- LoRA adapter bindings ------------------------------------------------
 
@@ -2066,6 +2145,13 @@ class ContinuousBatchingEngine:
                 else:
                     short = need > self._page_fresh_headroom_locked(
                         exclude
+                    )
+                if self._window is not None:
+                    # the window group's gate: the pages the prompt's chunks
+                    # hold at once (in decode the slot holds fewer)
+                    short = short or (
+                        self._window.pages_for(req.prompt.size)
+                        > self._window.pool.free_count()
                     )
                 if short:
                     # page pressure: park the request at the head of the
@@ -2207,6 +2293,7 @@ class ContinuousBatchingEngine:
                 _prof.record_session_stats(self._sessions.stats())
             row_table = self._page_table[s].copy()
         suffix = L - match_len
+        win = self._window
         # a remainder longer than the largest bucket goes in as consecutive
         # chunks of that bucket, each at its offset through the page table
         # (the first of a fresh prompt through the fresh-prefill program); the
@@ -2239,7 +2326,7 @@ class ContinuousBatchingEngine:
                 ad_t = to_tensor(
                     np.full(1, req.adapter_slot or 0, np.int32)
                 )
-                table_t = to_tensor(row_table)
+                table_t = to_tensor(row_table) if win is None else None
                 temp_t = to_tensor(np.float32(req.temperature))
                 slot_t = to_tensor(np.int32(s))
                 for offset, n in chunks:
@@ -2248,6 +2335,19 @@ class ContinuousBatchingEngine:
                     toks[0, :n] = req.prompt[offset:offset + n]
                     t_ch = time.perf_counter()
                     self._check_gen(gen)  # between chunks too: a restart owns the pages
+                    if win is not None:
+                        # a table a chunk: the window group maps the rows this
+                        # chunk writes and the reach before them, and gives
+                        # back what lies behind.  The chunk before was
+                        # dispatched with the table it needed
+                        with self._mu:
+                            self._check_gen(gen)
+                            win.map_range(s, win.first_visible(offset), offset + n - 1)
+                            _prof.record_page_group(
+                                "window", win.pool_pages, win.reach,
+                                win.pool.used_count(), prefill_pages=win.held(s),
+                            )
+                            table_t = to_tensor(np.stack([row_table, win.table[s]]))
                     if self._has_state:
                         _prof.record_linear_attn_prefill(n, resumed=offset != 0)
                     # only the last chunk's token is the request's first: of
@@ -2286,6 +2386,9 @@ class ContinuousBatchingEngine:
                 )
                 if inserted:
                     _prof.record_paging_event("cache_commits", inserted)
+            if win is not None:
+                # seated, the slot keeps what its first decode step can see
+                win.map_range(s, win.first_visible(L), L - 1)
             self._seat_locked(s, req, L)
             self._pending_fetch.append(_Unfetched(
                 nxt, None, [(s, req)], t_pf, eager=True,
@@ -2477,15 +2580,42 @@ class ContinuousBatchingEngine:
                 # page tables (and adapter bindings) change exactly when
                 # membership does — the same events that invalidate _dev
                 # — so one H2D mirror per membership change covers every
-                # following step.  A slot that sits out (its last step in
-                # flight, its finish a tick away) still maps its pages: its
-                # row goes up as zeros, so that the inactive slot's write at
-                # pos 0 lands on scratch and not on its prompt's first row
-                tables = np.zeros_like(self._page_table)
-                tables[run] = self._page_table[run]
-                self._tables_t = to_tensor(tables)
+                # following step
                 self._adapters_t = to_tensor(self._slot_adapter.copy())
                 self._obs_epoch_open(run)
+                self._tables_t = None
+            if self._window is not None:
+                # the window group's table moves with `pos`, not with
+                # membership alone: the page this step writes is mapped, the
+                # pages behind its reach go back to their pool, and a table
+                # that changed goes up again (64 KB; data, no retrace)
+                win = self._window
+                moved = [
+                    win.map_range(s, win.first_visible(self._pos[s]), self._pos[s])
+                    for s in run
+                ]
+                if any(moved):
+                    self._tables_t = None
+                _prof.record_page_group(
+                    "window", win.pool_pages, win.reach, win.pool.used_count(),
+                    slot_pages=max(win.held(s) for s in run),
+                    released_behind=win.released_behind,
+                )
+                win.released_behind = 0
+            if self._tables_t is None:
+                # a slot that sits out (its last step in flight, its finish a
+                # tick away) still maps its pages: its row goes up as zeros,
+                # so that the inactive slot's write at pos 0 lands on scratch
+                # and not on its prompt's first row
+                groups = [self._page_table] + (
+                    [self._window.table] if self._window is not None else []
+                )
+                tables = [np.zeros_like(t) for t in groups]
+                for t, g in zip(tables, groups):
+                    t[run] = g[run]
+                self._tables_t = to_tensor(
+                    tables[0] if self._window is None else np.stack(tables)
+                )
             pos_t, active_t, temps_t = self._dev
             key = self._key
             poison_t, poisoned = self._poison_zero, None
@@ -2550,6 +2680,11 @@ class ContinuousBatchingEngine:
             _prof.record_paging_tick(
                 self._pool.used_count(), self._pool.usable_pages
             )
+            if self._window is not None:
+                _prof.record_page_group(
+                    "full", self.pool_pages, None, self._pool.used_count(),
+                    slot_pages=max(len(self._slot_pages[s]) for s in run),
+                )
             if self.kv_quant == "int8":
                 # per-layer work divided out: one KV row-pair quantized
                 # per active slot, every mapped page dequantized in the
@@ -3103,11 +3238,19 @@ class ContinuousBatchingEngine:
         written.  Caller holds _mu."""
         pool, ps = self._pool, self.page_size
         check_table_bounds(self._page_table, pool.num_pages)
+        if self._window is not None:
+            self._window.check([
+                None if self._slot_req[s] is None else int(self._pos[s])
+                for s in range(self.slots)
+            ])
         # ISSUE 18: the scale arenas are audited alongside the K/V pages —
         # congruence (same page count, [ps, kv_heads, 1] f32 rows) is the
         # whole refcount story, because page p's scale rows share page p's
         # refcount by construction
-        check_scale_arenas(self._arenas, pool.num_pages, ps)
+        check_scale_arenas(
+            [a for a, g in zip(self._arenas, self._layer_group) if not g],
+            pool.num_pages, ps,
+        )
         expected = np.zeros(pool.num_pages, np.int64)
         for p in pool.scratch_pages:
             expected[p] = 1  # scratch pin (one per cp shard, ISSUE 20)

@@ -424,6 +424,108 @@ class PagePool:
         return False
 
 
+class WindowPages:
+    """The page group of layers whose rows are WINDOWED: a query at position
+    p reads its layer's rows `p - reach + 1 .. p` and no earlier one, so a
+    slot maps pages for the rows in reach only and gives a page back as soon
+    as its last row falls behind the window.  Its own `PagePool` and page
+    table beside the engine's (the same `[slots, pages_per_seq]` int32 of
+    LOGICAL page columns: column c holds rows `c * page_size ..`, 0 = nothing
+    mapped, which is the scratch page, so the kernels index it as they index
+    the full group's), and arenas of `pool_pages` pages.
+
+    A decoding slot holds at most `slot_pages` (`reach / page_size + 1` for
+    whole pages), a prefilling one at most `chunk_pages` (the rows a chunk
+    writes and the reach before them), and one prompt is prefilled at a time:
+    `slots * slot_pages + chunk_pages` pages and the scratch page never run
+    dry.  Nothing shares a windowed page (no prefix cache over windowed
+    layers), so a page's refcount is 0 or 1.
+
+    Host arithmetic only; the caller holds the engine's mutex.  A page given
+    back may be mapped by another slot at once: the device runs its programs
+    in the order of their dispatch, and each carries the table it was
+    dispatched with."""
+
+    def __init__(self, slots, pages_per_seq, page_size, reach, chunk_rows):
+        self.page_size, self.reach = int(page_size), int(reach)
+        if self.reach < 1:
+            raise ValueError(f"a windowed layer reaches at least its own token, not {reach}")
+        ps = self.page_size
+        self.slot_pages = min(-(-(self.reach - 1) // ps) + 1, int(pages_per_seq))
+        self.chunk_pages = min(-(-(self.reach - 1 + int(chunk_rows)) // ps) + 1, int(pages_per_seq))
+        self.pool_pages = int(slots) * self.slot_pages + self.chunk_pages + 1
+        self.pool = PagePool(self.pool_pages)
+        self.table = np.zeros((int(slots), int(pages_per_seq)), np.int32)
+        self.released_behind = 0  # since the engine last reported it
+
+    def first_visible(self, pos):
+        return max(int(pos) - self.reach + 1, 0)
+
+    def held(self, s):
+        return int(np.count_nonzero(self.table[s]))
+
+    def pages_for(self, rows):
+        """The most pages a prompt of `rows` rows needs at once while its
+        chunks go in, and then in decode."""
+        return min(-(-int(rows) // self.page_size), self.chunk_pages)
+
+    def map_range(self, s, first, last):
+        """Slot `s` is about to read rows from `first` and to write rows up
+        to `last`: pages behind `first` go back to the pool, columns up to
+        `last`'s that hold nothing get a page.  True if the table changed."""
+        ps, row = self.page_size, self.table[s]
+        lo, hi = int(first) // ps, int(last) // ps
+        if row[hi] and not (lo and row[lo - 1]):
+            return False  # the mapped columns are one run: nothing behind it, nothing missing
+        behind = np.flatnonzero(row[:lo])
+        for c in behind:
+            self.pool.decref(int(row[c]))
+            row[c] = 0
+        self.released_behind += len(behind)
+        fresh = [c for c in range(lo, hi + 1) if row[c] == 0]
+        for c in fresh:
+            row[c] = self.pool.alloc()
+        return bool(len(behind) or fresh)
+
+    def release(self, s):
+        """Drop everything slot `s` maps (finish, evict, restart)."""
+        for p in self.table[s][self.table[s] != 0]:
+            self.pool.decref(int(p))
+        self.table[s, :] = 0
+
+    def check(self, seated_pos):
+        """Debug invariants: `seated_pos[s]` is slot s's next position, None
+        for a free slot.  A free slot maps nothing; a seated one maps one run
+        of columns that covers every row in reach of its next step, none
+        behind it, and no more than `slot_pages`; every mapped page is
+        mapped once, and the free list is exactly the rest."""
+        check_table_bounds(self.table, self.pool.num_pages)
+        expected = np.zeros(self.pool.num_pages, np.int64)
+        expected[0] = 1
+        for s, pos in enumerate(seated_pos):
+            cols = np.flatnonzero(self.table[s])
+            if pos is None:
+                if len(cols):
+                    raise AssertionError(f"window invariant: free slot {s} maps columns {cols.tolist()}")
+                continue
+            # the rows its last step read and wrote (pos - 1) are still mapped,
+            # and nothing behind what that step could see
+            lo, hi = self.first_visible(pos - 1) // self.page_size, (pos - 1) // self.page_size
+            if len(cols) == 0 or cols[0] < lo or cols[0] > self.first_visible(pos) // self.page_size \
+                    or cols[-1] < hi or len(cols) != cols[-1] - cols[0] + 1:
+                raise AssertionError(
+                    f"window invariant: slot {s} at pos {pos} maps columns {cols.tolist()}, "
+                    f"its reach is columns {lo}..{hi}")
+            if len(cols) > self.slot_pages:
+                raise AssertionError(
+                    f"window invariant: slot {s} holds {len(cols)} pages, over the bound {self.slot_pages}")
+            np.add.at(expected, self.table[s][cols], 1)
+        if not np.array_equal(expected, self.pool.refs):
+            raise AssertionError("window invariant: a page's refcount is not its one mapping")
+        if sorted(self.pool._free) != [p for p in range(1, self.pool.num_pages) if expected[p] == 0]:
+            raise AssertionError("window invariant: the free list is not the unmapped pages")
+
+
 class _Entry:
     __slots__ = ("key", "parent_key", "page", "rows", "children", "last_used",
                  "tokens", "pinned")

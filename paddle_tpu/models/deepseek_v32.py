@@ -461,6 +461,23 @@ def _route(cfg, x, gate_w, bias):
     return experts.astype(jnp.int32), wts * cfg.routed_scaling_factor
 
 
+def _route_softmax(cfg, x, gate_w):
+    """The router's other published form (no bias, groups or scaling): x [T,
+    hidden] -> (experts [T, K] int32, weights [T, K] f32) with `p = softmax(x
+    W_g)` in float32 over every expert, the K largest, and, under
+    `norm_topk_prob`, their weights over their sum."""
+    import jax
+    import jax.numpy as jnp
+    from jax import lax
+
+    logits = jnp.dot(x.astype(jnp.float32), gate_w.astype(jnp.float32),
+                     precision=lax.Precision.HIGHEST)
+    wts, experts = lax.top_k(jax.nn.softmax(logits, axis=-1), cfg.num_experts_per_tok)
+    if cfg.norm_topk_prob:
+        wts = wts / jnp.sum(wts, axis=1, keepdims=True)
+    return experts.astype(jnp.int32), wts
+
+
 def _routed_experts(cfg, x, experts, wts, live, w1, w3, w2):
     """The held experts' part of the routed sum, dropless.  x [T, hidden],
     experts/wts [T, K], live [T] bool (padding rows and idle slots route

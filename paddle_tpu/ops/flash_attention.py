@@ -896,7 +896,7 @@ def paged_gather_scale(scale, tables, max_len):
 
 
 def _fused_paged_decode_forward(q, arena_k, arena_v, tables, pos, max_len,
-                                scale, interpret=False):
+                                scale, interpret=False, first=None):
     """Fused paged-decode attention: read the arena THROUGH the page tables
     in-kernel instead of materializing the gather (`paged_gather_kv` writes
     a dense [b, max_len, kv_h, d] copy of every sequence's KV to HBM each
@@ -948,6 +948,15 @@ def _fused_paged_decode_forward(q, arena_k, arena_v, tables, pos, max_len,
     `jid < max_len` reproduces the gather's `[:max_len]` slice of the
     trailing page's slack rows.
 
+    `first` ([b] int32, data; the looping walk only): each slot's FIRST
+    visible position, for a layer whose attention is windowed.  The walk then
+    starts at page `first // page_size` (no copy, step or compute exists for
+    a page before it, whatever the table holds there: the page manager has
+    released it), rows of that page below `first` are masked, and the block
+    count follows the pages in reach.  A third scalar-prefetch operand and
+    one more compare a block; without it the traced kernel is the one above,
+    to the instruction.
+
     Returns [b, sq, h, d]."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
@@ -964,6 +973,9 @@ def _fused_paged_decode_forward(q, arena_k, arena_v, tables, pos, max_len,
     qr = -(-R // 8) * 8  # f32 sublane tile; pad rows are sliced off
     hb = _pick_kv_heads_block(hk, qr, ps, d, itemsize)
     looped = qr <= _PAGED_WALK_LOOP_ROWS
+    windowed = first is not None
+    if windowed and not looped:
+        raise ValueError("a windowed page walk takes a decode step's rows, not a chunk's")
     if arena_v is None and not looped:
         arena_v = arena_k  # the pipeline of the grid brings a tile an operand
     arenas = (arena_k,) if arena_v is None else (arena_k, arena_v)
@@ -986,9 +998,10 @@ def _fused_paged_decode_forward(q, arena_k, arena_v, tables, pos, max_len,
         l_scr[...] = jnp.zeros_like(l_scr)
         acc_scr[...] = jnp.zeros_like(acc_scr)
 
-    def _compute(qb, kb, vb, first_row, p0, m_scr, l_scr, acc_scr):
+    def _compute(qb, kb, vb, first_row, p0, m_scr, l_scr, acc_scr, lo=None):
         """qb [hb, qr, d] against kb and vb [hb, n, d], the slot's rows
-        `first_row ..`: a page, or a block of pages in table order."""
+        `first_row ..`: a page, or a block of pages in table order.  `lo`:
+        the slot's first visible position (a windowed walk)."""
         n = kb.shape[1]
         s = jax.lax.dot_general(
             qb, kb, (((2,), (2,)), ((0,), (0,))),
@@ -996,7 +1009,10 @@ def _fused_paged_decode_forward(q, arena_k, arena_v, tables, pos, max_len,
         ) * scale  # [hb, qr, n]
         w = jax.lax.broadcasted_iota(jnp.int32, (qr, n), 0) % sq if sq > 1 else 0
         jid = first_row + jax.lax.broadcasted_iota(jnp.int32, (qr, n), 1)
-        s = jnp.where((jid <= p0 + w) & (jid < max_len), s, _NEG_INF)
+        seen = (jid <= p0 + w) & (jid < max_len)
+        if lo is not None:
+            seen &= jid >= lo
+        s = jnp.where(seen, s, _NEG_INF)
         m = m_scr[...]  # [hb, qr, 1]
         m_new = jnp.maximum(m, s.max(-1, keepdims=True))
         p = jnp.exp(s - m_new)
@@ -1024,13 +1040,17 @@ def _fused_paged_decode_forward(q, arena_k, arena_v, tables, pos, max_len,
 
     n_groups = hk // hb
 
-    def loop_kernel(t_ref, p_ref, q_ref, *rest):
+    def loop_kernel(t_ref, p_ref, *rest):
+        f_ref, (q_ref, *rest) = (rest[0], rest[1:]) if windowed else (None, rest)
         n = len(arenas)  # the arenas, the output, m l acc, a buffer an arena
         hbm, (o_ref, *scr), bufs, (sem, first) = rest[:n], rest[n:n + 4], rest[n + 4:-2], rest[-2:]
         slot, g = pl.program_id(0), pl.program_id(1)
 
+        def page0(s):  # the table column the slot's walk starts at
+            return f_ref[s] // ps if windowed else 0
+
         def n_pages(s):  # every slot holds one: an idle one is parked on page 0
-            return jnp.minimum((p_ref[s] + sq - 1) // ps + 1, P)
+            return jnp.minimum((p_ref[s] + sq - 1) // ps + 1, P) - page0(s)
 
         def block(s, grp, blk, buf, wait):
             """Start, or wait for, the copies of block `blk` of slot `s`, head
@@ -1044,7 +1064,7 @@ def _fused_paged_decode_forward(q, arena_k, arena_v, tables, pos, max_len,
                 rows_i = pl.ds(i * ps, ps)
 
                 def page(i=i, rows_i=rows_i):
-                    src = t_ref[s * P + blk * pp + i]
+                    src = t_ref[s * P + page0(s) + blk * pp + i]
                     for a in range(n):
                         tile = hbm[a].at[src] if hb == hk else hbm[a].at[src, pl.ds(grp * hb, hb)]
                         copy = pltpu.make_async_copy(
@@ -1078,7 +1098,8 @@ def _fused_paged_decode_forward(q, arena_k, arena_v, tables, pos, max_len,
             pl.when((blk + 1 == n_blocks) & (s_next < b))(
                 lambda: block(s_next, g_next, 0, 1 - buf, wait=False))
             block(slot, g, blk, buf, wait=True)
-            _compute(q_ref[...], bufs[0][buf], bufs[-1][buf], blk * pp * ps, p0, *scr)
+            _compute(q_ref[...], bufs[0][buf], bufs[-1][buf], (page0(slot) + blk * pp) * ps, p0, *scr,
+                     lo=f_ref[slot] if windowed else None)
             return carry
 
         jax.lax.fori_loop(0, n_blocks, body, 0)
@@ -1091,9 +1112,9 @@ def _fused_paged_decode_forward(q, arena_k, arena_v, tables, pos, max_len,
         pltpu.VMEM((hb, qr, d), jnp.float32),
     ]
     if looped:
-        rows = pl.BlockSpec((None, hb, qr, d), lambda s, g, t, p: (s, g, 0, 0))
+        rows = pl.BlockSpec((None, hb, qr, d), lambda s, g, *scalars: (s, g, 0, 0))
         grid_spec = pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=2,
+            num_scalar_prefetch=3 if windowed else 2,
             grid=(b, n_groups),
             in_specs=[rows] + [pl.BlockSpec(memory_space=pl.ANY)] * len(arenas),
             out_specs=rows,
@@ -1127,7 +1148,8 @@ def _fused_paged_decode_forward(q, arena_k, arena_v, tables, pos, max_len,
         out_shape=jax.ShapeDtypeStruct((b, hk, qr, d), q.dtype),
         interpret=interpret,
         name="paged_walk_decode",
-    )(tab.reshape(-1), pos_v, qg, *arenas)
+    )(tab.reshape(-1), pos_v, *([jnp.asarray(first, jnp.int32).reshape(b)] if windowed else []),
+      qg, *arenas)
     out = out[:, :, :R].reshape(b, hk, rep, sq, d).reshape(b, h, sq, d)
     return jnp.transpose(out, (0, 2, 1, 3))
 
@@ -1160,6 +1182,20 @@ def _fused_paged_decode_bwd(max_len, scale, interpret, res, g):
 
 
 _fused_paged_decode.defvjp(_fused_paged_decode_fwd, _fused_paged_decode_bwd)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(6, 7, 8))
+def _fused_paged_decode_window(q, arena_k, arena_v, tables, pos, first, max_len, scale, interpret):
+    """`_fused_paged_decode` for a windowed layer: `first` [b] int32 is each
+    slot's first visible position (`_fused_paged_decode_forward`)."""
+    return _fused_paged_decode_forward(
+        q, arena_k, arena_v, tables, pos, max_len, scale, interpret=interpret, first=first)
+
+
+_fused_paged_decode_window.defvjp(
+    lambda q, ak, av, t, p, f, max_len, scale, interpret: (
+        _fused_paged_decode_forward(q, ak, av, t, p, max_len, scale, interpret=interpret, first=f), None),
+    lambda max_len, scale, interpret, res, g: _fused_paged_decode_bwd(max_len, scale, interpret, res, g))
 
 
 def _fused_paged_decode_quant_forward(q, arena_k, arena_v, k_scale, v_scale,

@@ -628,6 +628,10 @@ _arena_bytes = {}  # cache kind (a token's rows, a slot's state) -> bytes over t
 # linear-attention layers with a fixed state per slot (ISSUE 33), counted the same way
 _linear_attn_gauges = {"steps": 0, "live_slots": 0, "state_bytes_read": 0, "state_bytes_written": 0,
                        "prefill_rows": 0, "chunks_resumed": 0}
+# layers whose rows are windowed, in a page group of their own (ISSUE 35): the rows in reach are
+# counted inside the step, the pages by the engine's page manager
+_window_gauges = {"steps": 0, "live_slots": 0, "rows_in_reach_full": 0, "rows_in_reach_window": 0}
+_page_groups = {}  # group name -> gauges, set by the engine that was built last
 
 
 def record_moe_step(tokens, picks_held, experts_hit, max_load):
@@ -676,15 +680,51 @@ def record_linear_attn_prefill(rows, resumed):
         _linear_attn_gauges["chunks_resumed"] += int(bool(resumed))
 
 
-def record_arena_bytes(by_kind):
+def record_window_rows(rows_full, rows_window, live_slots):
+    """One decode step of a model with windowed layers, counted inside the
+    step: the K/V rows in reach of the live slots, summed over the full
+    layers (`pos + 1` a slot a layer) and over the windowed ones (`min(pos +
+    1, reach)`)."""
     with _counters_lock:
+        g = _window_gauges
+        g["steps"] += 1
+        g["live_slots"] += int(live_slots)
+        g["rows_in_reach_full"] += int(rows_full)
+        g["rows_in_reach_window"] += int(rows_window)
+
+
+def record_page_group(name, pool_pages, reach, pages_live, slot_pages=0, prefill_pages=0,
+                      released_behind=0):
+    """What one page group of the engine's cache manager holds, as of now
+    (`pool_pages`, `reach`: tokens, None for a group that keeps every row;
+    `pages_live`) and since the last reset (the most pages live at once; the
+    most pages ONE decoding slot held, and one prefilling; `released_behind`
+    more pages given back behind a window while their slot ran on)."""
+    with _counters_lock:
+        g = _page_groups.setdefault(str(name), {
+            "pages_live_peak": 0, "slot_pages_peak": 0, "prefill_pages_peak": 0, "released_behind": 0})
+        g.update(pool_pages=int(pool_pages), reach=None if reach is None else int(reach),
+                 pages_live=int(pages_live))
+        g["pages_live_peak"] = max(g["pages_live_peak"], int(pages_live))
+        g["slot_pages_peak"] = max(g["slot_pages_peak"], int(slot_pages))
+        g["prefill_pages_peak"] = max(g["prefill_pages_peak"], int(prefill_pages))
+        g["released_behind"] += int(released_behind)
+
+
+def record_arena_bytes(by_kind):
+    """Set at engine construction; the page groups are that engine's too."""
+    with _counters_lock:
+        _page_groups.clear()
         _arena_bytes.clear()
         _arena_bytes.update({str(k): int(v) for k, v in by_kind.items()})
 
 
 def _reset_moe_locked():
-    for g in (_moe_gauges, _sparse_attn_gauges, _linear_attn_gauges):
+    for g in (_moe_gauges, _sparse_attn_gauges, _linear_attn_gauges, _window_gauges):
         for k in g:
+            g[k] = 0
+    for g in _page_groups.values():  # sizes stay, what was counted goes
+        for k in ("pages_live_peak", "slot_pages_peak", "prefill_pages_peak", "released_behind"):
             g[k] = 0
 
 
@@ -721,9 +761,22 @@ def linear_attn_summary():
     return g if g["steps"] or g["prefill_rows"] else {}
 
 
+def window_cache_summary():
+    """{} unless an engine with a windowed page group was built; else by page
+    group (`full`, `window`) what `record_page_group` keeps, and under
+    `decode` what `record_window_rows` counted inside the decode steps."""
+    with _counters_lock:
+        if "window" not in _page_groups:
+            return {}
+        out = {name: dict(g) for name, g in _page_groups.items()}
+        out["decode"] = dict(_window_gauges)
+    return out
+
+
 def arena_summary():
     """Bytes of the engine's cache per kind: a paged arena's row kinds and a
-    slot-state buffer's names alike ({} before an engine is built)."""
+    slot-state buffer's names alike; a windowed page group's kinds are
+    `<kind>.window` ({} before an engine is built)."""
     with _counters_lock:
         return dict(_arena_bytes)
 

@@ -153,6 +153,20 @@ def _paged_cases():
            lambda q, lat, t, p: fa._fused_paged_decode(q, lat, None, t, p, 32768, 0.07, False),
            [((64, 1, 32, 640), BF16, None), ((16385, 1, 128, 640), BF16, None),
             ((64, 256), jnp.int32, None), ((64,), jnp.int32, None)], None)
+    # `mellum2_serve.mixed32`'s two walks: 32 slots, 8 query rows a KV head over
+    # 4 KV heads; a full layer's over the 8,193-page arena, a sliding layer's
+    # over the window group's 314 pages from each slot's first visible position
+    m2 = [((32, 1, 32, 128), BF16, None), None, None, ((32, 256), jnp.int32, None), ((32,), jnp.int32, None)]
+    for tag, pages in (("full", 8193), ("window", 314)):
+        arena = ((pages, 4, 128, 128), BF16, None)
+        args = [m2[0], arena, arena] + m2[3:]
+        if tag == "full":
+            yield ("paged-mixed32-full",
+                   lambda q, k, v, t, p: fa._fused_paged_decode(q, k, v, t, p, 32768, 0.088, False), args, None)
+        else:
+            yield ("paged-mixed32-window",
+                   lambda q, k, v, t, p, f: fa._fused_paged_decode_window(q, k, v, t, p, f, 32768, 0.088, False),
+                   args + [((32,), jnp.int32, None)], None)
     for cp, mp in ((1, 4), (2, 2)):  # the shard_map wrappers
         for quant in (False, True):
             yield (f"paged-cp{cp}-mp{mp}-{'int8' if quant else 'bf16'}",

@@ -491,10 +491,16 @@ def _routed_experts(cfg, x, experts, wts, live, w1, w3, w2):
 
     A step of at most B tokens whose picks number the router's width or more
     (`T * K >= n_routed_experts`: every held expert expects a token or more,
-    which is a deployment's decode batch) takes `_all_held_experts` instead:
-    nearly every expert would be a block of its own, each costing the loop's
-    fixed work on top of the expert's bytes, and the step's time would follow
-    which experts the seed's router happens to hit (PERF.md, PR 33).
+    which is a deployment's decode batch) takes the `grouped_experts` kernel
+    (`ops/grouped_experts.py`) instead, on the TPU: nearly every expert would
+    be a block of its own here, each costing the loop's fixed work (32 us a
+    hit against 14 us of an expert's bytes, PERF.md, PR 33) on top of the
+    expert's bytes.  The kernel walks the same hit experts, pays a copy's
+    bytes a hit and nothing else, and overlaps one expert's dots with the
+    next one's copy; the router's weight goes into the middle product's rows
+    and the sum over experts stays in float32.  Off the TPU (and for a shape
+    the kernel refuses, which counts as a Pallas fallback) such a step takes
+    the loop as well: it is the one XLA form.
     Returns (y [T, hidden] f32, [tokens, picks held, experts hit, max load])."""
     import jax
     import jax.numpy as jnp
@@ -506,12 +512,22 @@ def _routed_experts(cfg, x, experts, wts, live, w1, w3, w2):
     local = experts - cfg.expert_offset
     mine = (local >= 0) & (local < held) & live[:, None]
     key = jnp.where(mine, local, held).reshape(-1)
-    counts = jnp.sum(key[:, None] == jnp.arange(held, dtype=jnp.int32)[None, :], axis=0,
-                     dtype=jnp.int32)
+    hot = key[:, None] == jnp.arange(held, dtype=jnp.int32)[None, :]  # [T * K, held]: a pick, its held expert
+    counts = jnp.sum(hot, axis=0, dtype=jnp.int32)
     stats = jnp.stack([jnp.sum(live, dtype=jnp.int32), jnp.sum(counts), jnp.sum(counts > 0, dtype=jnp.int32),
                        jnp.max(counts)])
     if T <= B and T * K >= cfg.n_routed_experts:
-        return _all_held_experts(x, jnp.where(mine, local, held), wts, w1, w3, w2), stats
+        from ..ops import flash_attention as fa
+        from ..ops import grouped_experts as ge
+
+        interpret = fa._FORCE_INTERPRET
+        if interpret or fa._on_tpu():
+            reason = None if interpret else ge.refusal(x, w1)  # the interpreter takes any shape
+            if reason is None:
+                fa._log_pallas_call("grouped_experts")
+                weight = jnp.sum(jnp.where(hot, wts.reshape(-1, 1), 0.0).reshape(T, K, held), axis=1)
+                return ge.grouped_experts(x, weight, *ge.hit_list(counts), w1, w3, w2, interpret), stats
+            fa._log_pallas_fallback("grouped_experts: " + reason, shape=x.shape)
     order = jnp.argsort(key, stable=True).astype(jnp.int32)
     blocks = (counts + B - 1) // B
     blk_end = jnp.cumsum(blocks)
@@ -531,24 +547,6 @@ def _routed_experts(cfg, x, experts, wts, live, w1, w3, w2):
         return y.at[tok].add(out.astype(jnp.float32) * wt[:, None])
 
     return lax.fori_loop(0, blk_end[-1], body, jnp.zeros(x.shape, jnp.float32)), stats
-
-
-def _all_held_experts(x, local, wts, w1, w3, w2):
-    """Every held expert's SwiGLU over every token of a small step, weighted
-    by the router, 0 where the token did not pick the expert (`local` [T, K]:
-    a pick's index among the held experts, `held` where it is not one):
-    the same sum as the loop's, each expert's output rounded as there, in
-    three batched matmuls that stream every held expert once."""
-    import jax
-    import jax.numpy as jnp
-
-    T, held = x.shape[0], w1.shape[0]
-    weight = jnp.zeros((T, held), jnp.float32).at[jnp.arange(T)[:, None], local].add(wts, mode="drop")
-    xb = jnp.broadcast_to(x[None], (held,) + x.shape)
-    mid = jax.nn.silu(jnp.einsum("etd,edf->etf", xb, w1)) * jnp.einsum("etd,edf->etf", xb, w3)
-    out = jnp.einsum("etf,efd->etd", mid, w2).astype(jnp.float32)
-    w_et = weight.T[:, :, None]
-    return jnp.sum(jnp.where(w_et > 0, out * w_et, 0.0), axis=0)
 
 
 def _moe(cfg, w, x, live):

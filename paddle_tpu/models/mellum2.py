@@ -25,8 +25,9 @@ layer_types[l]`:
 - experts: `p = softmax(x W_g)` in float32 over `num_experts`; the
   `num_experts_per_tok` largest (softmax before top-k) (assumed); `w = p_sel /
   sum(p_sel)` (`norm_topk_prob`); `sum_e w_e W_down,e (silu(W_gate,e x) *
-  W_up,e x)`.  `_route_softmax`, `_routed_experts` and `_all_held_experts` are
-  `deepseek_v32.py`'s, every expert held (`expert_offset` 0).
+  W_up,e x)`.  `_route_softmax` and `_routed_experts` (a decode step through
+  the `grouped_experts` kernel) are `deepseek_v32.py`'s, every expert held
+  (`expert_offset` 0).
 - `intermediate_size` is read by no layer (every `mlp_layer_types` entry is
   `sparse`; another is refused).  Not built: the MTP head the model card
   mentions (no key of the config describes it).

@@ -342,6 +342,28 @@ def paged_walk_summary():
         return [dict(zip(_PAGED_WALK_FIELDS, key)) for key in _paged_walks]
 
 
+_GROUPED_EXPERTS_FIELDS = ("tokens", "held", "expert_bytes", "experts_in_flight", "grid_steps")
+_grouped_experts = {}  # a tuple of `_GROUPED_EXPERTS_FIELDS` a distinct expert layer, in order of first trace
+
+
+def record_grouped_experts(**geometry):
+    """The geometry of one traced `grouped_experts` call (ops/grouped_experts.py),
+    recorded at trace time like `record_paged_walk`."""
+    key = tuple(int(geometry[f]) for f in _GROUPED_EXPERTS_FIELDS)
+    with _counters_lock:
+        _grouped_experts[key] = None
+
+
+def grouped_experts_summary():
+    """One entry per distinct expert layer traced through the grouped-expert
+    kernel since the last reset (a model's layers trace the same one): the
+    step's `tokens`, the experts `held`, the bytes of one expert's three
+    matrices, the experts whose copies are in flight or computing, and the
+    grid steps a call (1: the walk over the hit experts loops inside it)."""
+    with _counters_lock:
+        return [dict(zip(_GROUPED_EXPERTS_FIELDS, key)) for key in _grouped_experts]
+
+
 def reset():
     """Zero EVERY counter family (step, serving, paging, router, flash
     fallbacks) in one critical section, so one run's router/serving gauges
@@ -362,6 +384,7 @@ def reset():
         _flash_fallbacks.clear()
         _flash_pallas.clear()
         _paged_walks.clear()
+        _grouped_experts.clear()
         _reset_moe_locked()
 
 

@@ -30,6 +30,7 @@ from paddle_tpu.models import LlamaConfig, LlamaForCausalLM  # noqa: E402
 from paddle_tpu.models import Ling3Config, Ling3ForCausalLM  # noqa: E402
 from paddle_tpu.models import deepseek_v32 as dsv  # noqa: E402
 from paddle_tpu.models import ling3 as L  # noqa: E402
+from paddle_tpu.ops import flash_attention as fa  # noqa: E402
 
 SEED = 3_300_000_017  # past 2**31, as the driver's seeds are
 INIT = {"matrix_std": 0.05, "router_bias_std": 0.01, "conv_std": 0.5, "kda_A_log_max": 1.386,
@@ -157,12 +158,13 @@ def test_the_shares_routed_parts_and_the_shared_expert_once_add_up_to_the_uncut_
     np.testing.assert_allclose(routed + shared, want, rtol=1e-4, atol=1e-5)
 
 
+@pytest.mark.parametrize("small_step", ["the_loop_off_the_tpu", "the_kernel"])
 @pytest.mark.parametrize("rows", [4, 24])
-def test_the_two_forms_of_the_expert_layer_give_one_sum(rows):
-    """A small step whose picks cover the router's width computes every held
-    expert over every token (`_all_held_experts`), any other step loops over
-    the blocks in use: the same 24 tokens through one form and, three at a
-    time, through the other."""
+def test_the_two_forms_of_the_expert_layer_give_one_sum(rows, small_step):
+    """A small step whose picks cover the router's width takes the
+    `grouped_experts` kernel (interpreted here; off the TPU the loop), any
+    other step loops over the blocks in use: the same 24 tokens through one
+    form and, three at a time, through the other."""
     cfg = config()
     x = normal(8, 24, cfg.hidden_size)
     w = moe_weights(layer_leaves(cfg, 2))
@@ -170,15 +172,23 @@ def test_the_two_forms_of_the_expert_layer_give_one_sum(rows):
     experts, wts = dsv._route(cfg, x, w["gate.weight"], w["gate.e_score_correction_bias"])
     mats = (w["experts.gate_proj"], w["experts.up_proj"], w["experts.down_proj"])
     assert 3 * cfg.num_experts_per_tok < cfg.num_experts <= rows * cfg.num_experts_per_tok
-    whole, stats = dsv._routed_experts(cfg, x, experts, wts, live, *mats)
-    parts = [dsv._routed_experts(cfg, x[i:i + 3], experts[i:i + 3], wts[i:i + 3], live[i:i + 3], *mats)[0]
-             for i in range(0, 24, 3)]
-    np.testing.assert_allclose(whole, jnp.concatenate(parts), rtol=1e-5, atol=1e-6)
-    held = np.asarray((experts >= 4) & (experts < 8) & live[:, None])
-    assert [int(v) for v in stats[:2]] == [23, int(held.sum())] and not np.asarray(whole[5]).any()
-    if rows == 4:  # the form is chosen from the step's static shape alone
-        few = dsv._routed_experts(cfg, x[:4], experts[:4], wts[:4], live[:4], *mats)[0]
-        np.testing.assert_allclose(few, whole[:4], rtol=1e-5, atol=1e-6)
+    calls = lambda: profiler.flash_pallas_summary().get("grouped_experts", 0)
+    old, fa._FORCE_INTERPRET = fa._FORCE_INTERPRET, small_step == "the_kernel"
+    try:
+        before = calls()
+        whole, stats = dsv._routed_experts(cfg, x, experts, wts, live, *mats)
+        assert calls() - before == (small_step == "the_kernel")
+        parts = [dsv._routed_experts(cfg, x[i:i + 3], experts[i:i + 3], wts[i:i + 3], live[i:i + 3], *mats)[0]
+                 for i in range(0, 24, 3)]
+        assert calls() - before == (small_step == "the_kernel")  # three tokens' picks do not cover the router
+        np.testing.assert_allclose(whole, jnp.concatenate(parts), rtol=1e-5, atol=1e-6)
+        held = np.asarray((experts >= 4) & (experts < 8) & live[:, None])
+        assert [int(v) for v in stats[:2]] == [23, int(held.sum())] and not np.asarray(whole[5]).any()
+        if rows == 4:  # the form is chosen from the step's static shape alone
+            few = dsv._routed_experts(cfg, x[:4], experts[:4], wts[:4], live[:4], *mats)[0]
+            np.testing.assert_allclose(few, whole[:4], rtol=1e-5, atol=1e-6)
+    finally:
+        fa._FORCE_INTERPRET = old
 
 
 def kda_through_the_cache(cfg, w, x, n, cut, fault=None):
@@ -313,8 +323,6 @@ def test_mla_decode_walks_the_pages_in_the_kernel_as_it_gathers_them():
     """The Pallas page walk (interpreted here) over the one latent arena, its
     rows the keys and the values (`arena_v=None`), gives what the gathered
     context gives."""
-    from paddle_tpu.ops import flash_attention as fa
-
     cfg = config()
     w = attn_weights(layer_leaves(cfg, 4))
     cos, sin = ref.rope_tables(as_dict(cfg), 64)

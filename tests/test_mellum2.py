@@ -175,21 +175,30 @@ def test_softmax_router_picks_and_weights_as_the_reference_does():
     assert (np.asarray(raw).sum(1) < 1.0).all()
 
 
-@pytest.mark.parametrize("tokens", [3, 40], ids=["every_expert_over_every_token", "the_block_loop"])
-def test_expert_sum_with_all_experts_held_is_the_references(tokens):
-    """3 tokens x 2 picks < 8 experts takes the loop as well; 4 x 2 >= 8 the
-    second form: both are the reference's sum."""
+@pytest.mark.parametrize("tokens,kernel", [(3, False), (3, True), (40, False)],
+                         ids=["small_step_off_the_tpu", "small_step_through_the_kernel", "the_block_loop"])
+def test_expert_sum_with_all_experts_held_is_the_references(tokens, kernel):
+    """3 tokens x 2 picks < 8 experts takes the loop; 4 x 2 >= 8 is a small
+    step, which takes the `grouped_experts` kernel (interpreted here; off
+    the TPU the loop as well): all are the reference's sum."""
     cfg = Mellum2Config.tiny()
     lw = layer_leaves(cfg, 2)
     w = {k[len("mlp."):]: v for k, v in lw.items() if k.startswith("mlp.")}
-    for n in (tokens, tokens + 1):
-        x = normal(n, n, cfg.hidden_size)
-        live = jnp.ones((n,), bool).at[0].set(False)
-        y, stats = M._moe(cfg, w, x, live)
-        want = np.asarray(ref.moe(as_dict(cfg), f32_linear, lw, x))
-        np.testing.assert_allclose(np.asarray(y)[1:], want[1:], rtol=2e-4, atol=2e-6)
-        assert np.abs(np.asarray(y)[0]).max() == 0  # a row that is not live routes nowhere
-        assert int(stats[0]) == n - 1 and int(stats[1]) == 2 * (n - 1)
+    calls = lambda: profiler.flash_pallas_summary().get("grouped_experts", 0)
+    fa._FORCE_INTERPRET = kernel
+    try:
+        for n in (tokens, tokens + 1):
+            x = normal(n, n, cfg.hidden_size)
+            live = jnp.ones((n,), bool).at[0].set(False)
+            before = calls()
+            y, stats = M._moe(cfg, w, x, live)
+            assert calls() - before == (kernel and n == 4)
+            want = np.asarray(ref.moe(as_dict(cfg), f32_linear, lw, x))
+            np.testing.assert_allclose(np.asarray(y)[1:], want[1:], rtol=2e-4, atol=2e-6)
+            assert np.abs(np.asarray(y)[0]).max() == 0  # a row that is not live routes nowhere
+            assert int(stats[0]) == n - 1 and int(stats[1]) == 2 * (n - 1)
+    finally:
+        fa._FORCE_INTERPRET = False
 
 
 # -- the walk that starts at the window ---------------------------------------------------

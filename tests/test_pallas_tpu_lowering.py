@@ -30,6 +30,7 @@ from jax.sharding import NamedSharding, PartitionSpec as P, SingleDeviceSharding
 
 from paddle_tpu.distributed import mesh as pmesh
 from paddle_tpu.ops import flash_attention as fa
+from paddle_tpu.ops import grouped_experts as ge
 
 BF16 = jnp.bfloat16
 MAX_LEN = 1024
@@ -175,7 +176,22 @@ def _paged_cases():
                    {"cp": cp, "mp": mp})
 
 
-CASES = list(_flash_cases()) + list(_paged_cases())
+def _grouped_expert_cases():
+    """The expert layer of a decode step at the two serving cells' shapes
+    (`ling3_serve.reason64`: 64 tokens, 128 of 512 experts held, 2560 x 768;
+    `mellum2_serve.mixed32`: 32 tokens, all 64 experts, 2304 x 896 = 7 x 128):
+    two experts in flight are 23.6 and 24.8 MB of scoped VMEM."""
+    for tag, T, held, D, I in (("reason64", 64, 128, 2560, 768), ("mixed32", 32, 64, 2304, 896)):
+        def fn(x, weight, counts, w1, w3, w2):
+            assert ge.refusal(x, w1) is None
+            return ge.grouped_experts(x, weight, *ge.hit_list(counts), w1, w3, w2, False)
+
+        up, down = ((held, D, I), BF16, None), ((held, I, D), BF16, None)
+        yield (f"grouped-experts-{tag}", fn,
+               [((T, D), BF16, None), ((T, held), jnp.float32, None), ((held,), jnp.int32, None), up, up, down], None)
+
+
+CASES = list(_flash_cases()) + list(_paged_cases()) + list(_grouped_expert_cases())
 IDS = [c[0] for c in CASES]
 
 

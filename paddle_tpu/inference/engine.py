@@ -278,6 +278,47 @@ class _Unfetched:
         self.stats, self.first, self.eager = stats, first, eager
 
 
+# the phases of a scheduler tick: `profiler.TICK_PHASES`, by index.  The first
+# six are spans under FLAGS_trace (`engine.tick.<phase>`), `other` is counted
+# only
+_EVICT, _ADMIT, _PREPARE, _DISPATCH, _WAIT, _DELIVER, _OTHER = range(7)
+
+
+class _TickClock:
+    """Where one `step()`'s wall time went, by phase.  One `perf_counter`
+    stamp at every phase boundary: the seconds since the stamp before go to
+    the phase that was current, so the phases are disjoint and add up to the
+    tick.  A flush notes the phase it found, enters `wait` and `deliver`, and
+    enters what it found again when it is done: its seconds come out of
+    whatever enclosed it.  Outside a tick (`stop()`'s flush) no phase is
+    current and nothing is counted; `enter` still returns its stamp.  `first`
+    is when the tick first entered a phase (its span's start, where the phase
+    gets one); `step` is the step the tick dispatched, if it did: (slots in
+    it, requests queued, `busy_s`), for `record_serving_tick`; `n` is the
+    tick's ordinal in the engine's life.  Floats on the host, touched by the
+    scheduler alone: no lock, and legal inside the sanitizer's steady-state
+    zone."""
+
+    __slots__ = ("n", "phase", "mark", "secs", "first", "step")
+
+    def __init__(self):
+        self.n, self.phase = 0, None
+
+    def open(self):
+        self.n += 1
+        self.secs, self.first, self.step = [0.0] * 7, [None] * 7, None
+        self.phase, self.mark = _OTHER, time.perf_counter()
+
+    def enter(self, phase):
+        now = time.perf_counter()
+        if self.phase is not None:
+            self.secs[self.phase] += now - self.mark
+            self.phase, self.mark = phase, now
+            if phase is not None and self.first[phase] is None:
+                self.first[phase] = now
+        return now
+
+
 class EngineRequest:
     """Handle for one submitted generation: streaming callback target,
     completion event, deadline/cancellation, and timing for the serving
@@ -750,6 +791,16 @@ class ContinuousBatchingEngine:
         # open decode-epoch summary for tracing: {"t0", "ticks", "members"},
         # one engine.decode span per traced member when membership changes
         self._ep = None
+        # the traced flushes since the last epoch close, folded: [first
+        # flush's start, last flush's end, flushes, entries fetched, seconds
+        # blocked in the fetch]; one engine.fetch span per traced member
+        # beside its engine.decode.  None with tracing off
+        self._fold = None
+        # the scheduler's clock of the tick in progress, and the trace the
+        # engine's own spans (engine.tick.<phase>, one a phase a tick under
+        # FLAGS_trace) go under: the engine's, no request's
+        self._clock = _TickClock()
+        self.trace_id = _obs.new_trace_id()
         # programs dispatched whose tokens the host has not fetched, in
         # dispatch order: [_Unfetched]
         self._pending_fetch = []
@@ -1610,15 +1661,54 @@ class ContinuousBatchingEngine:
             if self._warmed and _san.enabled()
             else contextlib.nullcontext()
         )
+        clk = self._clock
+        clk.open()
         with ctx:
-            self._evict_expired(gen)
+            clk.enter(_EVICT)
+            evicted = self._evict_expired(gen)
+            clk.enter(_ADMIT)
             emitted = self._admit(gen)
+            clk.enter(_PREPARE)
             n = emitted + self._decode_once(gen)
+            clk.enter(_OTHER)
         if _fcore.flag("FLAGS_serve_debug_invariants"):
             self._check_invariants()
         # analysis: allow GRAFT010 — liveness stamp: a raced write only delays the watchdog one tick
         self._last_progress = time.monotonic()
+        self._tick_close(evicted, emitted)
         return n
+
+    def _tick_close(self, evicted, admitted):
+        """The tick's phases go to the serving gauges, with the step it
+        dispatched if it dispatched one (the one `record_serving_tick` a
+        tick), and under FLAGS_trace to one span a phase, from the clock's
+        own stamps: `engine.tick.evict` only if something was evicted,
+        `.admit` only if a request was seated, the others if the tick entered
+        them.  A span starts where the tick first entered its phase and lasts
+        the phase's seconds of the tick (a flush of several entries enters
+        `wait` and `deliver` in turn).  Siblings under the engine's trace id,
+        no span around them."""
+        from .. import profiler as _prof
+
+        clk = self._clock
+        now = clk.enter(None)
+        live, queued, busy_s = clk.step or (None, 0, 0.0)
+        _prof.record_serving_tick(
+            None if live is None else live / self.slots, queued, busy_s,
+            phases=clk.secs, end_s=now,
+        )
+        if not _obs.enabled():
+            return
+        for ph in range(_OTHER):
+            t0 = clk.first[ph]
+            if t0 is None or (ph == _EVICT and not evicted) or (
+                ph == _ADMIT and not admitted
+            ):
+                continue
+            _obs.record(
+                "engine.tick." + _prof.TICK_PHASES[ph], self.trace_id,
+                t0=t0, t1=t0 + clk.secs[ph], tick=clk.n, live=live,
+            )
 
     def run_until_idle(self):
         """Drive step() until queue and slots are empty (synchronous mode)."""
@@ -1761,7 +1851,7 @@ class ContinuousBatchingEngine:
             # first token) — stale host n-gram state must not outlive the
             # slot assignment it indexed
             self._drafters = [None] * self.slots
-            self._ep = None  # epoch members were restarted; drop, don't record
+            self._ep = self._fold = None  # epoch members were restarted; drop, don't record
             self._dev = None
             self._toks_t = None  # nothing unfetched is kept: _last_tok is whole
             self._pending_fetch = []
@@ -2030,7 +2120,8 @@ class ContinuousBatchingEngine:
     def _evict_expired(self, gen):
         """Evict cancelled/deadline-expired slots at step granularity: flush
         the tokens already dispatched, then recycle the slot (no recompile)
-        and resolve the request with its typed error."""
+        and resolve the request with its typed error.  Returns how many it
+        found to evict."""
         with self._mu:
             self._check_gen(gen)
             now = time.perf_counter()
@@ -2044,7 +2135,7 @@ class ContinuousBatchingEngine:
                 elif req.expired(now):
                     victims.append((s, req, "timeout"))
             if not victims:
-                return
+                return 0
             # emit what was already dispatched: a victim keeps every token
             # it was given a step for
             self._flush_pending_locked(cause="evict")
@@ -2059,6 +2150,7 @@ class ContinuousBatchingEngine:
                         req.deadline_s,
                     )
                 self._finish(s, req, reason)
+            return len(victims)
 
     def _pop_request(self):
         """Next admissible request (restart-requeued work first), resolving
@@ -2624,6 +2716,8 @@ class ContinuousBatchingEngine:
                 pz = np.zeros(self.slots, bool)
                 pz[poisoned] = True
                 poison_t = to_tensor(pz)
+        clk = self._clock
+        clk.enter(_DISPATCH)
         with self._watchdog.arm(
             "serve.decode", timeout=self._wd_timeout(),
             context=f"{len(run)} active slots",
@@ -2632,6 +2726,7 @@ class ContinuousBatchingEngine:
                 toks_t, pos_t, active_t, temps_t, poison_t, key,
                 self._tables_t, self._adapters_t,
             )
+        clk.enter(_OTHER)
         with self._mu:
             self._check_gen(gen)
             self._key = key
@@ -2673,9 +2768,8 @@ class ContinuousBatchingEngine:
                 self._flush_pending_locked(keep=1)
             if self._ep is not None:
                 self._ep["ticks"] += 1
-            _prof.record_serving_tick(
-                len(run) / self.slots, self._queue.qsize(),
-                time.perf_counter() - t0,
+            clk.step = (
+                len(run), self._queue.qsize(), time.perf_counter() - t0
             )
             _prof.record_paging_tick(
                 self._pool.used_count(), self._pool.usable_pages
@@ -2762,6 +2856,8 @@ class ContinuousBatchingEngine:
                 poison_t = to_tensor(pz)
             toks_t = to_tensor(toks)
             vl_t = to_tensor(vl)
+        clk = self._clock
+        clk.enter(_DISPATCH)
         with self._watchdog.arm(
             "serve.decode", timeout=self._wd_timeout(),
             context=f"{len(active_idx)} active slots (spec k={self.spec_k})",
@@ -2770,10 +2866,12 @@ class ContinuousBatchingEngine:
                 toks_t, pos_t, active_t, vl_t, temps_t, poison_t, key,
                 self._tables_t, self._adapters_t,
             )
+        clk.enter(_OTHER)
         with self._mu:
             self._check_gen(gen)
             self._key = key
             self._dev = (new_pos, active_t, temps_t)
+            t_w0 = clk.enter(_WAIT)
             with self._watchdog.arm(
                 "serve.fetch", timeout=self._wd_timeout(),
                 context=f"verify fetch ({len(active_idx)} slots)",
@@ -2781,10 +2879,12 @@ class ContinuousBatchingEngine:
                 out_np = np.asarray(out.numpy())
                 n_np = np.asarray(n_emit.numpy()).reshape(-1)
                 fin_np = np.asarray(finite.numpy()).reshape(-1)
+            now = clk.enter(_DELIVER)
+            if _obs.enabled():
+                self._fold_fetch(t_w0, now)
             # a restart that could not take the mutex may have superseded
             # us mid-fetch — bail before touching the new life's slot table
             self._check_gen(gen)
-            now = time.perf_counter()
             per = now - t0
             self._step_ewma_s = (
                 per if self._step_ewma_s is None
@@ -2826,9 +2926,8 @@ class ContinuousBatchingEngine:
             if self._ep is not None:
                 self._ep["ticks"] += 1
                 self._ep["accepted"] += accepted
-            _prof.record_serving_tick(
-                len(active_idx) / self.slots, self._queue.qsize(),
-                time.perf_counter() - t0,
+            clk.step = (
+                len(active_idx), self._queue.qsize(), clk.enter(_OTHER) - t0
             )
             _prof.record_paging_tick(
                 self._pool.used_count(), self._pool.usable_pages
@@ -2853,7 +2952,7 @@ class ContinuousBatchingEngine:
         Host-side bookkeeping only — a dict, no tensor touches — so it is
         legal inside the sanitizer's steady-state zone."""
         if not _obs.enabled():
-            self._ep = None
+            self._ep = self._fold = None
             return
         members = [(s, self._slot_req[s]) for s in active_idx]
         if not any(r.trace for _, r in members):
@@ -2870,11 +2969,13 @@ class ContinuousBatchingEngine:
         engine.decode span per traced member request — plus, when
         speculation is on, an engine.verify span carrying the epoch's
         proposed/accepted draft counts (the trace-visible acceptance
-        evidence ISSUE 11 requires)."""
+        evidence ISSUE 11 requires), and an engine.fetch span that folds the
+        epoch's flushes.  Returns the members it recorded for."""
         ep, self._ep = self._ep, None
         if not ep or not ep["ticks"]:
-            return
+            return ()
         t1 = time.perf_counter()
+        fold, self._fold = self._fold, None
         for s, req in ep["members"]:
             if req.trace:
                 _obs.record(
@@ -2883,6 +2984,7 @@ class ContinuousBatchingEngine:
                     ticks=ep["ticks"],
                     adapter=req.adapter.name if req.adapter is not None else None,
                 )
+                self._record_fetch(req, fold)
                 if self._spec_on:
                     _obs.record(
                         "engine.verify", req.trace[0], t0=ep["t0"], t1=t1,
@@ -2890,6 +2992,31 @@ class ContinuousBatchingEngine:
                         ticks=ep["ticks"], proposed=ep["proposed"],
                         accepted=ep["accepted"],
                     )
+        return ep["members"]
+
+    def _fold_fetch(self, t0, t1, new_flush=True):
+        """A fetch blocked from t0 to t1 under FLAGS_trace (the first of its
+        flush, or a later entry's): into the fold of the flushes since the
+        last epoch close.  Caller holds _mu."""
+        f = self._fold
+        if f is None:
+            f = self._fold = [t0, t1, 0, 0, 0.0]
+        f[1] = t1
+        f[2] += new_flush
+        f[3] += 1
+        f[4] += t1 - t0
+
+    def _record_fetch(self, req, fold):
+        """One engine.fetch span in a traced request's tree for a whole fold
+        (an epoch's flushes, as a rule): `fetches` flushes took `steps`
+        entries off the device and blocked `wait_s` seconds doing it.  What a
+        single tick's fetch took is `engine.tick.wait`."""
+        if fold is not None:
+            _obs.record(
+                "engine.fetch", req.trace[0], t0=fold[0], t1=fold[1],
+                parent_id=req.trace[1], req=req.id, fetches=fold[2],
+                steps=fold[3], wait_s=fold[4],
+            )
 
     def _flush_pending_locked(self, keep=0, cause=None):
         """Fetch every dispatched-but-unfetched entry (but the newest
@@ -2913,11 +3040,16 @@ class ContinuousBatchingEngine:
             _prof.record_serving_drain(cause)
         gen0 = self._gen
         batches, self._pending_fetch = self._pending_fetch[:n], self._pending_fetch[n:]
-        t_f0 = time.perf_counter()
+        # the tick's clock: blocked in the fetch is `wait`, the rest of the
+        # flush `deliver`, and both come out of the phase the flush found
+        clk, traced = self._clock, _obs.enabled()
+        outer = clk.phase
+        t_f0 = clk.enter(_DELIVER)
         steps, t_first, t_step = 0, None, t_f0
         # entry by entry, each delivered as soon as it is fetched: a step's
         # tokens do not wait for the prefill dispatched behind it
-        for e in batches:
+        for i, e in enumerate(batches):
+            t_w0 = clk.enter(_WAIT)
             with self._watchdog.arm(
                 "serve.fetch", timeout=self._wd_timeout(),
                 context=f"{len(batches)} buffered steps",
@@ -2927,8 +3059,10 @@ class ContinuousBatchingEngine:
                     fin_np = np.asarray(e.finite.numpy()).reshape(-1)
                 if e.stats:  # the model's own counters of the step, same fetch
                     self.model.record_step_stats(np.asarray(e.stats[0].numpy()))
+            now = clk.enter(_DELIVER)
+            if traced:
+                self._fold_fetch(t_w0, now, new_flush=i == 0)
             self._check_gen(gen0)
-            now = time.perf_counter()
             if e.first is None:
                 steps, t_step = steps + 1, now
                 t_first = e.t0 if t_first is None else t_first
@@ -2958,13 +3092,7 @@ class ContinuousBatchingEngine:
                     tok = int(nxt_np[s])
                     self._last_tok[s] = tok
                     self._emit(s, req, tok)
-        now = time.perf_counter()
-        if _obs.enabled():
-            flushed = {r.id: r for e in batches for _, r in e.pairs if r.trace}
-            for r in flushed.values():
-                _obs.record("engine.fetch", r.trace[0], t0=t_f0, t1=now,
-                            parent_id=r.trace[1], req=r.id,
-                            steps=len(batches))
+        now = clk.enter(outer)
         # EWMA decode-round wall time: dispatch-to-fetch of this burst's
         # decode steps (from the fetch before it, where its first step was
         # dispatched behind a step still in flight) over their count — feeds
@@ -3040,7 +3168,12 @@ class ContinuousBatchingEngine:
             # stays warm until arena LRU pressure needs its slot
             self._slot_adapter[s] = 0
             self._release_adapter_locked(req)
-        self._obs_epoch_close()
+        closed = self._obs_epoch_close()
+        if req.trace and not any(r is req for _, r in closed):
+            # it leaves outside an epoch it ran in (a first token was its
+            # last, an eviction before its first step): its tree still gets
+            # the fetches so far, the one that delivered to it among them
+            self._record_fetch(req, self._fold)
         self._dev = None  # membership changed: upload the host's part anew
         _prof.record_membership_change()
         self._resolve(req, reason)

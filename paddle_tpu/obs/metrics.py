@@ -18,6 +18,8 @@ Metric-name reference (the stable surface the scrape test pins):
     paddle_train_host_blocked_seconds_total
     paddle_train_wall_seconds_total     paddle_serving_ticks_total
     paddle_train_inflight_max           paddle_serving_busy_seconds_total
+    paddle_serving_tick_phase_seconds_total{phase="evict"|"admit"|"prepare"|
+        "dispatch"|"wait"|"deliver"|"other"}
     paddle_serving_ttft_seconds{quantile="0.5"|"0.95"}
     paddle_serving_occupancy_mean / _peak
     paddle_serving_queue_depth_max
@@ -159,6 +161,13 @@ def render(labels=None):
             "engine decode scheduler ticks")
     exp.add("paddle_serving_busy_seconds_total", g["busy_s"],
             "summed decode-step wall seconds (the tokens/s busy window)")
+    # all seven phases, at 0 before any traffic: wall seconds of every
+    # scheduler tick; host seconds are their sum less phase="wait"
+    for phase, sec in g["tick"]["phases_s"].items():
+        exp.add("paddle_serving_tick_phase_seconds_total", sec,
+                "scheduler tick wall seconds by phase (disjoint; 'wait' is "
+                "the host blocked on the device)", "counter",
+                {"phase": phase})
     ttfts = sorted(g["ttfts_s"])
     for q in (0.5, 0.95):
         exp.add("paddle_serving_ttft_seconds", _pctl(ttfts, q),

@@ -13,6 +13,13 @@ serving path pays one dict lookup per would-be span.  Context *minting* is
 always on — error bodies carry a ``trace_id`` even when span recording is
 off, so a 502 can be joined to its span tree the moment tracing is enabled.
 
+What a record costs (``timeit`` on the sandbox's CPU, PR 37; the engine's hot
+loop pays it whenever tracing is on, so the engine records at most six spans a
+tick, not one a request): ``record()`` with the flag on 4.4 us (8.4 us while
+an id was a ``uuid.uuid4()``, 3.0 us of it the id), with the flag off 0.44 us;
+an id 0.38 us; ``enabled()`` 0.08 us.  Ids are a per-process random prefix and
+a counter (``_seed_ids``).
+
 Cross-process propagation rides two hop headers next to ``X-Deadline-Ms``:
 
     X-Trace-Id:    16-hex trace id, same for every hop of one request
@@ -26,10 +33,10 @@ The buffer is queryable as flat spans (``spans``), a per-request tree
 
 import collections
 import contextlib
+import itertools
 import os
 import threading
 import time
-import uuid
 
 from ..framework import core as _core
 
@@ -62,12 +69,26 @@ def enabled():
         return False
 
 
+def _seed_ids():
+    """Ids are 16 hex: 8 the process drew from the OS once, 8 a counter that
+    starts at a drawn value.  Two processes mint the same id only if they
+    drew the same prefix (1 in 2**32 a pair) AND their counters overlap; a
+    forked child draws anew.  `count.__next__` is one C call under the GIL:
+    no lock."""
+    global _id_prefix, _id_next
+    _id_prefix = os.urandom(4).hex()
+    _id_next = itertools.count(int.from_bytes(os.urandom(4), "big")).__next__
+
+
+_seed_ids()
+os.register_at_fork(after_in_child=_seed_ids)
+
+
 def new_trace_id():
-    return uuid.uuid4().hex[:16]
+    return f"{_id_prefix}{_id_next() & 0xFFFFFFFF:08x}"
 
 
-def new_span_id():
-    return uuid.uuid4().hex[:16]
+new_span_id = new_trace_id
 
 
 def ctx_from_headers(headers):

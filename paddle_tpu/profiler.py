@@ -138,6 +138,23 @@ def step_breakdown():
 
 _TTFT_KEEP = 10000  # bound the percentile buffer; serving runs are long
 
+# where a scheduler tick's wall time goes (the engine's `step()`, stamped at
+# the phase boundaries; `inference/engine.py` `_TickClock`): evicting, admitting
+# (prefill dispatch included), preparing the decode step's operands,
+# dispatching it, blocked in the fetch of tokens (`wait`: the one phase in
+# which the host waits on the device), delivering them (callbacks,
+# finishes), and the rest.  Disjoint; they add up to the tick
+TICK_PHASES = (
+    "evict", "admit", "prepare", "dispatch", "wait", "deliver", "other",
+)
+_TICK_LONGEST_KEEP = 8
+
+
+def _new_tick_gauges():
+    return {"steps": 0, "wall_s": 0.0, "phases_s": [0.0] * len(TICK_PHASES),
+            "longest": []}
+
+
 _serving_gauges = {
     "requests": 0,
     "tokens": 0,
@@ -157,6 +174,9 @@ _serving_gauges = {
     # engine let NOTHING stay in flight on the device, by what forced it
     "membership_changes": 0,
     "drains": {},
+    # every `step()` of the engine by phase (TICK_PHASES), and the longest:
+    # `_tick_view` renders it
+    "tick": _new_tick_gauges(),
 }
 
 # serving fault-domain counter kinds (PR 6): engine restarts, requests
@@ -225,19 +245,58 @@ def record_serving_request(ttft_s, tokens, wall_s):
             del g["ttfts_s"][: -_TTFT_KEEP]
 
 
-def record_serving_tick(occupancy, queue_depth, busy_s=0.0):
-    """One engine decode step: fraction of slots active, queued requests,
-    and the step's wall time (summed into the busy window for tokens/s)."""
+def record_serving_tick(occupancy, queue_depth=0, busy_s=0.0, phases=None,
+                        end_s=0.0):
+    """One scheduler tick of the engine, in one critical section.  Where it
+    dispatched a decode step: the fraction of slots active in it, the queued
+    requests, and the step's wall time (summed into the busy window for
+    tokens/s); `occupancy` None says it dispatched none.  `phases`: the
+    tick's seconds by TICK_PHASES, counted for EVERY tick (one that only
+    flushes is host time between two device steps all the same); the longest
+    ticks are kept with `end_s`, the `perf_counter` at their end."""
     with _counters_lock:
         g = _serving_gauges
-        g["ticks"] += 1
-        g["occupancy_sum"] += float(occupancy)
-        if occupancy > g["occupancy_peak"]:
-            g["occupancy_peak"] = float(occupancy)
-        g["queue_depth_sum"] += int(queue_depth)
-        g["busy_s"] += float(busy_s)
-        if queue_depth > g["queue_depth_max"]:
-            g["queue_depth_max"] = int(queue_depth)
+        if occupancy is not None:
+            g["ticks"] += 1
+            g["occupancy_sum"] += float(occupancy)
+            if occupancy > g["occupancy_peak"]:
+                g["occupancy_peak"] = float(occupancy)
+            g["queue_depth_sum"] += int(queue_depth)
+            g["busy_s"] += float(busy_s)
+            if queue_depth > g["queue_depth_max"]:
+                g["queue_depth_max"] = int(queue_depth)
+        if phases is not None:
+            t = g["tick"]
+            t["steps"] += occupancy is not None
+            wall, total = 0.0, t["phases_s"]
+            for i, sec in enumerate(phases):
+                total[i] += sec
+                wall += sec
+            t["wall_s"] += wall
+            longest = t["longest"]
+            if len(longest) < _TICK_LONGEST_KEEP or wall > longest[-1][0]:
+                longest.append((wall, end_s, tuple(phases)))
+                longest.sort(reverse=True)
+                del longest[_TICK_LONGEST_KEEP:]
+
+
+def _tick_view(t):
+    """The `tick` block of `serving_summary()` and `metrics_snapshot()`, from
+    the raw gauges (caller holds _counters_lock): `host_s` is the ticks' wall
+    time less the time blocked on the device, `host_ms_mean` that per
+    dispatched step (None before one), `longest` the longest ticks first."""
+    named = lambda secs, k=1.0: {p: k * s for p, s in zip(TICK_PHASES, secs)}
+    wall, wait = t["wall_s"], t["phases_s"][TICK_PHASES.index("wait")]
+    return {
+        "steps": t["steps"], "wall_s": wall, "phases_s": named(t["phases_s"]),
+        "host_s": wall - wait,
+        "host_ms_mean": 1e3 * (wall - wait) / t["steps"] if t["steps"] else None,
+        "wait_share": wait / wall if wall else None,
+        "longest": [
+            {"ms": 1e3 * w, "at_s": at, "phases_ms": named(ph, 1e3)}
+            for w, at, ph in t["longest"]
+        ],
+    }
 
 
 def _reset_serving_locked():
@@ -245,7 +304,7 @@ def _reset_serving_locked():
         requests=0, tokens=0, ttfts_s=[], busy_s=0.0, ticks=0,
         occupancy_sum=0.0, occupancy_peak=0.0, queue_depth_sum=0,
         queue_depth_max=0, faults={}, deadline_miss_rate=0.0,
-        membership_changes=0, drains={},
+        membership_changes=0, drains={}, tick=_new_tick_gauges(),
     )
 
 
@@ -398,6 +457,7 @@ def metrics_snapshot():
         serving["ttfts_s"] = list(serving["ttfts_s"])
         serving["faults"] = dict(serving["faults"])
         serving["drains"] = dict(serving["drains"])
+        serving["tick"] = _tick_view(serving["tick"])
         router = dict(_router_gauges)
         router["replica_states"] = dict(router["replica_states"])
         return {
@@ -1099,16 +1159,19 @@ def serving_summary():
     """Aggregated serving metrics: requests, tokens, aggregate tokens/s over
     the busy window, TTFT p50/p95, mean slot occupancy, queue depth avg/max,
     the slots seated and left (`membership_changes`) and the engine's
-    `drains` by cause (see `_DRAIN_CAUSES`) — plus a nested `speculation`
+    `drains` by cause (see `_DRAIN_CAUSES`), the scheduler's `tick` by phase
+    (see `TICK_PHASES` and `_tick_view`) — plus a nested `speculation`
     block (acceptance rate, tokens/step) when any verify step ran."""
     with _counters_lock:
         g = dict(_serving_gauges)
         g["ttfts_s"] = list(g["ttfts_s"])
         g["faults"] = dict(g["faults"])
         drains = dict(g["drains"])
+        tick = _tick_view(g["tick"])
     out = {"requests": g["requests"], "tokens": g["tokens"],
            "membership_changes": g["membership_changes"],
-           "drains": {c: drains.get(c, 0) for c in _DRAIN_CAUSES}}
+           "drains": {c: drains.get(c, 0) for c in _DRAIN_CAUSES},
+           "tick": tick}
     if g["busy_s"] > 0:
         out["tokens_per_s"] = g["tokens"] / g["busy_s"]
     ttfts = sorted(g["ttfts_s"])

@@ -265,7 +265,8 @@ def test_router_metrics_endpoint_has_role_label(model):
         assert 'role="router"' in line
         assert line.endswith(" 1")
         # the router-side span tree is also served on the front door
-        tid = trace.trace_ids()[-1]
+        # the request's trace: the engine's own (its tick spans) is newer
+        tid = [t for t in trace.trace_ids() if t != eng.trace_id][-1]
         code, text, _ = _get(fURL + f"/trace/{tid}")
         assert code == 200
         names = [s["name"] for s in json.loads(text)["spans"]]
@@ -331,6 +332,222 @@ def test_serving_summary_names_membership_changes_and_drains_by_cause():
     prof.reset_serving()
     s = prof.serving_summary()
     assert (s["membership_changes"], sum(s["drains"].values())) == (0, 0)
+
+
+# ---------------------------------------------------------------------------
+# the scheduler's tick by phase (ISSUE 37): always-on counters in
+# serving_summary()["tick"], one engine.tick.<phase> span a phase a tick under
+# FLAGS_trace, engine.fetch folded to one an epoch
+# ---------------------------------------------------------------------------
+
+PHASES = ("evict", "admit", "prepare", "dispatch", "wait", "deliver", "other")
+
+
+def _engine(model, **kw):
+    kw = {"slots": 4, "max_len": 64, "prefill_buckets": [8],
+          "queue_depth": 16, "seed": 0, **kw}
+    return ContinuousBatchingEngine(model, **kw)
+
+
+def _drive(eng):
+    """step() until idle, synchronously; the number of ticks it took."""
+    n = 0
+    while eng.has_work():
+        eng.step()
+        n += 1
+    return n
+
+
+def _submit(eng, n_new, seed=0, on_token=lambda tok: None, **kw):
+    return eng.submit(_prompt(6, seed), max_new_tokens=n_new,
+                      on_token=on_token, trace=(trace.new_trace_id(), ""), **kw)
+
+
+def test_tick_phases_are_disjoint_and_add_up_to_the_ticks_wall_time(model):
+    eng = _engine(model)
+    for i, n in enumerate((5, 9, 12)):
+        _submit(eng, n, seed=i)
+    t_before = time.perf_counter()
+    ticks = _drive(eng)
+    t_after = time.perf_counter()
+    tick = prof.serving_summary()["tick"]
+    assert tuple(tick["phases_s"]) == PHASES
+    assert all(v >= 0.0 for v in tick["phases_s"].values())  # `other` too
+    assert sum(tick["phases_s"].values()) == pytest.approx(tick["wall_s"], rel=1e-6)
+    assert 0.0 < tick["wall_s"] <= t_after - t_before
+    # every step() counts in the wall time; `steps` only those that dispatched
+    # (the last tick delivers the last token and dispatches nothing)
+    raw = prof.metrics_snapshot()["serving"]
+    assert tick["steps"] == raw["ticks"] == 11 < ticks
+    assert raw["tick"] == tick
+    assert tick["host_s"] == pytest.approx(tick["wall_s"] - tick["phases_s"]["wait"])
+    assert tick["host_ms_mean"] == pytest.approx(1e3 * tick["host_s"] / tick["steps"])
+    assert tick["wait_share"] == pytest.approx(tick["phases_s"]["wait"] / tick["wall_s"])
+    assert tick["phases_s"]["wait"] > 0.0 and tick["phases_s"]["admit"] > 0.0
+    longest = tick["longest"]
+    assert len(longest) == min(8, ticks)
+    assert [t["ms"] for t in longest] == sorted((t["ms"] for t in longest), reverse=True)
+    for t in longest:
+        assert tuple(t["phases_ms"]) == PHASES
+        assert sum(t["phases_ms"].values()) == pytest.approx(t["ms"], rel=1e-6)
+        assert t_before < t["at_s"] <= t_after  # perf_counter at the tick's end
+    # the first tick compiles: the prefill in `admit`, the step in `dispatch`
+    assert max(longest[0]["phases_ms"], key=longest[0]["phases_ms"].get) in ("admit", "dispatch")
+
+
+def test_a_slow_on_token_moves_deliver_and_nothing_else(model):
+    """A client whose callback sleeps 5 ms costs `deliver` 5 ms a token; the
+    host's other phases and the wait on the device stay where they were."""
+    eng = _engine(model)
+    _submit(eng, 4)
+    _drive(eng)  # every program compiled
+
+    def run(on_token):
+        prof.reset_serving()
+        for i in range(2):
+            _submit(eng, 16, seed=i, on_token=on_token)
+        _drive(eng)
+        return prof.serving_summary()["tick"]["phases_s"]
+
+    quick = run(lambda tok: None)
+    slow = run(lambda tok: time.sleep(0.005))
+    slept = 0.005 * 32
+    assert slow["deliver"] - quick["deliver"] >= slept
+    for phase in ("wait", "prepare", "dispatch", "evict"):
+        assert abs(slow[phase] - quick[phase]) < 0.25 * slept, phase
+
+
+def test_a_flush_inside_an_eviction_is_wait_and_deliver_not_evict(model):
+    """A cancelled request is evicted after what was dispatched is flushed:
+    the flush's fetch and its callbacks go to `wait` and `deliver` and come
+    out of `evict`, the phase that enclosed them."""
+    eng = _engine(model)
+    victim = _submit(eng, 30)
+    _submit(eng, 30, seed=1, on_token=lambda tok: time.sleep(0.02))
+    for _ in range(4):
+        eng.step()
+    victim.cancel()
+    prof.reset_serving()
+    eng.step()  # this tick evicts: its flush delivers the step in flight
+    s = prof.serving_summary()
+    assert s["drains"]["evict"] == 1 and victim.finished.is_set()
+    phases = s["tick"]["phases_s"]
+    assert phases["deliver"] >= 0.02  # the other stream's callback slept in it
+    assert phases["wait"] > 0.0
+    assert phases["evict"] < 0.01
+    assert sum(phases.values()) == pytest.approx(s["tick"]["wall_s"], rel=1e-6)
+    eng.stop()
+
+
+def test_reset_serving_clears_the_tick(model):
+    eng = _engine(model)
+    _submit(eng, 4)
+    _drive(eng)
+    assert prof.serving_summary()["tick"]["steps"] == 3
+    prof.reset_serving()
+    for tick in (prof.serving_summary()["tick"], prof.metrics_snapshot()["serving"]["tick"]):
+        assert tick == {
+            "steps": 0, "wall_s": 0.0, "phases_s": dict.fromkeys(PHASES, 0.0),
+            "host_s": 0.0, "host_ms_mean": None, "wait_share": None, "longest": [],
+        }
+
+
+def test_tick_spans_exist_only_under_the_flag_and_under_the_engines_trace(model):
+    eng = _engine(model)
+    paddle.set_flags({"FLAGS_trace": False})
+    _submit(eng, 6)
+    _drive(eng)
+    assert trace.spans() == []  # the counters ran all the same
+    assert prof.serving_summary()["tick"]["steps"] == 5
+    paddle.set_flags({"FLAGS_trace": True})
+    prof.reset_serving()
+    reqs = [_submit(eng, n, seed=n) for n in (6, 10)]
+    ticks = _drive(eng)
+    tick = prof.serving_summary()["tick"]
+    own = trace.spans(eng.trace_id)
+    assert own and {s["name"] for s in trace.spans()
+                    if s["name"].startswith("engine.tick.")} == {s["name"] for s in own}
+    assert {s["name"] for s in own} <= {"engine.tick." + p for p in PHASES[:6]}
+    assert all(s["parent_id"] == "" for s in own)  # siblings: no span encloses a tick
+    by_name = {}
+    for s in own:
+        by_name.setdefault(s["name"][len("engine.tick."):], []).append(s)
+    # exactly one dispatch span for each tick that dispatched, each its own tick
+    dispatched = [s["attrs"]["tick"] for s in by_name["dispatch"]]
+    assert len(dispatched) == len(set(dispatched)) == tick["steps"] == 9
+    assert {s["attrs"]["live"] for s in by_name["dispatch"]} == {1, 2}
+    per_tick = {}
+    for s in own:
+        per_tick.setdefault(s["attrs"]["tick"], []).append(s["name"])
+    assert len(per_tick) == ticks
+    assert all(len(names) == len(set(names)) <= 6 for names in per_tick.values())
+    # one admission tick, no eviction: those two phases record only their work
+    assert len(by_name["admit"]) == 1 and "evict" not in by_name
+    # from the same stamps as the counters
+    for phase in ("prepare", "dispatch", "wait", "deliver"):
+        assert sum(s["dur_s"] for s in by_name[phase]) == pytest.approx(
+            tick["phases_s"][phase], rel=1e-4)
+    for r in reqs:  # a request's tree holds its own stages and no tick
+        names = [s["name"] for s in trace.spans(r.trace[0])]
+        assert not any(n.startswith("engine.tick.") for n in names)
+        assert {"engine.queue", "engine.prefill", "engine.decode", "engine.fetch"} <= set(names)
+
+
+def test_600_streamed_tokens_fit_the_default_ring_with_one_fetch_an_epoch():
+    """What used to be a record a request a tick (4,800 here, the ring's
+    4,096 lost the head) is one engine.fetch beside each engine.decode."""
+    np.random.seed(4321)
+    long_model = LlamaForCausalLM(LlamaConfig.tiny(max_position_embeddings=1024))
+    eng = _engine(long_model, slots=8, max_len=640)
+    first = _submit(eng, 600)
+    others = [_submit(eng, 40 + 60 * i, seed=i) for i in range(1, 8)]
+    ticks = _drive(eng)
+    assert len(first.tokens) == 600 and all(o.finished.is_set() for o in others)
+    assert ticks == 600  # the prefill's token, then 599 steps a tick behind
+    st = trace.stats()
+    assert st["spans_dropped"] == 0 and st["spans_recorded"] < 4096
+    tree = trace.spans(first.trace[0])
+    decode = [s for s in tree if s["name"] == "engine.decode"]
+    fetch = [s for s in tree if s["name"] == "engine.fetch"]
+    # one beside each epoch's engine.decode: an epoch a finish of the others
+    assert len(fetch) == len(decode) == 8
+    assert sum(s["attrs"]["fetches"] for s in fetch) == ticks
+    assert sum(s["attrs"]["steps"] for s in fetch) >= ticks
+    assert all(s["attrs"]["wait_s"] >= 0.0 for s in fetch)
+    assert sum(s["attrs"]["ticks"] for s in decode) <= ticks
+    assert len(trace.spans(eng.trace_id)) <= 6 * ticks
+
+
+def test_speculative_ticks_are_clocked_by_phase_too(model):
+    eng = _engine(model, spec_k=3)
+    r = _submit(eng, 12)
+    _drive(eng)
+    tick = prof.serving_summary()["tick"]
+    assert tick["steps"] == prof.metrics_snapshot()["speculation"]["steps"] > 0
+    assert sum(tick["phases_s"].values()) == pytest.approx(tick["wall_s"], rel=1e-6)
+    assert min(tick["phases_s"][p] for p in ("prepare", "dispatch", "wait", "deliver")) > 0.0
+    names = {s["name"] for s in trace.spans(r.trace[0])}
+    assert {"engine.decode", "engine.verify", "engine.fetch"} <= names
+    waits = [s for s in trace.spans(eng.trace_id) if s["name"] == "engine.tick.wait"]
+    assert len(waits) >= tick["steps"]  # the verify fetch blocks in every round
+
+
+def test_metrics_render_all_seven_tick_phases_before_any_traffic():
+    prof.reset()
+    text = metrics.render(labels={"replica": "unit"})
+    for phase in PHASES:
+        assert (f'paddle_serving_tick_phase_seconds_total{{phase="{phase}",'
+                f'replica="unit"}} 0') in text.splitlines()
+    assert "paddle_serving_busy_seconds_total" in text  # stays beside it
+
+
+def test_span_ids_are_16_hex_unique_and_a_fork_draws_anew():
+    ids = [trace.new_span_id() for _ in range(20000)] + [trace.new_trace_id()]
+    assert len(set(ids)) == len(ids)
+    assert all(len(i) == 16 and int(i, 16) >= 0 for i in ids)
+    assert len({i[:8] for i in ids}) == 1  # the process's prefix, a counter behind it
+    trace._seed_ids()  # what a forked child runs
+    assert trace.new_span_id()[:8] != ids[0][:8]
 
 
 # ---------------------------------------------------------------------------

@@ -35,8 +35,10 @@ out; program and reference (`benchmarks/reference_ling3.py`) read alike:
   head's d_v with one learned weight [d_v] (`group_norm_size` 1), the gate one
   scalar a head (`gated_attention_proj_granularity_type` head_wise) (assumed).
 
-Decode is that step for every live slot at once (`_kda_decode`); an idle
-slot's state and convolution tail are left as they were.  Prefill is the
+Decode is that step for every live slot at once (`_kda_decode`; on the TPU
+the recurrence over the state is the `kda_state_step` kernel of
+`ops/kda_decode.py`, one read and one write of the state); an idle slot's
+state and convolution tail are left as they were.  Prefill is the
 chunkwise form of the same recurrence (`_kda_scan`; Kimi Linear,
 arXiv:2510.26692, section 3): with `G_i` the running sum of `g` inside a chunk
 of C rows, `A[i, j] = beta_i sum_c k_i k_j exp(G_i - G_j)` (j < i), `Aqk[i, j]
@@ -246,26 +248,52 @@ def _kda_output(cfg, w, x, o):
     return (y * gate[..., None]).reshape(x.shape[0], -1).astype(x.dtype) @ w["o_proj.weight"]
 
 
-def _kda_decode(cfg, w, x, state, tail, live):
-    """One token a slot: x [S, hidden], state [S, H, dk, dv] f32, tail [S, K -
-    1, 3 H dk], live [S] bool.  Returns (out [S, hidden], state, tail); a slot
-    that is not live keeps its state and tail.  The state is read once for
-    both `S'^T k` and `S'^T q` (`S_t^T q = S'^T q + u (k . q)`) and once more
-    for the update: elementwise and reductions in float32, no matmul that
-    would round it."""
+def _kda_recurrence(q, k, v, g, beta, state, live):
+    """The decode step's recurrence for every slot: q, k, g [S, H, dk] f32, v
+    [S, H, dv] f32, beta [S, H] f32, state [S, H, dk, dv] f32, live [S] bool
+    -> (o [S, H, dv] f32, unscaled; the state, a slot that is not live
+    keeping its own).  Elementwise and reductions in float32, no matmul that
+    would round it.
+
+    XLA reads the state once for both `S'^T k` and `S'^T q` (`S_t^T q = S'^T
+    q + u (k . q)`) and once more for the update.  On the TPU the
+    `kda_state_step` kernel (`ops/kda_decode.py`) does the same arithmetic in
+    one read and one write of the state, in place; off the TPU (and for a
+    shape the kernel refuses, which counts as a Pallas fallback) the step
+    takes the XLA form, the one form."""
     import jax.numpy as jnp
 
-    proj = _kda_project(w, x)
-    conv_in = jnp.concatenate([tail, proj[:, None].astype(tail.dtype)], axis=1)
-    q, k, v, g, beta = _kda_inputs(cfg, w, x, conv_in)
+    from ..ops import flash_attention as fa
+
+    interpret = fa._FORCE_INTERPRET
+    if interpret or fa._on_tpu():
+        from ..ops import kda_decode as kd
+
+        reason = None if interpret else kd.refusal(state)  # the interpreter takes any shape
+        if reason is None:
+            fa._log_pallas_call("kda_state_step")
+            return kd.kda_state_step(q, k, g, v, beta, live, state, interpret)
+        fa._log_pallas_fallback("kda_state_step: " + reason, shape=state.shape)
     sp = jnp.exp(g)[..., None] * state
     pred = jnp.sum(sp * k[..., None], axis=2)
     u = beta[..., None] * (v - pred)
     o = jnp.sum(sp * q[..., None], axis=2) + u * jnp.sum(q * k, axis=-1, keepdims=True)
     new = sp + k[..., None] * u[:, :, None, :]
-    keep = live[:, None, None, None]
+    return o, jnp.where(live[:, None, None, None], new, state)
+
+
+def _kda_decode(cfg, w, x, state, tail, live):
+    """One token a slot: x [S, hidden], state [S, H, dk, dv] f32, tail [S, K -
+    1, 3 H dk], live [S] bool.  Returns (out [S, hidden], state, tail); a slot
+    that is not live keeps its state and tail."""
+    import jax.numpy as jnp
+
+    proj = _kda_project(w, x)
+    conv_in = jnp.concatenate([tail, proj[:, None].astype(tail.dtype)], axis=1)
+    q, k, v, g, beta = _kda_inputs(cfg, w, x, conv_in)
+    o, state = _kda_recurrence(q, k, v, g, beta, state, live)
     out = _kda_output(cfg, w, x, o * cfg.head_dim ** -0.5)
-    return out, jnp.where(keep, new, state), jnp.where(live[:, None, None], conv_in[:, 1:], tail)
+    return out, state, jnp.where(live[:, None, None], conv_in[:, 1:], tail)
 
 
 def _kda_scan(q, k, v, g, beta, s0, chunk=KDA_CHUNK):

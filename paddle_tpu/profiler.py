@@ -423,6 +423,28 @@ def grouped_experts_summary():
         return [dict(zip(_GROUPED_EXPERTS_FIELDS, key)) for key in _grouped_experts]
 
 
+_KDA_DECODE_FIELDS = ("slots", "heads", "dk", "dv", "heads_per_step", "grid_steps", "state_bytes_per_step")
+_kda_decodes = {}  # a tuple of `_KDA_DECODE_FIELDS` a distinct KDA decode step, in order of first trace
+
+
+def record_kda_decode(**geometry):
+    """The geometry of one traced `kda_state_step` call (ops/kda_decode.py),
+    recorded at trace time like `record_grouped_experts`."""
+    key = tuple(int(geometry[f]) for f in _KDA_DECODE_FIELDS)
+    with _counters_lock:
+        _kda_decodes[key] = None
+
+
+def kda_decode_summary():
+    """One entry per distinct KDA decode step traced through the state kernel
+    since the last reset (a model's layers trace the same one): `slots`,
+    `heads`, the state's `dk` x `dv` a head, the heads a grid step takes, the
+    grid steps a call, and the state's bytes one grid step reads (and writes
+    back)."""
+    with _counters_lock:
+        return [dict(zip(_KDA_DECODE_FIELDS, key)) for key in _kda_decodes]
+
+
 def reset():
     """Zero EVERY counter family (step, serving, paging, router, flash
     fallbacks) in one critical section, so one run's router/serving gauges
@@ -444,6 +466,7 @@ def reset():
         _flash_pallas.clear()
         _paged_walks.clear()
         _grouped_experts.clear()
+        _kda_decodes.clear()
         _reset_moe_locked()
 
 

@@ -31,6 +31,7 @@ from jax.sharding import NamedSharding, PartitionSpec as P, SingleDeviceSharding
 from paddle_tpu.distributed import mesh as pmesh
 from paddle_tpu.ops import flash_attention as fa
 from paddle_tpu.ops import grouped_experts as ge
+from paddle_tpu.ops import kda_decode as kd
 
 BF16 = jnp.bfloat16
 MAX_LEN = 1024
@@ -191,7 +192,23 @@ def _grouped_expert_cases():
                [((T, D), BF16, None), ((T, held), jnp.float32, None), ((held,), jnp.int32, None), up, up, down], None)
 
 
-CASES = list(_flash_cases()) + list(_paged_cases()) + list(_grouped_expert_cases())
+def _kda_state_cases():
+    """The KDA layers' decode recurrence at `ling3_serve.reason64`'s state: 64
+    slots of 32 heads of 128 x 128 float32, a whole slot (2 MiB) a grid step,
+    the state written back in place."""
+    def fn(q, k, g, v, beta, live, state):
+        assert kd.refusal(state) is None
+        return kd.kda_state_step(q, k, g, v, beta, live, state, False)
+
+    S, H, d = 64, 32, 128
+    rows = ((S, H, d), jnp.float32, None)
+    yield ("kda-state-reason64", fn,
+           [rows, rows, rows, rows, ((S, H), jnp.float32, None), ((S,), jnp.bool_, None),
+            ((S, H, d, d), jnp.float32, None)], None)
+
+
+CASES = (list(_flash_cases()) + list(_paged_cases()) + list(_grouped_expert_cases())
+         + list(_kda_state_cases()))
 IDS = [c[0] for c in CASES]
 
 

@@ -35,6 +35,11 @@ out; program and reference (`benchmarks/reference_ling3.py`) read alike:
   head's d_v with one learned weight [d_v] (`group_norm_size` 1), the gate one
   scalar a head (`gated_attention_proj_granularity_type` head_wise) (assumed).
 
+The KDA and MLA layers here are Kimi Linear's too (`models/kimi_linear.py`),
+in the forms its config switches on: `use_kda_lora` (a low-rank softplus decay
+unbounded below, a gate a channel), `mla_rope` and `mla_gate` off (no rope on
+`q_pe` and `k_pe`, no gate before `W_o`); Ling-3's config keeps Ling-3's.
+
 Decode is that step for every live slot at once (`_kda_decode`; on the TPU
 the recurrence over the state is the `kda_state_step` kernel of
 `ops/kda_decode.py`, one read and one write of the state); an idle slot's
@@ -46,7 +51,11 @@ of C rows, `A[i, j] = beta_i sum_c k_i k_j exp(G_i - G_j)` (j < i), `Aqk[i, j]
 exp(G)]` (a triangular solve), and per chunk `u = U - W S`, `o = (q exp(G)) S +
 Aqk u`, `S <- diag(exp(G_C)) S + (k exp(G_C - G))^T u`.  The decays are formed
 pairwise, `exp(G_i - G_j)` with `i >= j`, never as `exp(-G_j)`: a row decays by
-up to e^5, so a factored form leaves float32 after 17 rows.  Rows at or past
+up to e^5 here, and without a bound under Kimi Linear's gate (e^-49 a row as
+the benchmark seeds it, `kimi_linear.py`), so a factored form leaves float32
+after 17 rows or fewer.  Every exponent the scan takes is at most 0: `exp(G)`
+and `exp(G_C)` fall to their true limit, 0, where a chunk's decays sum past
+e^-104, and no quotient of two such values is formed.  Rows at or past
 `true_len` take `g = 0`, `beta = 0` and leave state and tail as they were; a
 fresh prefill starts its slot from zero, a later chunk from what the chunk
 before left in the slot.
@@ -176,6 +185,20 @@ class Ling3Config:
     def n_shared_experts(self):
         return self.num_shared_experts
 
+    # the forms the layers shared with `kimi_linear.py` read: Ling-3's published
+    # switches (`use_kda_lora` and `use_mla_nope` off in its config)
+    use_kda_lora = False  # a full-rank bounded decay and one output gate a head
+    mla_rope = True
+    mla_gate = True
+
+    @property
+    def kda_heads(self):
+        return self.num_attention_heads
+
+    @property
+    def kda_head_dim(self):
+        return self.head_dim
+
     def published_index(self, layer):
         return layer + self.first_k_dense_replace - self.dense_layers_kept
 
@@ -202,6 +225,8 @@ class Ling3Config:
 
 
 def _rope_tables(cfg):
+    if not cfg.mla_rope:
+        return None
     d = cfg.qk_rope_head_dim
     inv = 1.0 / (float(cfg.rope_theta) ** (np.arange(0, d, 2, dtype=np.float64) / d))
     f = np.outer(np.arange(cfg.max_position_embeddings, dtype=np.float64), inv)
@@ -214,18 +239,25 @@ def _kda_inputs(cfg, w, x, conv_in):
     """x [n, hidden] (normed) and the convolution's input rows `conv_in` [n,
     K, 3 * H * dk] (each token's own projection last, the K - 1 before it
     first) -> q, k, v [n, H, dk] f32 (q and k l2-normed), g [n, H, dk] f32
-    (log decay, in (kda_lower_bound, 0)), beta [n, H] f32."""
+    (log decay, below 0), beta [n, H] f32.  The decay is Ling-3's bounded one
+    (`kda_lower_bound * sigmoid(exp(A) (x W_f + b_f))`) or, under
+    `cfg.use_kda_lora`, Kimi Linear's low-rank one (`-exp(A) * softplus(x W_fa
+    W_fb + dt_bias)`, unbounded below)."""
     import jax
     import jax.numpy as jnp
 
     n = x.shape[0]
-    H, dk = cfg.num_attention_heads, cfg.head_dim
+    H, dk = cfg.kda_heads, cfg.kda_head_dim
     conv = jnp.sum(conv_in.astype(jnp.float32) * w["conv.weight"].astype(jnp.float32)[None], axis=1)
     q, k, v = (a.reshape(n, H, dk) for a in jnp.split(jax.nn.silu(conv), 3, axis=-1))
     q = q * jax.lax.rsqrt(jnp.sum(q * q, axis=-1, keepdims=True) + 1e-6)
     k = k * jax.lax.rsqrt(jnp.sum(k * k, axis=-1, keepdims=True) + 1e-6)
-    f = (x @ w["f_proj.weight"]).astype(jnp.float32) + w["f_proj.bias"]
-    g = cfg.kda_lower_bound * jax.nn.sigmoid(jnp.exp(w["A_log"])[None, :, None] * f.reshape(n, H, dk))
+    if cfg.use_kda_lora:
+        f = ((x @ w["f_a_proj.weight"]) @ w["f_b_proj.weight"]).astype(jnp.float32) + w["dt_bias"]
+        g = -jnp.exp(w["A_log"])[None, :, None] * jax.nn.softplus(f.reshape(n, H, dk))
+    else:
+        f = (x @ w["f_proj.weight"]).astype(jnp.float32) + w["f_proj.bias"]
+        g = cfg.kda_lower_bound * jax.nn.sigmoid(jnp.exp(w["A_log"])[None, :, None] * f.reshape(n, H, dk))
     beta = jax.nn.sigmoid((x @ w["b_proj.weight"]).astype(jnp.float32))
     return q, k, v, g, beta
 
@@ -239,13 +271,18 @@ def _kda_project(w, x):
 
 def _kda_output(cfg, w, x, o):
     """o [n, H, dv] f32 -> the layer's output [n, hidden]: the per-head norm,
-    the head-wise gate, W_o."""
+    the gate (one a head, or under `cfg.use_kda_lora` one a channel through
+    the low-rank pair `W_ga W_gb`), W_o."""
     import jax
     import jax.numpy as jnp
 
     y = o * jax.lax.rsqrt(jnp.mean(o * o, axis=-1, keepdims=True) + cfg.rms_norm_eps) * w["o_norm.weight"]
-    gate = jax.nn.sigmoid((x @ w["g_proj.weight"]).astype(jnp.float32))
-    return (y * gate[..., None]).reshape(x.shape[0], -1).astype(x.dtype) @ w["o_proj.weight"]
+    if cfg.use_kda_lora:
+        gate = jax.nn.sigmoid(((x @ w["g_a_proj.weight"]) @ w["g_b_proj.weight"]).astype(jnp.float32))
+        gate = gate.reshape(o.shape)
+    else:
+        gate = jax.nn.sigmoid((x @ w["g_proj.weight"]).astype(jnp.float32))[..., None]
+    return (y * gate).reshape(x.shape[0], -1).astype(x.dtype) @ w["o_proj.weight"]
 
 
 def _kda_recurrence(q, k, v, g, beta, state, live):
@@ -292,7 +329,7 @@ def _kda_decode(cfg, w, x, state, tail, live):
     conv_in = jnp.concatenate([tail, proj[:, None].astype(tail.dtype)], axis=1)
     q, k, v, g, beta = _kda_inputs(cfg, w, x, conv_in)
     o, state = _kda_recurrence(q, k, v, g, beta, state, live)
-    out = _kda_output(cfg, w, x, o * cfg.head_dim ** -0.5)
+    out = _kda_output(cfg, w, x, o * cfg.kda_head_dim ** -0.5)
     return out, state, jnp.where(live[:, None, None], conv_in[:, 1:], tail)
 
 
@@ -300,7 +337,9 @@ def _kda_scan(q, k, v, g, beta, s0, chunk=KDA_CHUNK):
     """The chunkwise form of the recurrence over n rows of one sequence: q,
     k, v, g [n, H, d] f32, beta [n, H] f32, s0 [H, dk, dv] f32 -> (o [n, H,
     dv] f32, unscaled; the state after the last row).  A row with g = 0 and
-    beta = 0 changes nothing."""
+    beta = 0 changes nothing.  `g` may lie anywhere below 0 (Ling-3's bounded
+    gate, Kimi Linear's unbounded one): the module's docstring says why the
+    pairwise form holds."""
     import jax
     import jax.numpy as jnp
     from jax import lax
@@ -366,7 +405,7 @@ def _kda_prefill(cfg, w, x, state, tail, slot, true_len, fresh):
     beta = jnp.where(valid[:, None], beta, 0.0)
     o, s1 = _kda_scan(q, k, v, g, beta, s0)
     t1 = lax.dynamic_slice_in_dim(rows, n_valid, K - 1, 0)  # the last K - 1 real rows
-    out = _kda_output(cfg, w, x, o * cfg.head_dim ** -0.5)
+    out = _kda_output(cfg, w, x, o * cfg.kda_head_dim ** -0.5)
     return (out, lax.dynamic_update_index_in_dim(state, s1, slot, 0),
             lax.dynamic_update_index_in_dim(tail, t1, slot, 0))
 
@@ -378,8 +417,10 @@ def _mla_scale(cfg):
 
 
 def _mla_project(cfg, w, x, cos, sin):
-    """x [n, hidden], cos/sin [n, rope/2] -> q_nope [n, H, dn], q_pe [n, H,
-    dr], the cache's latent row [n, latent_width]."""
+    """x [n, hidden], cos/sin [n, rope/2] (None without rope) -> q_nope [n,
+    H, dn], q_pe [n, H, dr], the cache's latent row [n, latent_width].  Under
+    `cfg.mla_rope` false (Kimi Linear's `mla_use_nope`) `q_pe` and `k_pe` are
+    taken as they are projected."""
     import jax.numpy as jnp
 
     n = x.shape[0]
@@ -387,16 +428,21 @@ def _mla_project(cfg, w, x, cos, sin):
     q = (x @ w["q_proj.weight"]).reshape(n, H, dn + dr)
     kv = x @ w["kv_a_proj_with_mqa.weight"]
     row = jnp.concatenate([_rms(kv[:, :c], w["kv_a_layernorm.weight"], cfg.rms_norm_eps),
-                           _rope(kv[:, c:], cos, sin),
+                           _rope(kv[:, c:], cos, sin) if cfg.mla_rope else kv[:, c:],
                            jnp.zeros((n, latent_width(cfg) - c - dr), x.dtype)], -1)
+    if not cfg.mla_rope:
+        return q[..., :dn], q[..., dn:], row
     return q[..., :dn], _rope(q[..., dn:], cos[:, None], sin[:, None]), row
 
 
 def _mla_output(cfg, w, x, o):
-    """o [n, H, dv] -> [n, hidden]: the head-wise gate, then W_o."""
+    """o [n, H, dv] -> [n, hidden]: the head-wise gate (under `cfg.mla_gate`),
+    then W_o."""
     import jax
     import jax.numpy as jnp
 
+    if not cfg.mla_gate:
+        return o.reshape(x.shape[0], -1) @ w["o_proj.weight"]
     gate = jax.nn.sigmoid((x @ w["g_proj.weight"]).astype(jnp.float32))
     return (o * gate[..., None].astype(o.dtype)).reshape(x.shape[0], -1) @ w["o_proj.weight"]
 
@@ -467,14 +513,25 @@ def _is_decode(cache):
 class Ling3KDA(_Leaves):
     def __init__(self, cfg):
         super().__init__(cfg)
-        h, H, dk = cfg.hidden_size, cfg.num_attention_heads, cfg.head_dim
-        for n in ("q_proj", "k_proj", "v_proj", "f_proj"):
-            self._matrix(n + ".weight", h, H * dk)
-        self._matrix("conv.weight", cfg.short_conv_kernel_size, 3 * H * dk)
-        self._norm("f_proj.bias", H * dk, 0.0)
-        self._norm("A_log", H, 0.0)
-        self._matrix("b_proj.weight", h, H)
-        self._matrix("g_proj.weight", h, H)
+        h, H, dk = cfg.hidden_size, cfg.kda_heads, cfg.kda_head_dim
+        if cfg.use_kda_lora:  # Kimi Linear: low-rank decay and gate, a decay bias a channel
+            for n in ("q_proj", "k_proj", "v_proj"):
+                self._matrix(n + ".weight", h, H * dk)
+            self._matrix("conv.weight", cfg.short_conv_kernel_size, 3 * H * dk)
+            for n in ("f", "g"):
+                self._matrix(n + "_a_proj.weight", h, dk)
+                self._matrix(n + "_b_proj.weight", dk, H * dk)
+            self._norm("dt_bias", H * dk, 0.0)
+            self._norm("A_log", H, 0.0)
+            self._matrix("b_proj.weight", h, H)
+        else:
+            for n in ("q_proj", "k_proj", "v_proj", "f_proj"):
+                self._matrix(n + ".weight", h, H * dk)
+            self._matrix("conv.weight", cfg.short_conv_kernel_size, 3 * H * dk)
+            self._norm("f_proj.bias", H * dk, 0.0)
+            self._norm("A_log", H, 0.0)
+            self._matrix("b_proj.weight", h, H)
+            self._matrix("g_proj.weight", h, H)
         self._norm("o_norm.weight", dk)
         self._matrix("o_proj.weight", H * dk, h)
 
@@ -512,6 +569,12 @@ class Ling3KDA(_Leaves):
         return out
 
 
+def _rows_at(rope, at):
+    """The rope tables' rows at positions `at`: (cos, sin), or (None, None)
+    for a model without rope."""
+    return tuple(t[at] for t in rope) if rope else (None, None)
+
+
 class Ling3MLA(_Leaves):
     def __init__(self, cfg, rope):
         super().__init__(cfg)
@@ -521,9 +584,10 @@ class Ling3MLA(_Leaves):
         self._matrix("kv_a_proj_with_mqa.weight", h, c + dr)
         self._norm("kv_a_layernorm.weight", c)
         self._matrix("kv_b_proj.weight", c, H * (dn + dv))
-        self._matrix("g_proj.weight", h, H)
+        if cfg.mla_gate:
+            self._matrix("g_proj.weight", h, H)
         self._matrix("o_proj.weight", H * dv, h)
-        self.rope_cos, self.rope_sin = rope
+        self.rope_cos, self.rope_sin = rope or (None, None)
 
     def forward(self, x, cache, pos=None):
         from ..ops.dispatch import apply
@@ -533,32 +597,37 @@ class Ling3MLA(_Leaves):
         names, leaves = list(named), list(named.values())
         lat_t = getattr(cache.arena, LATENT)
         max_len = cache.max_len
+        rope = [self.rope_cos, self.rope_sin] if cfg.mla_rope else []
+        r = len(rope)
         if _is_decode(cache):
             if x.shape[1] != 1:
                 raise ValueError("the latent decode path takes one token a slot")
 
-            def f(xa, cos, sin, lat, tables, p, *ws):
-                out, lat = _mla_decode(cfg, dict(zip(names, ws)), xa[:, 0], cos[p], sin[p], lat,
-                                       tables, p, max_len)
+            def f(xa, *rest):
+                lat, tables, p, *ws = rest[r:]
+                x0 = xa[:, 0]
+                cos, sin = _rows_at(rest[:r], p)
+                out, lat = _mla_decode(cfg, dict(zip(names, ws)), x0, cos, sin, lat, tables, p, max_len)
                 return out[:, None], lat
 
-            out, lat = apply(f, [x, self.rope_cos, self.rope_sin, lat_t, cache.tables, pos] + leaves,
+            out, lat = apply(f, [x] + rope + [lat_t, cache.tables, pos] + leaves,
                              multi=True, name="mla_walk_decode")
         else:
             if x.shape[0] != 1:
                 raise ValueError("the latent prefill path takes one sequence")
             has_start = cache.start is not None
 
-            def f(xa, cos, sin, lat, table, tl, *rest):
+            def f(xa, *rest):
                 import jax.numpy as jnp
-                st = rest[0] if has_start else jnp.zeros((1,), jnp.int32)
-                ws = rest[1:] if has_start else rest
+                lat, table, tl, *ws = rest[r:]
+                st = ws.pop(0) if has_start else jnp.zeros((1,), jnp.int32)
                 at = st[0] + jnp.arange(xa.shape[1], dtype=jnp.int32)
-                out, lat = _mla_prefill(cfg, dict(zip(names, ws)), xa[0], cos[at], sin[at], lat,
-                                        table, st, tl)
+                x0 = xa[0]
+                cos, sin = _rows_at(rest[:r], at)
+                out, lat = _mla_prefill(cfg, dict(zip(names, ws)), x0, cos, sin, lat, table, st, tl)
                 return out[None], lat
 
-            ins = [x, self.rope_cos, self.rope_sin, lat_t, cache.table, cache.true_len]
+            ins = [x] + rope + [lat_t, cache.table, cache.true_len]
             out, lat = apply(f, ins + ([cache.start] if has_start else []) + leaves, multi=True,
                              name="mla_prefill")
         lat_t._data = lat._data
@@ -615,15 +684,23 @@ class Ling3Model(nn.Layer):
             x, st = layer(x, cache, pos, live)
             if st is not None:
                 stats.append(st)
-        self.step_stats = None
-        if decode:
-            def count(lv, *moe):
-                m = jnp.stack(moe) if moe else jnp.zeros((1, 4), jnp.int32)
-                return jnp.concatenate([jnp.sum(m[:, :3], axis=0), jnp.max(m[:, 3:], axis=0),
-                                        jnp.sum(lv, dtype=jnp.int32)[None]])
-
-            self.step_stats = apply(count, [live] + stats, name="ling3_step_stats")
+        self.step_stats = self._step_counts(live, pos, stats) if decode else None
         return self.norm(x), caches
+
+    def _step_counts(self, live, pos, moe_stats):
+        """A decode step's counters, int32[5]: the four of
+        `profiler.record_moe_step` summed over the expert layers, then the
+        live slots."""
+        import jax.numpy as jnp
+
+        from ..ops.dispatch import apply
+
+        def count(lv, *moe):
+            m = jnp.stack(moe) if moe else jnp.zeros((1, 4), jnp.int32)
+            return jnp.concatenate([jnp.sum(m[:, :3], axis=0), jnp.max(m[:, 3:], axis=0),
+                                    jnp.sum(lv, dtype=jnp.int32)[None]])
+
+        return apply(count, [live] + moe_stats, name="ling3_step_stats")
 
 
 class Ling3ForCausalLM(nn.Layer):
@@ -637,11 +714,12 @@ class Ling3ForCausalLM(nn.Layer):
     # from pages alone, and 35 of 42 layers keep their past in a state per
     # slot that no page holds (a snapshot at page boundaries would: ROADMAP A.7)
     engine_unsupported = frozenset({"tp", "cp", "kv_quant", "lora", "spec_k", "role", "prefix_cache"})
+    model_class = Ling3Model
 
     def __init__(self, config):
         super().__init__()
         self.config = config
-        self.model = Ling3Model(config)
+        self.model = self.model_class(config)
         self.lm_head = _Head(config)
         self.eval()
 
@@ -658,7 +736,7 @@ class Ling3ForCausalLM(nn.Layer):
         """A slot's state in a layer that has state (the KDA layers): (name,
         shape, dtype)."""
         c = self.config
-        H, d = c.num_attention_heads, c.head_dim
+        H, d = c.kda_heads, c.kda_head_dim
         return [(KDA_STATE, (H, d, d), "float32"),
                 (CONV_TAIL, (c.short_conv_kernel_size - 1, 3 * H * d), c.dtype)]
 

@@ -738,6 +738,8 @@ _linear_attn_gauges = {"steps": 0, "live_slots": 0, "state_bytes_read": 0, "stat
 # counted inside the step, the pages by the engine's page manager
 _window_gauges = {"steps": 0, "live_slots": 0, "rows_in_reach_full": 0, "rows_in_reach_window": 0}
 _page_groups = {}  # group name -> gauges, set by the engine that was built last
+# latent rows in reach of the decode steps' MLA layers (ISSUE 39), counted inside the step
+_latent_walk_gauges = {"steps": 0, "live_slots": 0, "rows_in_reach": 0}
 
 
 def record_moe_step(tokens, picks_held, experts_hit, max_load):
@@ -799,6 +801,17 @@ def record_window_rows(rows_full, rows_window, live_slots):
         g["rows_in_reach_window"] += int(rows_window)
 
 
+def record_latent_walk_step(rows, live_slots):
+    """One decode step of a model whose MLA layers walk a latent page cache,
+    counted inside the step: the rows in reach of the live slots (`pos + 1` a
+    slot), summed over those layers."""
+    with _counters_lock:
+        g = _latent_walk_gauges
+        g["steps"] += 1
+        g["live_slots"] += int(live_slots)
+        g["rows_in_reach"] += int(rows)
+
+
 def record_page_group(name, pool_pages, reach, pages_live, slot_pages=0, prefill_pages=0,
                       released_behind=0):
     """What one page group of the engine's cache manager holds, as of now
@@ -826,7 +839,7 @@ def record_arena_bytes(by_kind):
 
 
 def _reset_moe_locked():
-    for g in (_moe_gauges, _sparse_attn_gauges, _linear_attn_gauges, _window_gauges):
+    for g in (_moe_gauges, _sparse_attn_gauges, _linear_attn_gauges, _window_gauges, _latent_walk_gauges):
         for k in g:
             g[k] = 0
     for g in _page_groups.values():  # sizes stay, what was counted goes
@@ -865,6 +878,16 @@ def linear_attn_summary():
     with _counters_lock:
         g = dict(_linear_attn_gauges)
     return g if g["steps"] or g["prefill_rows"] else {}
+
+
+def latent_walk_summary():
+    """{} before any counted step; else the totals and `rows_per_step`."""
+    with _counters_lock:
+        g = dict(_latent_walk_gauges)
+    if not g["steps"]:
+        return {}
+    g["rows_per_step"] = g["rows_in_reach"] / g["steps"]
+    return g
 
 
 def window_cache_summary():

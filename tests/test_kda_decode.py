@@ -20,10 +20,11 @@ import jax  # noqa: E402
 import jax.numpy as jnp  # noqa: E402
 
 import paddle_tpu as paddle  # noqa: E402
+from benchmarks import weights_kimi_linear as WK  # noqa: E402
 from benchmarks import weights_ling3 as W  # noqa: E402
 from paddle_tpu import profiler  # noqa: E402
 from paddle_tpu.inference.engine import ContinuousBatchingEngine  # noqa: E402
-from paddle_tpu.models import Ling3Config, Ling3ForCausalLM  # noqa: E402
+from paddle_tpu.models import KimiLinearConfig, Ling3Config, Ling3ForCausalLM  # noqa: E402
 from paddle_tpu.models import ling3 as L  # noqa: E402
 from paddle_tpu.ops import flash_attention as fa  # noqa: E402
 from paddle_tpu.ops import kda_decode as kd  # noqa: E402
@@ -94,6 +95,36 @@ def test_kernel_is_the_xla_recurrence(interpret, S, H, d):
     got = jax.jit(lambda *a: L._kda_recurrence(*a))(q, k, v, g, beta, state, live)  # a trace of its own
     assert profiler.flash_pallas_summary().get("kda_state_step", 0) == before + 1
     assert_the_xla_form(got, want, state, live)
+
+
+def test_kernel_is_the_xla_recurrence_at_32_slots_under_kimi_linears_unbounded_decays(interpret):
+    """Kimi Linear's decay (`-exp(A) * softplus(x W_fa W_fb + dt_bias)`, the
+    benchmark's draws: A_log uniform(0, ln 16), dt_bias uniform(-6, 3)) goes far
+    below Ling-3's e^-5: the kernel takes `g` however it was formed."""
+    S, H, d = 32, 2, 128
+    lin = {"full_attn_layers": [2], "kda_layers": [1], "head_dim": d, "num_heads": H, "short_conv_kernel_size": 4}
+    cfg = KimiLinearConfig.tiny(num_hidden_layers=2, num_attention_heads=H, linear_attn_config=lin,
+                                experts_held=4, expert_offset=4)
+    spec = dict(vars(cfg), init={"matrix_std": 0.02, "router_bias_std": 0.01, "conv_std": 0.5,
+                                 "kda_A_log_max": float(np.log(16.0)), "kda_dt_bias_min": -6.0,
+                                 "kda_dt_bias_max": 3.0})
+    pre = "model.layers.0.self_attn."
+    w = {n[len(pre):]: a for n, a in WK.make(5, spec, WK.layer_leaves(spec, 0), jnp.float32).items()
+         if n.startswith(pre)}
+    rng = np.random.default_rng(5)
+    x = jnp.asarray(rng.normal(size=(S, cfg.hidden_size)), jnp.float32)
+    conv_in = jnp.asarray(rng.normal(size=(S, 4, 3 * H * d)), jnp.float32)
+    q, k, v, g, beta = L._kda_inputs(cfg, w, x, conv_in)
+    state = jnp.asarray(rng.normal(size=(S, H, d, d)) * 0.5, jnp.float32)
+    live = jnp.asarray(np.arange(S) % 5 != 2)
+    assert float(jnp.min(g)) < -20.0 and float(jnp.max(g)) > np.log(0.95)  # from a full memory to none
+    fa._FORCE_INTERPRET = False
+    want = L._kda_recurrence(q, k, v, g, beta, state, live)
+    fa._FORCE_INTERPRET = True
+    got = jax.jit(lambda *a: L._kda_recurrence(*a))(q, k, v, g, beta, state, live)
+    assert_the_xla_form(got, want, state, live)
+    gone = np.asarray(g)[np.asarray(live)] < -20.0  # channels whose past is e^-20 away: the update alone
+    assert gone.any()
 
 
 @pytest.mark.parametrize("shape,dtype,why", [

@@ -155,6 +155,11 @@ def _paged_cases():
            lambda q, lat, t, p: fa._fused_paged_decode(q, lat, None, t, p, 32768, 0.07, False),
            [((64, 1, 32, 640), BF16, None), ((16385, 1, 128, 640), BF16, None),
             ((64, 256), jnp.int32, None), ((64,), jnp.int32, None)], None)
+    # `kimi_serve.longreason32`'s: 32 slots of 512 table entries (64k tokens) over the same arena
+    yield ("paged-longreason32",
+           lambda q, lat, t, p: fa._fused_paged_decode(q, lat, None, t, p, 65536, 0.07, False),
+           [((32, 1, 32, 640), BF16, None), ((16385, 1, 128, 640), BF16, None),
+            ((32, 512), jnp.int32, None), ((32,), jnp.int32, None)], None)
     # `mellum2_serve.mixed32`'s two walks: 32 slots, 8 query rows a KV head over
     # 4 KV heads; a full layer's over the 8,193-page arena, a sliding layer's
     # over the window group's 314 pages from each slot's first visible position
@@ -178,11 +183,13 @@ def _paged_cases():
 
 
 def _grouped_expert_cases():
-    """The expert layer of a decode step at the two serving cells' shapes
+    """The expert layer of a decode step at the three serving cells' shapes
     (`ling3_serve.reason64`: 64 tokens, 128 of 512 experts held, 2560 x 768;
-    `mellum2_serve.mixed32`: 32 tokens, all 64 experts, 2304 x 896 = 7 x 128):
-    two experts in flight are 23.6 and 24.8 MB of scoped VMEM."""
-    for tag, T, held, D, I in (("reason64", 64, 128, 2560, 768), ("mixed32", 32, 64, 2304, 896)):
+    `mellum2_serve.mixed32`: 32 tokens, all 64 experts, 2304 x 896 = 7 x 128;
+    `kimi_serve.longreason32`: 32 tokens, 128 of 256 experts held, 2304 x
+    1024): two experts in flight are 23.6, 24.8 and 28.3 MB of scoped VMEM."""
+    for tag, T, held, D, I in (("reason64", 64, 128, 2560, 768), ("mixed32", 32, 64, 2304, 896),
+                               ("longreason32", 32, 128, 2304, 1024)):
         def fn(x, weight, counts, w1, w3, w2):
             assert ge.refusal(x, w1) is None
             return ge.grouped_experts(x, weight, *ge.hit_list(counts), w1, w3, w2, False)
@@ -193,18 +200,20 @@ def _grouped_expert_cases():
 
 
 def _kda_state_cases():
-    """The KDA layers' decode recurrence at `ling3_serve.reason64`'s state: 64
-    slots of 32 heads of 128 x 128 float32, a whole slot (2 MiB) a grid step,
-    the state written back in place."""
+    """The KDA layers' decode recurrence at `ling3_serve.reason64`'s state (64
+    slots) and `kimi_serve.longreason32`'s (32): 32 heads of 128 x 128
+    float32, a whole slot (2 MiB) a grid step, the state written back in
+    place."""
     def fn(q, k, g, v, beta, live, state):
         assert kd.refusal(state) is None
         return kd.kda_state_step(q, k, g, v, beta, live, state, False)
 
-    S, H, d = 64, 32, 128
-    rows = ((S, H, d), jnp.float32, None)
-    yield ("kda-state-reason64", fn,
-           [rows, rows, rows, rows, ((S, H), jnp.float32, None), ((S,), jnp.bool_, None),
-            ((S, H, d, d), jnp.float32, None)], None)
+    H, d = 32, 128
+    for tag, S in (("reason64", 64), ("longreason32", 32)):
+        rows = ((S, H, d), jnp.float32, None)
+        yield (f"kda-state-{tag}", fn,
+               [rows, rows, rows, rows, ((S, H), jnp.float32, None), ((S,), jnp.bool_, None),
+                ((S, H, d, d), jnp.float32, None)], None)
 
 
 CASES = (list(_flash_cases()) + list(_paged_cases()) + list(_grouped_expert_cases())

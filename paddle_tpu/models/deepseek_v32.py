@@ -317,36 +317,41 @@ def _select_mask(score, k):
         lambda: above | (tie & (jnp.cumsum(tie, axis=1, dtype=jnp.int32) <= room)))
 
 
-def _attend_expanded(cfg, w_ukv, q_nope, q_pe, ctx_lat, n_blocks, kb, qb, scale, mask_of):
-    """Attention of s queries (q_nope [s, H, dn], q_pe [s, H, dr]) over one
-    sequence's latent rows `ctx_lat` [L, latent_width], K and V expanded from
-    them a block of `kb` keys at a time, online softmax, `qb` queries to a
-    score tile.  `mask_of(j)` -> bool [s, kb]: which keys of block j each
-    query may see.  The first `n_blocks` (data) blocks are visited.
-    Returns [s, H * dv] in q's dtype."""
+def _attend_expanded(cfg, w_ukv, q_nope, q_pe, ctx_lat, n_blocks, kb, qb, scale, start, mask=None):
+    """s queries (q_nope [s, H, dn], q_pe [s, H, dr]) over one sequence's latent rows `ctx_lat` [L,
+    latent_width], K and V expanded `kb` keys at a time, online softmax, `qb` queries a tile, the first
+    `n_blocks` (data) blocks; `mask` None is causal from `start` (data), else bool [s, L].  On the TPU one
+    Pallas kernel (`ops/mla_prefill.py`) keeps the score tiles in VMEM; elsewhere, or for a shape it
+    refuses, this loop, whose tiles go through memory.  Returns [s, H * dv] in q's dtype."""
     import jax.numpy as jnp
     from jax import lax
 
+    from ..ops import flash_attention as fa, mla_prefill as mp
+
+    if (it := fa._FORCE_INTERPRET) or fa._on_tpu():  # the backend and static shapes choose
+        reason = mp.refusal(q_nope, q_pe, ctx_lat, w_ukv, kb, qb, mask, it)
+        if reason is None:
+            fa._log_pallas_call("mla_prefill")
+            return mp.mla_prefill(q_nope, q_pe, ctx_lat, w_ukv, n_blocks, kb, qb, scale, start, mask, it)
+        fa._log_pallas_fallback("mla_prefill: " + reason, shape=q_nope.shape)
     s = q_nope.shape[0]
-    H, dn, dv, c = cfg.num_attention_heads, cfg.qk_nope_head_dim, cfg.v_head_dim, cfg.kv_lora_rank
-    dr = cfg.qk_rope_head_dim
-    # one matmul a score tile: [q_nope | q_pe] against [k_nope | k_pe for every head].  The
-    # loop is bound by the tiles' trips through memory, and two matmuls wrote two
-    q_full = jnp.concatenate([q_nope, q_pe], -1)
+    H, dn, dv, c, dr = (cfg.num_attention_heads, cfg.qk_nope_head_dim, cfg.v_head_dim, cfg.kv_lora_rank,
+                        cfg.qk_rope_head_dim)
+    q_full = jnp.concatenate([q_nope, q_pe], -1)  # against [k_nope | k_pe]: one matmul a score tile
+    q_pos = jnp.reshape(start, ()) + jnp.arange(s, dtype=jnp.int32)
 
     def attend_block(j, carry):
         m, l, acc = carry
         rows = lax.dynamic_slice_in_dim(ctx_lat, j * kb, kb, 0)
         kv = (rows[:, :c] @ w_ukv).reshape(kb, H, dn + dv)
         v = kv[..., dn:]
-        k_full = jnp.concatenate(
-            [kv[..., :dn], jnp.broadcast_to(rows[:, None, c:c + dr], (kb, H, dr))], -1)
-        mask = mask_of(j)
+        k_full = jnp.concatenate([kv[..., :dn], jnp.broadcast_to(rows[:, None, c:c + dr], (kb, H, dr))], -1)
+        mask_j = (lax.dynamic_slice_in_dim(mask, j * kb, kb, 1) if mask is not None
+                  else (j * kb + jnp.arange(kb, dtype=jnp.int32))[None, :] <= q_pos[:, None])
         ms, ls, accs = [], [], []
         for i in range(0, s, qb):
-            lg = jnp.einsum("thd,lhd->thl", q_full[i:i + qb], k_full,
-                            preferred_element_type=jnp.float32)
-            lg = jnp.where(mask[i:i + qb, None, :], lg * scale, -jnp.inf)
+            lg = jnp.einsum("thd,lhd->thl", q_full[i:i + qb], k_full, preferred_element_type=jnp.float32)
+            lg = jnp.where(mask_j[i:i + qb, None, :], lg * scale, -jnp.inf)
             m_new = jnp.maximum(m[i:i + qb], jnp.max(lg, axis=-1))
             m_safe = jnp.where(m_new == -jnp.inf, 0.0, m_new)  # no key chosen yet
             p = jnp.exp(lg - m_safe[..., None])
@@ -354,14 +359,11 @@ def _attend_expanded(cfg, w_ukv, q_nope, q_pe, ctx_lat, n_blocks, kb, qb, scale,
             ms.append(m_new)
             ls.append(l[i:i + qb] * fade + jnp.sum(p, axis=-1))
             accs.append(acc[i:i + qb] * fade[..., None]
-                        + jnp.einsum("thl,lhd->thd", p.astype(v.dtype), v,
-                                     preferred_element_type=jnp.float32))
+                        + jnp.einsum("thl,lhd->thd", p.astype(v.dtype), v, preferred_element_type=jnp.float32))
         return jnp.concatenate(ms, 0), jnp.concatenate(ls, 0), jnp.concatenate(accs, 0)
 
-    m0 = jnp.full((s, H), -jnp.inf, jnp.float32)
-    _, l, acc = lax.fori_loop(
-        0, n_blocks, attend_block,
-        (m0, jnp.zeros((s, H), jnp.float32), jnp.zeros((s, H, dv), jnp.float32)))
+    init = (jnp.full((s, H), -jnp.inf, jnp.float32), jnp.zeros((s, H), jnp.float32), jnp.zeros((s, H, dv), jnp.float32))
+    _, l, acc = lax.fori_loop(0, n_blocks, attend_block, init)
     return (acc / jnp.maximum(l, 1e-30)[..., None]).astype(q_nope.dtype).reshape(s, H * dv)
 
 
@@ -386,8 +388,7 @@ def _prefill_attention(cfg, w, x, cos, sin, lat, idx_arena, table, start, true_l
     idx_arena = _kv_store(idx_arena, ki[None, :, None, :], table[None], st, true_len)
     ctx_lat, ctx_key = _gather_context(lat, table), _gather_context(idx_arena, table)
     L = ctx_lat.shape[0]
-    kb = _block_rows(L, 1024)
-    qb = _block_rows(s, 512)
+    kb, qb = _block_rows(L, 1024), _block_rows(s, 512)
     n_ctx = st[0] + jnp.reshape(true_len, ())
     n_blocks = (n_ctx + kb - 1) // kb
     q_pos = st[0] + jnp.arange(s, dtype=jnp.int32)
@@ -406,8 +407,7 @@ def _prefill_attention(cfg, w, x, cos, sin, lat, idx_arena, table, start, true_l
 
     score = lax.fori_loop(0, n_blocks, score_block, jnp.full((s, L), -jnp.inf, jnp.float32))
     chosen = _select_mask(score, cfg.index_topk)
-    o = _attend_expanded(cfg, w["kv_b_proj"], q_nope, q_pe, ctx_lat, n_blocks, kb, qb, scale,
-                         lambda j: lax.dynamic_slice_in_dim(chosen, j * kb, kb, 1))
+    o = _attend_expanded(cfg, w["kv_b_proj"], q_nope, q_pe, ctx_lat, n_blocks, kb, qb, scale, st[0], chosen)
     return o @ w["o_proj"], lat, idx_arena
 
 

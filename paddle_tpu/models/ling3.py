@@ -482,8 +482,8 @@ def _mla_prefill(cfg, w, x, cos, sin, lat, table, start, true_len):
     """A chunk of one sequence: x [s, hidden] at positions start .. start + s.
     Stores the chunk's rows, then attends the sequence through the page table
     causally, K and V expanded from the latent rows a block of keys at a time
-    (`deepseek_v32._attend_expanded`); key blocks past the context are never
-    visited."""
+    (`deepseek_v32._attend_expanded`: the `mla_prefill` kernel on the TPU);
+    key blocks past the context are never visited."""
     import jax.numpy as jnp
 
     s = x.shape[0]
@@ -493,10 +493,7 @@ def _mla_prefill(cfg, w, x, cos, sin, lat, table, start, true_len):
     ctx = _gather_context(lat, table)
     kb, qb = _block_rows(ctx.shape[0], 1024), _block_rows(s, 512)
     n_blocks = (st[0] + jnp.reshape(true_len, ()) + kb - 1) // kb
-    q_pos = st[0] + jnp.arange(s, dtype=jnp.int32)
-    o = _attend_expanded(
-        cfg, w["kv_b_proj.weight"], q_nope, q_pe, ctx, n_blocks, kb, qb, _mla_scale(cfg),
-        lambda j: (j * kb + jnp.arange(kb, dtype=jnp.int32))[None, :] <= q_pos[:, None])
+    o = _attend_expanded(cfg, w["kv_b_proj.weight"], q_nope, q_pe, ctx, n_blocks, kb, qb, _mla_scale(cfg), st[0])
     return _mla_output(cfg, w, x, o.reshape(s, cfg.num_attention_heads, cfg.v_head_dim)), lat
 
 

@@ -445,6 +445,30 @@ def kda_decode_summary():
         return [dict(zip(_KDA_DECODE_FIELDS, key)) for key in _kda_decodes]
 
 
+_MLA_PREFILL_FIELDS = ("rows", "heads", "kb", "heads_per_step", "grid_steps", "mask", "vmem_bytes")
+_mla_prefills = {}  # a tuple of `_MLA_PREFILL_FIELDS` a distinct MLA prefill attention, in order of first trace
+
+
+def record_mla_prefill(**geometry):
+    """The geometry of one traced `mla_prefill` call (ops/mla_prefill.py),
+    recorded at trace time like `record_grouped_experts`; `mask` is `causal`
+    or `selected`."""
+    key = tuple(str(geometry[f]) if f == "mask" else int(geometry[f]) for f in _MLA_PREFILL_FIELDS)
+    with _counters_lock:
+        _mla_prefills[key] = None
+
+
+def mla_prefill_summary():
+    """One entry per distinct chunk attention traced through the MLA prefill
+    kernel since the last reset (a model's layers trace the same one): the
+    chunk's `rows`, `heads`, the key block `kb`, the heads a grid step takes,
+    the grid steps a call (head groups x key blocks of the context's table;
+    those past the context skip their work), the mask's form and the VMEM
+    the call's blocks, scratch and temporaries take."""
+    with _counters_lock:
+        return [dict(zip(_MLA_PREFILL_FIELDS, key)) for key in _mla_prefills]
+
+
 def reset():
     """Zero EVERY counter family (step, serving, paging, router, flash
     fallbacks) in one critical section, so one run's router/serving gauges
@@ -467,6 +491,7 @@ def reset():
         _paged_walks.clear()
         _grouped_experts.clear()
         _kda_decodes.clear()
+        _mla_prefills.clear()
         _reset_moe_locked()
 
 

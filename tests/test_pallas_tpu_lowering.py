@@ -32,6 +32,7 @@ from paddle_tpu.distributed import mesh as pmesh
 from paddle_tpu.ops import flash_attention as fa
 from paddle_tpu.ops import grouped_experts as ge
 from paddle_tpu.ops import kda_decode as kd
+from paddle_tpu.ops import mla_prefill as mp
 
 BF16 = jnp.bfloat16
 MAX_LEN = 1024
@@ -216,8 +217,28 @@ def _kda_state_cases():
                 ((S, H, d, d), jnp.float32, None)], None)
 
 
+def _mla_prefill_args(s, H, L, selected):
+    args = [((s, H, 128), BF16, None), ((s, H, 64), BF16, None), ((L, 640), BF16, None),
+            ((512, H * 256), BF16, None), ((), jnp.int32, None), ((), jnp.int32, None)]
+    return args + ([((s, L), jnp.bool_, None)] if selected else [])
+
+
+def _mla_prefill_cases():
+    """A prefill chunk's latent attention: `dsv32_serve.longctx16`'s (2048
+    rows, 128 heads, 24,576 keys, DeepSeek-V3.2's selected keys) and Ling-3's
+    (`ling3_serve.reason64`: 2048 rows, 32 heads, 32,768 keys, causal), K and
+    V expanded from 640-wide latent rows a block of 1024 keys at a time."""
+    def fn(q_nope, q_pe, lat, w_ukv, n_blocks, start, *chosen):
+        mask = chosen[0] if chosen else None
+        assert mp.refusal(q_nope, q_pe, lat, w_ukv, 1024, 512, mask) is None
+        return mp.mla_prefill(q_nope, q_pe, lat, w_ukv, n_blocks, 1024, 512, 0.135, start, mask)
+
+    yield "mla-prefill-longctx16", fn, _mla_prefill_args(2048, 128, 24576, True), None
+    yield "mla-prefill-ling3", fn, _mla_prefill_args(2048, 32, 32768, False), None
+
+
 CASES = (list(_flash_cases()) + list(_paged_cases()) + list(_grouped_expert_cases())
-         + list(_kda_state_cases()))
+         + list(_kda_state_cases()) + list(_mla_prefill_cases()))
 IDS = [c[0] for c in CASES]
 
 
@@ -245,6 +266,23 @@ def test_lowers_for_tpu(case):
         *_avals(args, degrees, jax.devices())
     )
     assert "tpu_custom_call" in exported.mlir_module()
+
+
+def test_mla_prefill_refusal_names_what_it_cannot_take():
+    """Shapes the TPU kernel cannot take, each named; the interpreter takes
+    any whole tiling."""
+    aval = lambda *shape, dt=BF16: jax.ShapeDtypeStruct(shape, dt)
+    q, qp, lat, w = aval(2048, 128, 128), aval(2048, 128, 64), aval(24576, 640), aval(512, 128 * 256)
+    chosen = aval(2048, 24576, dt=jnp.bool_)
+    assert mp.refusal(q, qp, lat, w, 1024, 512, chosen) is None
+    assert "rows in tiles of 512" in mp.refusal(aval(2000, 128, 128), aval(2000, 128, 64), lat, w, 1024, 512)
+    assert "not whole lanes" in mp.refusal(aval(2048, 128, 64), qp, lat, aval(512, 128 * 192), 1024, 512)
+    assert "dtypes" in mp.refusal(q, qp, aval(24576, 640, dt=jnp.float32), w, 1024, 512)
+    assert "does not pack" in mp.refusal(q, qp, aval(24576, 640), w, 3072, 512, chosen)
+    assert "over the VMEM" in mp.refusal(aval(65536, 128, 128), aval(65536, 128, 64), lat, w, 1024, 512)
+    tiny = aval(32, 4, 16), aval(32, 4, 8), aval(64, 128), aval(16, 4 * 32)
+    assert "not whole lanes" in mp.refusal(*tiny, 16, 8)
+    assert mp.refusal(*tiny, 16, 8, interpret=True) is None
 
 
 @functools.lru_cache(maxsize=1)

@@ -70,13 +70,14 @@ SERVE_SIZES = dict(
     kernel_tol=1e-2, kernel_tol_int8=5e-2,
 )
 TRAIN_SIZES = dict(batch=8, seqlen=2048, steps=4)
-# DeepseekV32Config at a tenth of its widths: 8 of 64 experts held, a top-k of
+# DeepseekV32Config at a tenth of its widths (the indexer row at its published
+# 128, whole lanes: the engine refuses a narrower row on the TPU): 8 of 64 experts held, a top-k of
 # 256 under contexts of 300-700, the 700-token prompt in chunks of 256
 LATENT_CONFIG = dict(
     vocab_size=4096, hidden_size=1024, intermediate_size=2048, moe_intermediate_size=256,
     num_hidden_layers=3, first_k_dense_replace=1, num_attention_heads=16,
     num_key_value_heads=16, q_lora_rank=256, kv_lora_rank=128, qk_nope_head_dim=64,
-    qk_rope_head_dim=32, v_head_dim=64, index_n_heads=8, index_head_dim=64, index_topk=256,
+    qk_rope_head_dim=32, v_head_dim=64, index_n_heads=8, index_head_dim=128, index_topk=256,
     n_routed_experts=64, experts_held=8, max_position_embeddings=1024,
 )
 LATENT_SIZES = dict(slots=4, max_len=1024, buckets=[128, 256], lengths=[300, 700, 100],

@@ -145,6 +145,16 @@ logger = logging.getLogger("paddle_tpu")
 # tick, long enough that one straggler does not flap the fleet
 _MISS_EWMA_ALPHA = 0.05
 
+# the minor dim of a vreg: a cache row is a multiple of it on the TPU
+LANES = 128
+
+
+def _rows_need_whole_lanes():
+    """Whether the arenas this process builds live on a TPU."""
+    import jax
+
+    return jax.default_backend() == "tpu"
+
 
 class EngineUnavailable(RuntimeError):
     """The engine cannot take this request right now (queue full, draining,
@@ -522,6 +532,17 @@ class ContinuousBatchingEngine:
             else [(rows, [])] * cfg.num_hidden_layers
         )
         layers = [(list(e[0]), list(e[1])) for e in declared]
+        # a row whose width is not whole lanes makes XLA:TPU lay the arena out
+        # rows-minor and copy all of it around every store and walk (PERF.md,
+        # PR 29): refused here, for every model, where the arena lives on a TPU
+        if _rows_need_whole_lanes():
+            for name, _, width, _ in {row for r, _ in layers for row in r}:
+                if int(width) % LANES:
+                    raise ValueError(
+                        f"{type(model).__name__} declares cache rows {name!r} {width} wide: a "
+                        f"row on the TPU is whole lanes ({LANES} columns a multiple), or XLA "
+                        "copies the whole arena around every store and call; pad or pack the row"
+                    )
         reaches = [e[2] if len(e) > 2 else None for e in declared]
         windowed = {int(r) for r in reaches if r is not None}
         if len(windowed) > 1:
@@ -660,6 +681,9 @@ class ContinuousBatchingEngine:
             for (r, st), g in zip(layers, self._layer_group)
         ]
         self._has_state = any(st for _, st in layers)
+        # a model with state may count its state layers' prefill itself
+        self._record_state_prefill = getattr(
+            model, "record_prefill_stats", _prof.record_linear_attn_prefill)
         if self.tp > 1 or self.cp > 1:
             for a in self._arenas:
                 shard_kv_for_tp(a)
@@ -2441,7 +2465,7 @@ class ContinuousBatchingEngine:
                             )
                             table_t = to_tensor(np.stack([row_table, win.table[s]]))
                     if self._has_state:
-                        _prof.record_linear_attn_prefill(n, resumed=offset != 0)
+                        self._record_state_prefill(n, resumed=offset != 0)
                     # only the last chunk's token is the request's first: of
                     # an earlier chunk's vector nothing is kept
                     if offset == 0:

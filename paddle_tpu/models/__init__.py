@@ -6,6 +6,7 @@ from .deepseek_v32 import DeepseekV32Config, DeepseekV32ForCausalLM  # noqa: F40
 from .ling3 import Ling3Config, Ling3ForCausalLM  # noqa: F401
 from .kimi_linear import KimiLinearConfig, KimiLinearForCausalLM  # noqa: F401
 from .mellum2 import Mellum2Config, Mellum2ForCausalLM  # noqa: F401
+from .lfm2_moe import Lfm2MoeConfig, Lfm2MoeForCausalLM  # noqa: F401
 from .gpt import (  # noqa: F401
     GPTConfig,
     GPTForCausalLM,

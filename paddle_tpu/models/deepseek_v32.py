@@ -457,7 +457,9 @@ def _route(cfg, x, gate_w, bias):
     _, experts = lax.top_k(choice, cfg.num_experts_per_tok)
     wts = jnp.take_along_axis(s, experts, axis=1)
     if cfg.norm_topk_prob:
-        wts = wts / jnp.sum(wts, axis=1, keepdims=True)
+        total = jnp.sum(wts, axis=1, keepdims=True)
+        eps = getattr(cfg, "topk_norm_eps", 0.0)  # LFM2's published form adds one; no other does
+        wts = wts / (total + eps if eps else total)
     return experts.astype(jnp.int32), wts * cfg.routed_scaling_factor
 
 

@@ -244,7 +244,8 @@ def _attn_decode(cfg, w, x, cos, sin, ak, av, tables, pos, max_len, window):
 
 def _attend_pages(q, ak, av, table, q_pos, n_ctx, window, scale, key_rows=1024, q_rows=512):
     """Attention of s queries (q [s, H, d] at positions q_pos [s]) over one
-    sequence's K and V rows, read THROUGH its page table a block of whole
+    sequence's K and V rows (`av` None: `ak`'s rows are `[k | v]`, 2 d wide),
+    read THROUGH its page table a block of whole
     pages at a time (`key_rows` keys, `q_rows` queries to a score tile),
     online softmax in float32.  Blocks from the one that holds the first
     query's first visible key to the one that holds key `n_ctx - 1` are
@@ -265,8 +266,11 @@ def _attend_pages(q, ak, av, table, q_pos, n_ctx, window, scale, key_rows=1024, 
         m, l, acc = carry
         pages = lax.dynamic_slice_in_dim(table, j * pb, pb, 0)
         # [pb, KV, ps, d] -> [KV, kb, d]: a block's rows of one head lie together
-        k = jnp.moveaxis(ak[pages], 1, 0).reshape(KV, kb, d)
-        v = jnp.moveaxis(av[pages], 1, 0).reshape(KV, kb, d)
+        k = jnp.moveaxis(ak[pages], 1, 0).reshape(KV, kb, -1)
+        if av is None:  # packed rows, [k | v] of a head each
+            k, v = k[..., :d], k[..., d:]
+        else:
+            v = jnp.moveaxis(av[pages], 1, 0).reshape(KV, kb, d)
         at = j * kb + jnp.arange(kb, dtype=jnp.int32)
         mask = (at[None, :] <= q_pos[:, None]) & (at[None, :] >= lo[:, None])
         ms, ls, accs = [], [], []

@@ -765,6 +765,11 @@ _window_gauges = {"steps": 0, "live_slots": 0, "rows_in_reach_full": 0, "rows_in
 _page_groups = {}  # group name -> gauges, set by the engine that was built last
 # latent rows in reach of the decode steps' MLA layers (ISSUE 39), counted inside the step
 _latent_walk_gauges = {"steps": 0, "live_slots": 0, "rows_in_reach": 0}
+# gated short-convolution layers with a tail per slot (ISSUE 43), and the packed K/V rows in reach
+# of the attention layers beside them, counted inside the step
+_short_conv_gauges = {"steps": 0, "live_slots": 0, "tail_bytes_read": 0, "tail_bytes_written": 0,
+                      "prefill_rows": 0, "chunks_resumed": 0}
+_kv_walk_gauges = {"steps": 0, "live_slots": 0, "rows_in_reach": 0}
 
 
 def record_moe_step(tokens, picks_held, experts_hit, max_load):
@@ -837,6 +842,38 @@ def record_latent_walk_step(rows, live_slots):
         g["rows_in_reach"] += int(rows)
 
 
+def record_short_conv_step(live_slots, tail_bytes):
+    """One decode step of the short-convolution layers: live slots, and the
+    bytes of tail they read and wrote again, all such layers together (an
+    idle slot's tail is not touched)."""
+    with _counters_lock:
+        g = _short_conv_gauges
+        g["steps"] += 1
+        g["live_slots"] += int(live_slots)
+        g["tail_bytes_read"] += int(tail_bytes)
+        g["tail_bytes_written"] += int(tail_bytes)
+
+
+def record_short_conv_prefill(rows, resumed):
+    """One prefill program of such a model: the prompt rows it convolved,
+    and whether it resumed from the tails an earlier chunk of the same
+    prompt left (a fresh prefill starts from zeros)."""
+    with _counters_lock:
+        _short_conv_gauges["prefill_rows"] += int(rows)
+        _short_conv_gauges["chunks_resumed"] += int(bool(resumed))
+
+
+def record_kv_walk_step(rows, live_slots):
+    """One decode step of a model whose attention layers walk a K/V page
+    cache, counted inside the step: the rows in reach of the live slots
+    (`pos + 1` a slot), summed over those layers."""
+    with _counters_lock:
+        g = _kv_walk_gauges
+        g["steps"] += 1
+        g["live_slots"] += int(live_slots)
+        g["rows_in_reach"] += int(rows)
+
+
 def record_page_group(name, pool_pages, reach, pages_live, slot_pages=0, prefill_pages=0,
                       released_behind=0):
     """What one page group of the engine's cache manager holds, as of now
@@ -864,7 +901,8 @@ def record_arena_bytes(by_kind):
 
 
 def _reset_moe_locked():
-    for g in (_moe_gauges, _sparse_attn_gauges, _linear_attn_gauges, _window_gauges, _latent_walk_gauges):
+    for g in (_moe_gauges, _sparse_attn_gauges, _linear_attn_gauges, _window_gauges, _latent_walk_gauges,
+              _short_conv_gauges, _kv_walk_gauges):
         for k in g:
             g[k] = 0
     for g in _page_groups.values():  # sizes stay, what was counted goes
@@ -909,6 +947,23 @@ def latent_walk_summary():
     """{} before any counted step; else the totals and `rows_per_step`."""
     with _counters_lock:
         g = dict(_latent_walk_gauges)
+    if not g["steps"]:
+        return {}
+    g["rows_per_step"] = g["rows_in_reach"] / g["steps"]
+    return g
+
+
+def short_conv_summary():
+    """{} before any counted step or prefill; else the totals."""
+    with _counters_lock:
+        g = dict(_short_conv_gauges)
+    return g if g["steps"] or g["prefill_rows"] else {}
+
+
+def kv_walk_summary():
+    """{} before any counted step; else the totals and `rows_per_step`."""
+    with _counters_lock:
+        g = dict(_kv_walk_gauges)
     if not g["steps"]:
         return {}
     g["rows_per_step"] = g["rows_in_reach"] / g["steps"]

@@ -175,6 +175,13 @@ def _paged_cases():
             yield ("paged-mixed32-window",
                    lambda q, k, v, t, p, f: fa._fused_paged_decode_window(q, k, v, t, p, f, 32768, 0.088, False),
                    args + [((32,), jnp.int32, None)], None)
+    # `lfm2_serve.gen128`'s GQA walk over packed `[k | v]` rows: 128 slots, 4
+    # query rows a KV head over 8 KV heads of 128 (2 x 64), 160 table entries
+    # (20,480 tokens) over the 11,000-page arena, one operand (`arena_v=None`)
+    yield ("paged-gen128-packed",
+           lambda q, kv, t, p: fa._fused_paged_decode(q, kv, None, t, p, 20480, 0.125, False),
+           [((128, 1, 32, 128), BF16, None), ((11000, 8, 128, 128), BF16, None),
+            ((128, 160), jnp.int32, None), ((128,), jnp.int32, None)], None)
     for cp, mp in ((1, 4), (2, 2)):  # the shard_map wrappers
         for quant in (False, True):
             yield (f"paged-cp{cp}-mp{mp}-{'int8' if quant else 'bf16'}",
@@ -188,9 +195,11 @@ def _grouped_expert_cases():
     (`ling3_serve.reason64`: 64 tokens, 128 of 512 experts held, 2560 x 768;
     `mellum2_serve.mixed32`: 32 tokens, all 64 experts, 2304 x 896 = 7 x 128;
     `kimi_serve.longreason32`: 32 tokens, 128 of 256 experts held, 2304 x
-    1024): two experts in flight are 23.6, 24.8 and 28.3 MB of scoped VMEM."""
+    1024; `lfm2_serve.gen128`: 128 tokens, the kernel's cap, all 32 experts,
+    2048 x 1792 = 14 x 128): two experts in flight are 23.6, 24.8, 28.3 and
+    44.0 MB of scoped VMEM."""
     for tag, T, held, D, I in (("reason64", 64, 128, 2560, 768), ("mixed32", 32, 64, 2304, 896),
-                               ("longreason32", 32, 128, 2304, 1024)):
+                               ("longreason32", 32, 128, 2304, 1024), ("gen128", 128, 32, 2048, 1792)):
         def fn(x, weight, counts, w1, w3, w2):
             assert ge.refusal(x, w1) is None
             return ge.grouped_experts(x, weight, *ge.hit_list(counts), w1, w3, w2, False)
@@ -403,3 +412,70 @@ def test_engine_step_compiles_for_v5e_without_arena_copies(step):
     assert bad == []
     # one arena is 134.5 MB: a program that copies one needs that much room
     assert compiled.memory_analysis().temp_size_in_bytes < 64 * 2**20
+
+
+def _lfm2_engine(**over):
+    """An LFM2 engine of one attention and one convolution layer, traced and
+    compiled here and never run; its attention arena holds packed `[k | v]`
+    rows, one operand."""
+    import numpy as np
+
+    import paddle_tpu as paddle
+    from paddle_tpu.inference.engine import ContinuousBatchingEngine
+    from paddle_tpu.models import Lfm2MoeConfig, Lfm2MoeForCausalLM
+
+    engine = over.pop("engine")
+    state = paddle.get_rng_state()
+    try:
+        np.random.seed(1234)
+        model = Lfm2MoeForCausalLM(Lfm2MoeConfig.tiny(
+            num_hidden_layers=2, layer_types=["full_attention", "conv"], **over))
+        return ContinuousBatchingEngine(model, **engine)
+    finally:
+        paddle.set_rng_state(state)
+
+
+@pytest.mark.parametrize("step", ["decode", "prefill"])
+def test_lfm2_step_jaxpr_holds_the_packed_store_alone(step):
+    """The packed arena of `lfm2_serve.gen128` is made in the traced step by
+    its one store alone: K and V go in as one row, the walk (or the chunk
+    loop) reads the arena as it lies."""
+    from conftest import paged_engine_steps
+
+    eng = _lfm2_engine(num_dense_layers=0, engine=dict(slots=3, max_len=64, prefill_buckets=[16], page_size=8))
+    shape = tuple(eng._arenas[0].kv.shape)
+    assert shape[-1] == 2 * eng.model.config.head_dim
+    fn, args = paged_engine_steps(eng, 16)[step]
+    entry, *arrays = fn._prepare(args, {})
+    found = _arena_shaped_equations(jax.make_jaxpr(entry.jitted)(*arrays).jaxpr, shape, [])
+    names = [e.primitive.name for e in found]
+    assert names.count("scatter") == 1  # one layer with rows, one packed row kind
+    assert set(names) <= {"scatter", "stop_gradient", "broadcast_in_dim"}, names
+
+
+@pytest.mark.slow
+@pytest.mark.parametrize("step", ["decode", "prefill"])
+def test_lfm2_step_compiles_for_v5e_without_arena_copies(step):
+    """The same two steps compiled for `TPU v5 lite` at the cell's widths
+    (hidden 2048, 8 KV heads of a packed 128, 128 slots, page 128): nothing
+    arena-shaped is copied or selected around the store or the walk.  Dense
+    feed-forward layers and a cut vocabulary keep the build small; they do
+    not touch the arena."""
+    from conftest import hlo_results, paged_engine_steps
+
+    eng = _lfm2_engine(
+        vocab_size=4096, hidden_size=2048, intermediate_size=7168, num_attention_heads=32,
+        num_key_value_heads=8, num_dense_layers=2, max_position_embeddings=20480, dtype="bfloat16",
+        engine=dict(slots=128, max_len=20480, prefill_buckets=[512], pool_pages=513))
+    shape = tuple(eng._arenas[0].kv.shape)
+    assert shape == (513, 8, 128, 128)
+    fn, args = paged_engine_steps(eng, 512)[step]
+    entry, *arrays = fn._prepare(args, {})
+    on_chip = SingleDeviceSharding(_v5e_devices()[0])
+    avals = jax.tree.map(lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=on_chip), arrays)
+    compiled = entry.jitted.lower(*avals).compile()
+    made = hlo_results(compiled.as_text(), shape)
+    bad = [(n, op) for n, op in made
+           if op in ("copy", "select") or (op == "fusion" and ("copy" in n or "select" in n))]
+    assert bad == []
+    assert compiled.memory_analysis().temp_size_in_bytes < 64 * 2**20  # the arena is 134.5 MB

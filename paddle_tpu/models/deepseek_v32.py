@@ -297,7 +297,7 @@ def _select_mask(score, k):
     from jax import lax
 
     k = min(k, score.shape[1])
-    bits = lax.bitcast_convert_type(score + 0.0, jnp.int32)  # -0.0 is 0.0: one code for equal scores
+    bits = jnp.where(score == 0, 0, lax.bitcast_convert_type(score, jnp.int32))  # -0.0 is 0.0 (XLA drops `+ 0.0`)
     # larger float <-> larger unsigned code (negative floats: magnitude reversed)
     code = lax.bitcast_convert_type(jnp.where(bits < 0, bits ^ 0x7FFFFFFF, bits), jnp.uint32) ^ jnp.uint32(1 << 31)
 
@@ -406,7 +406,7 @@ def _prefill_attention(cfg, w, x, cos, sin, lat, idx_arena, table, start, true_l
         return lax.dynamic_update_slice_in_dim(score, blk, j * kb, 1)
 
     score = lax.fori_loop(0, n_blocks, score_block, jnp.full((s, L), -jnp.inf, jnp.float32))
-    chosen = _select_mask(score, cfg.index_topk)
+    chosen = _select_topk(score, cfg.index_topk, n_blocks, kb)
     o = _attend_expanded(cfg, w["kv_b_proj"], q_nope, q_pe, ctx_lat, n_blocks, kb, qb, scale, st[0], chosen)
     return o @ w["o_proj"], lat, idx_arena
 
@@ -854,3 +854,20 @@ class DeepseekV32ForCausalLM(nn.Layer):
         raise NotImplementedError(
             "DeepseekV32ForCausalLM is served through ContinuousBatchingEngine; it has no "
             "cache-free forward (benchmarks/reference_deepseek_v32.py is the plain one)")
+
+
+def _select_topk(score, k, n_blocks, kb):
+    """`_select_mask`'s mask of a prefill chunk whose context fills the first
+    `n_blocks` (data) key blocks of `kb` (the columns past them are -inf).  On
+    the TPU one Pallas kernel (`ops/topk_select.py`) reads only those blocks,
+    once, into VMEM and counts there; elsewhere, or for a shape it refuses,
+    `_select_mask`'s 32 passes over every column."""
+    from ..ops import flash_attention as fa, topk_select as ts
+
+    if (it := fa._FORCE_INTERPRET) or fa._on_tpu():  # the backend and static shapes choose
+        reason = ts.refusal(score, kb, it)
+        if reason is None:
+            fa._log_pallas_call("topk_select")
+            return ts.topk_select(score, k, n_blocks, kb, it)
+        fa._log_pallas_fallback("topk_select: " + reason, shape=score.shape)
+    return _select_mask(score, k)

@@ -469,6 +469,28 @@ def mla_prefill_summary():
         return [dict(zip(_MLA_PREFILL_FIELDS, key)) for key in _mla_prefills]
 
 
+_TOPK_SELECT_FIELDS = ("rows", "cols", "row_tile", "kb", "vmem_bytes")
+_topk_selects = {}  # a tuple of `_TOPK_SELECT_FIELDS` a distinct top-k selection, in order of first trace
+
+
+def record_topk_select(**geometry):
+    """The geometry of one traced `topk_select` call (ops/topk_select.py),
+    recorded at trace time like `record_grouped_experts`."""
+    key = tuple(int(geometry[f]) for f in _TOPK_SELECT_FIELDS)
+    with _counters_lock:
+        _topk_selects[key] = None
+
+
+def topk_select_summary():
+    """One entry per distinct chunk selection traced through the top-k
+    kernel since the last reset (a model's layers trace the same one): the
+    chunk's `rows`, the page table's `cols`, the rows a grid step holds, the
+    key block `kb` (blocks past the context are not read) and the VMEM the
+    call's blocks, scratch and temporaries take."""
+    with _counters_lock:
+        return [dict(zip(_TOPK_SELECT_FIELDS, key)) for key in _topk_selects]
+
+
 def reset():
     """Zero EVERY counter family (step, serving, paging, router, flash
     fallbacks) in one critical section, so one run's router/serving gauges
@@ -492,6 +514,7 @@ def reset():
         _grouped_experts.clear()
         _kda_decodes.clear()
         _mla_prefills.clear()
+        _topk_selects.clear()
         _reset_moe_locked()
 
 

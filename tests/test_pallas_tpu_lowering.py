@@ -33,6 +33,7 @@ from paddle_tpu.ops import flash_attention as fa
 from paddle_tpu.ops import grouped_experts as ge
 from paddle_tpu.ops import kda_decode as kd
 from paddle_tpu.ops import mla_prefill as mp
+from paddle_tpu.ops import topk_select as ts
 
 BF16 = jnp.bfloat16
 MAX_LEN = 1024
@@ -246,8 +247,20 @@ def _mla_prefill_cases():
     yield "mla-prefill-ling3", fn, _mla_prefill_args(2048, 32, 32768, False), None
 
 
+def _topk_select_cases():
+    """DeepSeek-V3.2's exact top-2048 over a prefill chunk's score at
+    `dsv32_serve.longctx16`'s shapes: each prefill bucket's rows over the
+    24,576 columns of the page table, key blocks of 1024."""
+    def fn(score, n_blocks):
+        assert ts.refusal(score, 1024) is None
+        return ts.topk_select(score, 2048, n_blocks, 1024)
+
+    for s in (512, 1024, 2048):
+        yield f"topk-select-longctx16-{s}", fn, [((s, 24576), jnp.float32, None), ((), jnp.int32, None)], None
+
+
 CASES = (list(_flash_cases()) + list(_paged_cases()) + list(_grouped_expert_cases())
-         + list(_kda_state_cases()) + list(_mla_prefill_cases()))
+         + list(_kda_state_cases()) + list(_mla_prefill_cases()) + list(_topk_select_cases()))
 IDS = [c[0] for c in CASES]
 
 
@@ -292,6 +305,19 @@ def test_mla_prefill_refusal_names_what_it_cannot_take():
     tiny = aval(32, 4, 16), aval(32, 4, 8), aval(64, 128), aval(16, 4 * 32)
     assert "not whole lanes" in mp.refusal(*tiny, 16, 8)
     assert mp.refusal(*tiny, 16, 8, interpret=True) is None
+
+
+def test_topk_select_refusal_names_what_it_cannot_take():
+    """Shapes the TPU kernel cannot take, each named; the interpreter takes
+    any whole blocking."""
+    aval = lambda *shape, dt=jnp.float32: jax.ShapeDtypeStruct(shape, dt)
+    assert ts.refusal(aval(2048, 24576), 1024) is None
+    assert "keys in blocks of 1000" in ts.refusal(aval(2048, 24576), 1000)
+    assert "bfloat16 score" in ts.refusal(aval(2048, 24576, dt=BF16), 1024)
+    assert "not whole lanes" in ts.refusal(aval(2048, 24576), 64)
+    assert "not whole tiles of 32" in ts.refusal(aval(2000, 24576), 1024)
+    assert "over the VMEM" in ts.refusal(aval(2048, 24576 * 256), 1024)
+    assert ts.refusal(aval(2000, 24576), 64, interpret=True) is None
 
 
 @functools.lru_cache(maxsize=1)
